@@ -1,11 +1,11 @@
 """Kernel-plane benchmark: the Pallas compute unit across workload GEMMs.
 
-No TPU in this container, so wall-clock numbers would measure the Python
-interpreter, not the kernel.  Instead this reports the *structural* kernel
+On the CPU the kernels run interpreted, so wall-clock numbers there measure
+the interpreter, not the kernel.  This reports the *structural* kernel
 metrics the DSE optimizes — chosen BlockSpec, VMEM working set, MXU
 efficiency, arithmetic intensity vs the v5e ridge point, and the modeled
-MXU-bound time per GEMM — and runs a correctness pass (interpret=True) of
-every kernel against its oracle at a reduced shape.
+MXU-bound time per GEMM — and runs a correctness pass of every kernel
+against its oracle at a reduced shape.
 """
 from __future__ import annotations
 
@@ -59,18 +59,18 @@ def correctness_pass() -> dict:
     key = jax.random.PRNGKey(0)
     x = jax.random.normal(key, (96, 160)) * 0.3
     w = jax.random.normal(jax.random.fold_in(key, 1), (160, 64)) * 0.3
-    mm = float(jnp.abs(ops.matmul_fp(x, w, interpret=True) - ref.matmul_ref(x, w)).max())
+    mm = float(jnp.abs(ops.matmul_fp(x, w) - ref.matmul_ref(x, w)).max())
     from repro.core.quantization import quantize
     q = float(jnp.abs(
-        ops.matmul_q16(quantize(x), quantize(w), interpret=True).astype(jnp.int32)
+        ops.matmul_q16(quantize(x), quantize(w)).astype(jnp.int32)
         - ref.matmul_q16_ref(quantize(x), quantize(w)).astype(jnp.int32)
     ).max())
     xi = jax.random.normal(key, (1, 10, 10, 4))
     wi = jax.random.normal(jax.random.fold_in(key, 2), (3, 3, 4, 8)) * 0.3
-    cv = float(jnp.abs(ops.conv2d(xi, wi, interpret=True) - ref.conv2d_ref(xi, wi)).max())
+    cv = float(jnp.abs(ops.conv2d(xi, wi) - ref.conv2d_ref(xi, wi)).max())
     qq = jax.random.normal(key, (1, 4, 64, 32)) * 0.3
     kk = jax.random.normal(jax.random.fold_in(key, 3), (1, 2, 64, 32)) * 0.3
-    fa_out = ops.flash_attention(qq, kk, kk, causal=True, bq=32, bk=32, interpret=True)
+    fa_out = ops.flash_attention(qq, kk, kk, causal=True, bq=32, bk=32)
     qf = qq.reshape(1, 2, 2, 64, 32).reshape(4, 64, 32)
     kf = jnp.broadcast_to(kk[:, :, None], (1, 2, 2, 64, 32)).reshape(4, 64, 32)
     fa = float(jnp.abs(fa_out.reshape(4, 64, 32) - ref.attention_ref(qf, kf, kf)).max())
@@ -79,7 +79,7 @@ def correctness_pass() -> dict:
 
 def _time_conv(route: str, x, w, reps: int = 3) -> float:
     fn = lambda: jax.block_until_ready(
-        ops.conv2d(x, w, stride=1, padding=1, route=route, interpret=True)
+        ops.conv2d(x, w, stride=1, padding=1, route=route)
     )
     fn()  # compile
     t0 = time.perf_counter()
@@ -93,7 +93,7 @@ def im2col_vs_direct_row(n=1, hw=16, cin=16, cout=32, k=3, pad=1) -> dict:
 
     Bytes are the HBM traffic of each route's GEMM stage (f32): im2col must
     materialize the (N·Ho·Wo, Cin·K²) column matrix, the direct kernel
-    streams the image slab once. Wall time is interpret=True on CPU (it
+    streams the image slab once. Wall time on the CPU is interpreted (it
     measures the Pallas interpreter, not the MXU — useful only as a relative
     trajectory between PRs; the structural bytes are the hardware story).
     """
@@ -129,7 +129,7 @@ def spatial_tiling_row() -> dict:
     scheme, so the honest gate is VMEM residency and the input-stream
     traffic term — both must come out ≤ 0.6×).  Numeric: on a shrunken
     budget the same planner decision is executed end-to-end and checked
-    against the im2col route (interpret=True).
+    against the im2col route.
     """
     import dataclasses
 
@@ -138,7 +138,7 @@ def spatial_tiling_row() -> dict:
                                 explore_conv_spatial)
     from repro.core.template import TemplateConfig
 
-    eng = Engine(TemplateConfig(backend="pallas", interpret=True))
+    eng = Engine(TemplateConfig(backend="pallas"))
     plan = eng.plan_conv((1, 512, 512, 64), (3, 3, 64, 64), stride=1, padding=1)
     untiled = direct_conv_vmem(514, 514, 64, 3, 3, 512, 512, plan.tau or 64, 4)
     # best legal two-block config on the same layer (large top: the DMA
@@ -155,8 +155,8 @@ def spatial_tiling_row() -> dict:
         tile_rows=two_blk.tile_rows, halo_mode=mode)
         for mode in ("two_block", "dma")}
     # numeric differential at a budget that forces tiling on a small layer
-    hw = dataclasses.replace(TPU_V5E, vmem_bytes=256 * 1024)
-    eng_s = Engine(TemplateConfig(backend="pallas", interpret=True, hw=hw))
+    hw = dataclasses.replace(TPU_V5E, vmem_bytes=1024 * 1024)  # Cin=32: 128 lanes
+    eng_s = Engine(TemplateConfig(backend="pallas", hw=hw))
     key = jax.random.PRNGKey(0)
     x = jax.random.normal(key, (1, 32, 32, 32)) * 0.3
     w = jax.random.normal(jax.random.fold_in(key, 1), (3, 3, 32, 16)) * 0.3
@@ -284,7 +284,7 @@ def plan_store_warm_start_row() -> dict:
     convs = [((1, 32, 32, 16), (3, 3, 16, 32)), ((1, 224, 224, 3), (11, 11, 3, 64))]
 
     def plan_all(reg):
-        eng = Engine(TemplateConfig(backend="pallas", interpret=True), plan_cache=reg)
+        eng = Engine(TemplateConfig(backend="pallas"), plan_cache=reg)
         t0 = time.perf_counter()
         for m, n, k in gemms:
             eng.plan_gemm(m, n, k)
@@ -546,7 +546,7 @@ def main():
         print(f"{r['gemm']:28s} {str(r['block']):>16s} {r['vmem_MiB']:6.1f} "
               f"{r['mxu_eff']:5.2f} {r['ai']:6.1f} {r['bound']:>8s} "
               f"{r['mxu_us']:8.1f} {r['hbm_us']:8.1f}")
-    print("\n== Kernel correctness vs oracles (interpret=True) ==")
+    print("\n== Kernel correctness vs oracles ==")
     for k, v in correctness_pass().items():
         print(f"  {k:18s} max|err| = {v:.2e}")
     print("\n== im2col vs direct conv route (JSON, append-able trajectory) ==")

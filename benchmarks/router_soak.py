@@ -7,10 +7,12 @@ one injected kill — zero lost/duplicated tokens and zero cold DSE searches.
 The cross-process half of the ISSUE 7 failover story (the in-process half —
 VirtualClock fault injection through :class:`ReplicaRouter` — lives in
 tests/test_router_failover.py and the kernel_table ``router_failover`` row).
-The parent:
+The parent never touches JAX: every scheduler runs in a child process,
+pinned to the CPU (:func:`_child_env`).  The parent:
 
-1. replays the whole trace through ONE in-process scheduler (the reference
-   ledger) and merges the resulting plans into a shared flock'd plan store;
+1. has ONE reference child replay the whole trace through a single
+   scheduler (the reference ledger) and merge the resulting plans into a
+   shared flock'd plan store;
 2. partitions the trace round-robin across N worker subprocesses
    (``--worker`` mode: a real ServeScheduler per process, warm-started from
    the shared store), each streaming ``T rid pos tok`` ledger lines and
@@ -43,7 +45,6 @@ import sys
 import tempfile
 import time
 
-import jax
 import numpy as np
 
 #: trace prompts sweep only up to 24 while the schedulers run a 32 top rung:
@@ -61,6 +62,8 @@ def build_scheduler(args):
     from repro.launch.scheduler import (SchedulerConfig, ServeScheduler,
                                         VirtualClock)
     from repro.models import transformer as T
+
+    import jax
 
     cfg = reduced(get_config(args.arch))
     tpl = default_template(args.backend)
@@ -136,19 +139,69 @@ def worker_main(args) -> None:
 # ---------------------------------------------------------------------------
 
 
-def _spawn(args, wid, reqfile, ckpt_dir, store, die_at=-1):
-    cmd = [
-        sys.executable, "-m", "benchmarks.router_soak", "--worker",
-        "--worker-id", str(wid), "--reqfile", reqfile,
-        "--ckpt-dir", ckpt_dir, "--die-at-tick", str(die_at),
-        "--checkpoint-every", str(args.checkpoint_every),
+def reference_main(args) -> None:
+    """The reference child: the whole trace through one scheduler.  Writes
+    the trace (as session snapshots), the reference ledger and its DSE
+    misses to ``--reference``, and the merged plans to ``--store-out``."""
+    from repro.core.engine import (plan_store_stats, save_plan_store,
+                                   warm_start_plan_store)
+    from repro.launch.scheduler import (replay_trace, session_snapshot,
+                                        synthetic_trace)
+
+    _, warm_loaded = warm_start_plan_store()
+    before = plan_store_stats()
+    cfg, sched = build_scheduler(args)
+    sched.warmup()
+    trace = synthetic_trace(args.requests, seed=args.seed, vocab=cfg.vocab,
+                            ladder=TRACE_LADDER, max_new=MAX_NEW)
+    snapshots = [session_snapshot(r) for r in trace]
+    replay_trace(sched, trace)
+    misses = plan_store_stats()["misses"] - before["misses"]
+    if os.environ.get("REPRO_PLAN_ASSERT_WARM") == "1" and misses > 0:
+        raise RuntimeError(
+            f"ASSERT_WARM: reference run searched {misses} times "
+            "against a populated store")
+    save_plan_store(args.store_out)  # warm-started entries + reference plans
+    with open(args.reference, "w") as f:
+        json.dump({
+            "arch": cfg.name,
+            "snapshots": snapshots,
+            "reference": {str(r.rid): list(sched.results[r.rid].generated)
+                          for r in trace},
+            "misses": misses,
+            "warm_loaded": warm_loaded,
+        }, f)
+
+
+def _child_env(store=None) -> dict:
+    """Environment of every child process.  ``JAX_PLATFORMS=cpu``: the soak
+    counts tokens and measures no device time, and a chip belongs to one
+    process at a time — on a machine with one, N+2 schedulers would contend
+    for it.  ``store`` (when given) is the shared plan store."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH="src" + os.pathsep + os.environ.get("PYTHONPATH", ""))
+    if store is not None:
+        env["REPRO_PLAN_STORE"] = store
+    return env
+
+
+def _cmd(args, *extra) -> list:
+    return [
+        sys.executable, "-m", "benchmarks.router_soak", *extra,
         "--arch", args.arch, "--backend", args.backend,
         "--slots", str(args.slots), "--seed", str(args.seed),
         "--requests", str(args.requests),
     ]
-    env = dict(os.environ, REPRO_PLAN_STORE=store,
-               PYTHONPATH="src" + os.pathsep + os.environ.get("PYTHONPATH", ""))
-    return subprocess.Popen(cmd, stdout=subprocess.PIPE, text=True, env=env)
+
+
+def _spawn(args, wid, reqfile, ckpt_dir, store, die_at=-1):
+    cmd = _cmd(
+        args, "--worker", "--worker-id", str(wid), "--reqfile", reqfile,
+        "--ckpt-dir", ckpt_dir, "--die-at-tick", str(die_at),
+        "--checkpoint-every", str(args.checkpoint_every),
+    )
+    return subprocess.Popen(cmd, stdout=subprocess.PIPE, text=True,
+                            env=_child_env(store))
 
 
 def _consume(ledger, text, counters):
@@ -193,48 +246,46 @@ def main(argv=None):
                          "trace (worker 0 needs ~6 ticks)")
     ap.add_argument("--out", default="router_soak.json",
                     help="stats JSON artifact path ('' = skip)")
+    ap.add_argument("--reference", default="",
+                    help="(internal) run the reference child, writing here")
+    ap.add_argument("--store-out", default="",
+                    help="(internal) where the reference child saves plans")
     args = ap.parse_args(argv)
     if args.worker:
         return worker_main(args)
+    if args.reference:
+        return reference_main(args)
 
-    from repro.core.engine import (plan_store_stats, save_plan_store,
-                                   warm_start_plan_store)
     from repro.checkpoint.manager import CheckpointManager
     from repro.launch.router import TokenLedger
-    from repro.launch.scheduler import (replay_trace, session_snapshot,
-                                        synthetic_trace)
 
     t_start = time.time()
-    _, warm_loaded = warm_start_plan_store()
-    before = plan_store_stats()
-
-    # 1. the reference ledger (one in-process scheduler, whole trace) — this
-    #    also plants every plan the workers will need
-    cfg, ref_sched = build_scheduler(args)
-    ref_sched.warmup()
-    trace = synthetic_trace(args.requests, seed=args.seed, vocab=cfg.vocab,
-                            ladder=TRACE_LADDER, max_new=MAX_NEW)
-    snapshots = {r.rid: session_snapshot(r) for r in trace}
-    replay_trace(ref_sched, trace)
-    reference = {r.rid: list(ref_sched.results[r.rid].generated)
-                 for r in trace}
-    parent_misses = plan_store_stats()["misses"] - before["misses"]
-    print(f"[router-soak] reference: {len(reference)} sessions, "
-          f"{sum(len(v) for v in reference.values())} tokens, "
-          f"{parent_misses} parent DSE misses (warm_loaded={warm_loaded})")
-    if os.environ.get("REPRO_PLAN_ASSERT_WARM") == "1" and parent_misses > 0:
-        raise RuntimeError(
-            f"ASSERT_WARM: reference run searched {parent_misses} times "
-            "against a populated store")
-
     work = tempfile.mkdtemp(prefix="router_soak_")
     store = os.path.join(work, "plan_store.json")
-    save_plan_store(store)  # merged: warm-started entries + reference plans
+
+    # 1. the reference ledger (one scheduler, whole trace, in a child) —
+    #    this also plants every plan the workers will need; the child warm
+    #    starts from the caller's REPRO_PLAN_STORE, if any
+    ref_path = os.path.join(work, "reference.json")
+    subprocess.run(
+        _cmd(args, "--reference", ref_path, "--store-out", store),
+        env=_child_env(), check=True, timeout=1200,
+    )
+    with open(ref_path) as f:
+        ref = json.load(f)
+    snapshots = {int(snap["rid"]): snap for snap in ref["snapshots"]}
+    trace_rids = [int(snap["rid"]) for snap in ref["snapshots"]]
+    reference = {int(rid): toks for rid, toks in ref["reference"].items()}
+    parent_misses = ref["misses"]
+    print(f"[router-soak] reference: {len(reference)} sessions, "
+          f"{sum(len(v) for v in reference.values())} tokens, "
+          f"{parent_misses} reference DSE misses "
+          f"(warm_loaded={ref['warm_loaded']})")
 
     # 2. partition round-robin and launch the worker fleet
     parts = {w: [] for w in range(args.workers)}
-    for i, r in enumerate(trace):
-        parts[i % args.workers].append(snapshots[r.rid])
+    for i, rid in enumerate(trace_rids):
+        parts[i % args.workers].append(snapshots[rid])
     procs = {}
     for wid, part in parts.items():
         reqfile = os.path.join(work, f"reqs_{wid}.json")
@@ -320,7 +371,7 @@ def main(argv=None):
 
     row = {
         "bench": "router_soak",
-        "arch": cfg.name, "backend": args.backend,
+        "arch": ref["arch"], "backend": args.backend,
         "workers": args.workers, "requests": args.requests,
         "slots": args.slots, "seed": args.seed,
         "kill_tick": args.kill_tick,

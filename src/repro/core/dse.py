@@ -30,7 +30,17 @@ import itertools
 from typing import Callable, Optional, Sequence
 
 from .fpga_model import Board, LayerSpec, TemplateInstance, evaluate_network
-from .tiling import ConvTiling, FCTiling, MatmulBlock, TPU_V5E, TpuSpec, ceil_div
+from .tiling import (
+    ConvTiling,
+    FCTiling,
+    MatmulBlock,
+    TPU_V5E,
+    TpuSpec,
+    ceil_div,
+    conv_chunk_rows,
+    dma_window_cols,
+    padded_bytes,
+)
 
 __all__ = [
     "DseResult",
@@ -174,6 +184,16 @@ def _infer_halo_mode(ho: int, wo: int, th: int, tw: int, halo_mode) -> str:
     return "two_block" if th < ho else "none"
 
 
+def _dma_window(th: int, tw: int, kh: int, kw: int, stride: int, in_bytes: int):
+    """(rows, cols) of the input window one DMA-regime tile copies — exactly
+    what kernels/conv2d.py allocates and fetches."""
+    return stride * th + kh - stride, dma_window_cols(stride * tw + kw - stride, in_bytes)
+
+
+def _lanes(c: int) -> int:
+    return ceil_div(c, TPU_V5E.lane) * TPU_V5E.lane
+
+
 def direct_conv_vmem(
     hp: int, wp: int, cin: int, kh: int, kw: int, ho: int, wo: int, tau: int,
     in_bytes: int, acc_bytes: int = 4, *, stride: int = 1, tile_rows: int = 0,
@@ -181,6 +201,9 @@ def direct_conv_vmem(
 ) -> int:
     """VMEM working set of one direct-conv grid step (double-buffered I/O).
 
+    Every buffer is counted as the chip lays it out
+    (:func:`~repro.core.tiling.padded_bytes`): channels pad to 128 lanes and
+    widths to whole sublane tiles, so a Cin=3 image slab costs 128 lanes.
     Three regimes (``halo_mode``, inferred from the tile dims when omitted):
 
     * ``"none"`` — untiled: the whole padded image slab is resident
@@ -188,36 +211,40 @@ def direct_conv_vmem(
     * ``"two_block"`` — row-tiled with blocked successor reads: each step
       holds *two* adjacent ``stride·tile_rows``-row full-width input blocks
       (the tile plus the successor supplying the ``kh − stride`` halo rows)
-      plus the same-sized concatenated copy the kernel materializes to
-      stitch them — a ~6× tile-rows residency.
+      plus the same-sized buffer the kernel stitches them into — a ~6×
+      tile-rows residency.
     * ``"dma"`` — (𝒯, ℭ)-tiled with manual async copies: exactly the
       ``stride·tile_rows + kh − stride`` × ``stride·tile_cols + kw −
       stride`` input window a tile reads, double-buffered (×2) for the
       prefetch pipeline — roughly half the two-block residency at equal
       tile_rows, and the only regime that tiles the width.
 
-    The accumulator/output shrink to tile_rows × tile_cols output pixels.
+    Besides the input: the (K², Cin, τ) weight block and the (1, τ) bias
+    (double-buffered), the (tile_rows, tile_cols, τ) output block
+    (double-buffered), and the values of one in-kernel chunk — the tap
+    operand (and its int8 digits on the fixed-point path), the product and
+    the accumulator (:data:`~repro.core.tiling.CONV_CHUNK_M` rows).
     """
     th, tw = _eff_tiles(ho, wo, tile_rows, tile_cols)
     mode = _infer_halo_mode(ho, wo, th, tw, halo_mode)
     if mode == "none":
-        x = hp * wp * cin * in_bytes * 2
+        x = 2 * hp * padded_bytes(wp, cin, in_bytes)
     elif mode == "two_block":
         if tw < wo:
             raise ValueError("two_block halo cannot tile columns (use 'dma')")
-        rows = 2 * stride * th
-        # two double-buffered input blocks + the in-kernel concat buffer
-        x = rows * wp * cin * in_bytes * 3
+        # two double-buffered input blocks + the in-kernel stitch buffer
+        x = 3 * 2 * stride * th * padded_bytes(wp, cin, in_bytes)
     elif mode == "dma":
-        rows_in = min(hp, stride * th + kh - stride)
-        cols_in = min(wp, stride * tw + kw - stride)
-        x = 2 * rows_in * cols_in * cin * in_bytes  # double-buffered window
+        rows_in, cols_in = _dma_window(th, tw, kh, kw, stride, in_bytes)
+        x = 2 * rows_in * padded_bytes(cols_in, cin, in_bytes)
     else:
         raise ValueError(f"unknown halo_mode {mode!r}")
-    w = kh * kw * cin * tau * in_bytes * 2
-    acc = th * tw * tau * acc_bytes
-    out = th * tw * tau * in_bytes * 2
-    return x + w + acc + out
+    w = 2 * kh * kw * padded_bytes(cin, tau, in_bytes)
+    bias = 2 * padded_bytes(1, tau, 4)
+    out = 2 * th * padded_bytes(tw, tau, in_bytes)
+    m = conv_chunk_rows(th, tw) * tw
+    chunk = 2 * padded_bytes(m, cin, in_bytes) + 3 * padded_bytes(m, tau, acc_bytes)
+    return x + w + bias + out + chunk
 
 
 def direct_conv_hbm_traffic(
@@ -228,7 +255,8 @@ def direct_conv_hbm_traffic(
     """Modeled HBM bytes one forward pass of the layer actually moves.
 
     The cost model behind the conv DSE score (and the bench table's
-    HBM-traffic column):
+    HBM-traffic column).  The image's channels count as whole 128-lane
+    tiles (its layout on the chip), in every regime:
 
     * the image streams once per τ-way (ceil(cout/τ) output-channel tiles);
       the two-block regime additionally re-streams every full-width block
@@ -246,14 +274,14 @@ def direct_conv_hbm_traffic(
     tiles_r = ceil_div(ho, th)
     tiles_c = ceil_div(wo, tw)
     tiles = tiles_r * tiles_c
+    lanes = _lanes(cin)
     if mode == "none":
-        x_traffic = ways * hp * wp * cin
+        x_traffic = ways * hp * wp * lanes
     elif mode == "two_block":
-        x_traffic = ways * tiles_r * 2 * stride * th * wp * cin
+        x_traffic = ways * tiles_r * 2 * stride * th * wp * lanes
     elif mode == "dma":
-        rows_in = min(hp, stride * th + kh - stride)
-        cols_in = min(wp, stride * tw + kw - stride)
-        x_traffic = ways * tiles * rows_in * cols_in * cin
+        rows_in, cols_in = _dma_window(th, tw, kh, kw, stride, in_bytes)
+        x_traffic = ways * tiles * min(hp, rows_in) * min(wp, cols_in) * lanes
     else:
         raise ValueError(f"unknown halo_mode {mode!r}")
     w_traffic = tiles * kh * kw * cin * coutp
@@ -397,9 +425,11 @@ def explore_conv_spatial(
     every exact divisor of the extent.  Three regimes are enumerated:
     untiled whole-slab, row-tiled two-block (legality: stride·tile_rows ≥
     kh so the successor block covers the tap window), and (𝒯, ℭ)-tiled
-    manual-DMA — which has no legality bound (the window always covers the
-    taps) and is the only regime that tiles the width, so extreme-width
-    layers stay direct instead of falling back to im2col.
+    manual-DMA — whose window always covers the taps and which is the only
+    regime that tiles the width, so extreme-width layers stay direct
+    instead of falling back to im2col.  Its one legality bound is the
+    chip's: a packed (16/8-bit) window starts on an 8-column group, so a
+    column tile must advance a multiple of 8 input columns.
     """
     tau0 = min(spec.lane, cout)
     taus = []
@@ -418,6 +448,9 @@ def explore_conv_spatial(
         for tw in _tile_ladder(wo, 1):
             if th >= ho and tw >= wo:
                 continue  # the untiled regime already covers the whole slab
+            if tw < wo and in_bytes < 4 and (stride * tw) % spec.sublane:
+                # packed (16/8-bit) windows must start on an 8-column group
+                continue
             configs.append((th, tw, "dma"))
     out: list[ConvTileChoice] = []
     for tau, (th, tw, mode) in itertools.product(taus, configs):

@@ -163,6 +163,19 @@ class PrecisionChoice:
     drift: Optional[float] = None
 
 
+def _timing_device():
+    """The device :meth:`PlanRegistry.measure_and_pin` times on: a TPU.
+    Anywhere else the kernels run interpreted and a timing says nothing
+    about the chip, so this raises."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise RuntimeError(
+            f"measure_and_pin times compiled kernels on a TPU; this process "
+            f"computes on {dev.platform!r}, where Pallas kernels run interpreted"
+        )
+    return dev
+
+
 def _spec_to_doc(spec: TpuSpec) -> dict:
     return dataclasses.asdict(spec)
 
@@ -291,18 +304,18 @@ class PlanRegistry:
         candidates: Optional[Sequence[MatmulBlock]] = None,
         top_k: int = 3,
         reps: int = 2,
-        interpret: bool = True,
         dtype=jnp.float32,
     ) -> MatmulBlock:
         """Time the top-K analytic candidates with real kernel launches and
         overwrite the registry entry with the fastest (``source: measured``).
 
-        On this CPU container ``interpret=True`` times the Pallas interpreter
-        rather than the MXU — the *mechanism* (measure, pick, pin, persist)
-        is what ships; on real hardware the same call times compiled kernels.
+        The timings are of compiled kernels on a TPU: on any other platform
+        the kernels would run interpreted, so this raises there
+        (:func:`_timing_device`) rather than pin an interpreter timing.
         """
         from repro.kernels import ops as kops
 
+        _timing_device()
         if candidates is None:
             ranked = dse.explore_tpu_block(m, n, k, spec, top=top_k)
             candidates = [blk for blk, _ in ranked]
@@ -314,7 +327,7 @@ class PlanRegistry:
         best, best_t = None, float("inf")
         for blk in candidates:
             run = lambda: jax.block_until_ready(
-                kops.matmul_fp(x, w, block=blk, interpret=interpret)
+                kops.matmul_fp(x, w, block=blk, vmem_limit_bytes=spec.vmem_bytes)
             )
             run()  # compile / first-touch outside the timed region
             t0 = time.perf_counter()
@@ -919,7 +932,6 @@ class Engine:
     def measure_and_pin(self, m: int, n: int, k: int, **kw) -> MatmulBlock:
         """Measured-time autotune for this engine's hardware spec — times the
         top-K analytic candidates and pins the winner in the registry."""
-        kw.setdefault("interpret", self.config.interpret)
         return self.plan_cache.measure_and_pin(m, n, k, self.config.hw, **kw)
 
     def plan_gemm(
@@ -1248,7 +1260,7 @@ class Engine:
         out = kops.matmul_q16(
             x2.raw, w.raw, bias=b_raw, relu=relu, fmt=out_fmt,
             shift=acc_frac - out_fmt.frac_bits, bias_shift=bias_shift,
-            wide=wide, block=block, interpret=self.config.interpret,
+            wide=wide, block=block, vmem_limit_bytes=self.config.hw.vmem_bytes,
         )
         if wide:
             self.counters["dequantize_calls"] += 1
@@ -1295,7 +1307,7 @@ class Engine:
             relu=relu, fmt=out_fmt, shift=acc_frac - out_fmt.frac_bits,
             bias_shift=bias_shift, route=plan.route, block=plan.block,
             tile_rows=plan.tile_rows, tile_cols=plan.tile_cols,
-            halo_mode=plan.halo_mode, interpret=self.config.interpret,
+            halo_mode=plan.halo_mode, vmem_limit_bytes=self.config.hw.vmem_bytes,
         )
         return QTensor(out, out_fmt)
 
@@ -1358,7 +1370,7 @@ class Engine:
             block = plan.block if plan is not None and plan.block is not None else self._adhoc_block(m, n, k)
             out = kops.matmul_fp(
                 x2, w, bias=bias, relu=relu, qout=qout, block=block,
-                interpret=self.config.interpret,
+                vmem_limit_bytes=self.config.hw.vmem_bytes,
             )
         elif backend == "q16":
             from repro.kernels import ops as kops
@@ -1379,7 +1391,7 @@ class Engine:
                 relu=relu,
                 fmt=fmt,
                 block=block,
-                interpret=self.config.interpret,
+                vmem_limit_bytes=self.config.hw.vmem_bytes,
             )
             out = dequantize(qres, fmt, dtype=x.dtype)
         else:  # pragma: no cover - config validation
@@ -1458,7 +1470,7 @@ class Engine:
                 x, w, bias=bias, stride=stride, padding=pad, tau=plan.tau,
                 relu=relu, qout=qout, route=plan.route, block=plan.block,
                 tile_rows=plan.tile_rows, tile_cols=plan.tile_cols,
-                halo_mode=plan.halo_mode, interpret=self.config.interpret,
+                halo_mode=plan.halo_mode, vmem_limit_bytes=self.config.hw.vmem_bytes,
             )
         assert backend == "q16", backend
         # legacy per-op fixed point (see matmul): quantize/dequantize every
@@ -1480,7 +1492,7 @@ class Engine:
             tile_rows=plan.tile_rows,
             tile_cols=plan.tile_cols,
             halo_mode=plan.halo_mode,
-            interpret=self.config.interpret,
+            vmem_limit_bytes=self.config.hw.vmem_bytes,
         )
         return dequantize(qres, fmt, dtype=x.dtype)
 
@@ -1491,8 +1503,9 @@ class Engine:
         with the slab dim (optionally) sharded over ``plan.halo.axis``.
         Exchange the halo rows with the neighbor shards, pre-pad W by the
         conv's ``pad`` (H zeros already came from the exchange's edge
-        fill), fold slabs into the batch dim for the planned per-shard
-        kernel, then restore the slab layout — masking the ragged tail
+        fill), run the planned per-shard kernel on each device's own slabs
+        folded into the batch dim (:func:`~repro.parallel.sharding.map_slabs`),
+        then restore the slab layout — masking the ragged tail
         shard's invalid rows back to zero so the *next* seam's halo reads
         stay exact.  Contraction dims never cross a shard boundary, so the
         result is bit-identical to the unsharded kernel per output row.
@@ -1510,14 +1523,19 @@ class Engine:
             ext = jnp.pad(
                 ext, ((0, 0), (0, 0), (0, 0), (hs.pad, hs.pad), (0, 0))
             )
-        s, n = ext.shape[0], ext.shape[1]
-        flat = ext.reshape(s * n, *ext.shape[2:])
-        out = self.conv2d(
-            QTensor(flat, x.fmt) if quant else flat, w,
-            bias=bias, relu=relu, qout=qout, plan=inner,
-        )
+        def per_shard(e, w, bias):
+            # this device's slabs, folded into the batch dim of the kernel
+            s, n = e.shape[0], e.shape[1]
+            flat = e.reshape(s * n, *e.shape[2:])
+            out = self.conv2d(
+                QTensor(flat, x.fmt) if quant else flat, w,
+                bias=bias, relu=relu, qout=qout, plan=inner,
+            )
+            return out.reshape(s, n, *out.shape[1:])
+
+        out = sh.map_slabs(per_shard, ext, w, bias, axis=hs.axis)
         qres = isinstance(out, QTensor)
-        ov = out.raw if qres else out
-        ov = ov.reshape(s, n, *ov.shape[1:])
-        ov = sh.constrain_slabs(sh.mask_slab_rows(ov, hs), hs.axis)
+        ov = sh.constrain_slabs(
+            sh.mask_slab_rows(out.raw if qres else out, hs), hs.axis
+        )
         return QTensor(ov, out.fmt) if qres else ov

@@ -12,8 +12,8 @@ projections, MLP, MoE expert FFNs and vocab projections all call
                    production path on real TPUs for the distributed graph.
   * ``"pallas"`` — the hand-tiled Pallas kernels (`kernels/matmul_fp.py`,
                    `kernels/conv2d.py`) with BlockSpec tiles chosen by the
-                   DSE (`core/dse.py`); the TPU-target artifact, validated
-                   interpret=True on CPU.
+                   DSE (`core/dse.py`); compiled on a TPU, interpreted on
+                   the CPU (`kernels/common.py`).
   * ``"q16"``    — the paper's 16-bit Q2.14 fixed-point numerics
                    (`kernels/matmul_q16.py`), for paper-faithful inference.
 
@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from .quantization import QFormat, Q2_14
-from .tiling import MatmulBlock, TPU_V5E, TpuSpec
+from .tiling import MatmulBlock, TpuSpec, device_spec
 
 __all__ = ["Template", "TemplateConfig", "default_template"]
 
@@ -44,13 +44,17 @@ Backend = Literal["xla", "pallas", "q16"]
 @dataclasses.dataclass(frozen=True)
 class TemplateConfig:
     """Hardware-specification half of the template (paper abstract:
-    'takes pre-trained weights ... and target hardware specification')."""
+    'takes pre-trained weights ... and target hardware specification').
+
+    ``hw`` defaults to the spec of the chip this process computes on
+    (:func:`~repro.core.tiling.device_spec`); whether Pallas kernels run
+    compiled or interpreted follows the platform too (kernels/common.py).
+    """
 
     backend: Backend = "xla"
     block: Optional[MatmulBlock] = None  # None => DSE picks per-shape (plan-cached)
     qformat: QFormat = Q2_14
-    hw: TpuSpec = TPU_V5E
-    interpret: bool = True  # CPU container: Pallas kernels run interpreted
+    hw: TpuSpec = dataclasses.field(default_factory=device_spec)
     #: GEMM output dtype; None = match the input dtype.  The TPU MXU
     #: accumulates bf16 products in f32 internally either way — requesting a
     #: bf16 *result* halves dot-output HBM traffic and lets the FSDP
@@ -61,7 +65,7 @@ class TemplateConfig:
 
 @dataclasses.dataclass(frozen=True)
 class Template:
-    config: TemplateConfig = TemplateConfig()
+    config: TemplateConfig = dataclasses.field(default_factory=TemplateConfig)
 
     # -- the execution-plan engine -------------------------------------------
 

@@ -22,9 +22,17 @@ __all__ = [
     "ConvTiling",
     "FCTiling",
     "MatmulBlock",
+    "REHEARSAL_DEVICE_KIND",
+    "TPU_SPECS",
     "TPU_V5E",
     "TpuSpec",
+    "CONV_CHUNK_M",
     "ceil_div",
+    "conv_chunk_rows",
+    "device_spec",
+    "dma_window_cols",
+    "padded_bytes",
+    "tpu_spec",
 ]
 
 
@@ -117,7 +125,12 @@ class FCTiling:
 
 @dataclasses.dataclass(frozen=True)
 class TpuSpec:
-    """Per-chip TPU hardware description used by tiling/DSE/roofline."""
+    """Per-chip TPU hardware description used by tiling/DSE/roofline.
+
+    ``vmem_bytes`` is the VMEM budget the DSE tiles against *and* the
+    ``vmem_limit_bytes`` every planned kernel is compiled with, so the plan
+    the compiler sees is the plan the DSE chose.
+    """
 
     name: str = "tpu_v5e"
     peak_bf16_flops: float = 197e12  # FLOP/s
@@ -129,7 +142,79 @@ class TpuSpec:
     sublane: int = 8  # second-minor dim granularity (f32)
 
 
+#: TPU v5e peaks: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
+#: 819 GB/s HBM).  VMEM: 128 MiB per core, of which the planner uses half.
 TPU_V5E = TpuSpec()
+
+#: Chip specs keyed by ``jax.Device.device_kind``.  A kind missing here is an
+#: error (:func:`tpu_spec`), never a silent default.
+TPU_SPECS = {"TPU v5 lite": TPU_V5E}
+
+#: The chip a CPU process rehearses: it plans for this kind and interprets
+#: the kernels (kernels/common.py).
+REHEARSAL_DEVICE_KIND = "TPU v5 lite"
+
+
+def tpu_spec(device_kind: str) -> TpuSpec:
+    """The spec of one TPU device kind; raises for a kind the table lacks."""
+    try:
+        return TPU_SPECS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no TpuSpec for device kind {device_kind!r}; known kinds: "
+            f"{sorted(TPU_SPECS)} (add its peaks and VMEM to TPU_SPECS)"
+        ) from None
+
+
+def device_spec(device=None) -> TpuSpec:
+    """The spec of the chip a process computes on (default: the first JAX
+    device).  A TPU is looked up by its device kind; a CPU process plans
+    for :data:`REHEARSAL_DEVICE_KIND`, the chip its interpreted kernels
+    stand in for.  Any other platform is an error."""
+    if device is None:
+        import jax
+
+        device = jax.devices()[0]
+    if device.platform == "cpu":
+        return tpu_spec(REHEARSAL_DEVICE_KIND)
+    if device.platform != "tpu":
+        raise ValueError(f"no TPU plan target for platform {device.platform!r}")
+    return tpu_spec(device.device_kind)
+
+
+def padded_bytes(rows: int, cols: int, itemsize: int, spec: TpuSpec = TPU_V5E) -> int:
+    """Bytes a (rows, cols) slab occupies on the chip: the minor dim pads to
+    whole 128-lane tiles and the second-minor dim to whole sublane tiles
+    (8 rows of 32-bit words; 16-bit and 8-bit values pack 2 and 4 rows per
+    word, so their tiles are 16 and 32 rows).  A Cin=3 image costs 128 lanes."""
+    sub = spec.sublane * max(1, 4 // itemsize)
+    return (ceil_div(rows, sub) * sub) * (ceil_div(cols, spec.lane) * spec.lane) * itemsize
+
+
+def dma_window_cols(cols: int, itemsize: int, spec: TpuSpec = TPU_V5E) -> int:
+    """Columns a manual-DMA conv window copies: packed (16/8-bit) windows
+    move whole 8-column groups, so their width rounds up to a multiple of
+    8; 32-bit windows copy exactly ``cols``."""
+    if itemsize >= 4:
+        return cols
+    return ceil_div(cols, spec.sublane) * spec.sublane
+
+
+#: Output pixels (GEMM rows) one in-kernel chunk of the direct conv
+#: contracts per tap (kernels/conv2d.py): it bounds the live tap operand and
+#: accumulator values of a grid step whatever the tile size.
+CONV_CHUNK_M = 256
+
+
+def conv_chunk_rows(th: int, tw: int) -> int:
+    """Output rows per in-kernel chunk of a (th, tw) conv tile: the largest
+    divisor of ``th`` whose ``rows × tw`` GEMM stays within
+    :data:`CONV_CHUNK_M` (at least one row)."""
+    best = 1
+    for r in range(1, th + 1):
+        if th % r == 0 and r * tw <= CONV_CHUNK_M:
+            best = r
+    return best
 
 
 @dataclasses.dataclass(frozen=True)

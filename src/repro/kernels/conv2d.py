@@ -8,11 +8,19 @@ TPU adaptation: instead of one (spatial, tap) position per cycle, each grid
 step keeps an image slab in VMEM and runs K² *matmuls* of shape
 (rows·Wo, Cin) x (Cin, τ) — the tap loop is unrolled (K is static) and each
 tap is an MXU-shaped GEMM, which is how the μ×τ wave generalizes to a 128×128
-systolic array.  Accumulation lives in a f32/i32 VMEM scratch across taps.
+systolic array.  Inside a grid step the output rows are walked in chunks of
+at most ``CONV_CHUNK_M`` GEMM rows (a ``fori_loop``; core/tiling.py), each
+chunk accumulating its K² tap products in registers before the fused
+epilogue writes it back, so the compiled kernel's size does not grow with
+the tile.
 
 Strided convs (AlexNet conv1) are handled *directly*: each tap reads a
 strided slice of the resident image slab (per-tap strided slicing), so the
 same kernel covers stride ∈ {1, 2, 4, ...} without falling back to im2col.
+
+The float and fixed-point kernels are one kernel with two contractions: f32
+taps at full f32 MXU precision, or int16/int8 taps through
+:func:`repro.kernels.common.int_dot` (exact int32).
 
 Spatial tiling (the paper's 𝒯/ℭ loop tiles, §III.B): when the whole image
 slab exceeds the VMEM budget, ``tile_rows`` adds an output-row tile axis to
@@ -49,7 +57,8 @@ round-trip through HBM between the GEMM and the nonlinearity (DESIGN.md §3).
 Grid: (N, ceil(Ho/tile_rows), Cout/τ) for the blocked regimes, with a
 ceil(Wo/tile_cols) axis inserted before the τ axis in the DMA regime; tile
 axes are 1 when untiled.  τ is the fastest axis so a DMA'd input window is
-fetched once and reused by every output-channel way.
+fetched once and reused by every output-channel way.  The weights are laid
+out (K², Cin, Cout) so each tap's (Cin, τ) slab is a leading-axis index.
 """
 from __future__ import annotations
 
@@ -61,34 +70,20 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quantization import QFormat, Q2_14, shift_saturate_i32
+from repro.core.tiling import conv_chunk_rows, dma_window_cols
+
+from .common import int_dot, pallas
 
 __all__ = ["conv2d_pallas", "conv2d_q16_pallas"]
 
 
-def _tap_patch(img, i, j, rows, wo, stride):
-    """Image slab -> (rows*Wo, Cin) GEMM rows for tap (i, j).
-
-    Per-tap strided slicing: output position (r, c) reads input pixel
-    (i + stride*r, j + stride*c), so tap (i, j)'s rows are a strided window
-    of the resident slab.
-    """
-    patch = img[
-        i : i + stride * (rows - 1) + 1 : stride,
-        j : j + stride * (wo - 1) + 1 : stride,
-        :,
-    ]
-    return patch.reshape(rows * wo, img.shape[-1])
+def _float_dot(lhs, rhs):
+    return jnp.dot(lhs, rhs, preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
 
 
-def _split_refs(refs, halo, fused_bias):
-    """refs -> (x1, x2 | None, w, bias | None, out, acc)."""
-    refs = list(refs)
-    x1 = refs.pop(0)
-    x2 = refs.pop(0) if halo else None
-    w = refs.pop(0)
-    b = refs.pop(0) if fused_bias else None
-    o, acc = refs
-    return x1, x2, w, b, o, acc
+def _span(start, size, stride):
+    return pl.ds(start, size, stride) if stride > 1 else pl.ds(start, size)
 
 
 def _float_epilogue(acc, b_ref, *, relu, qout):
@@ -112,26 +107,58 @@ def _q16_epilogue(acc, b_ref, *, relu, shift, bias_shift, raw_min, raw_max,
     return shift_saturate_i32(acc, shift, raw_min, raw_max, out_dtype)
 
 
-def _conv_kernel(*refs, kh, kw, th, wo, stride, relu, qout, halo, fused_bias):
-    # refs: x1 (1, rows, Wp, Cin) image block; x2 same-shape successor block
-    # (halo rows; only when spatially tiled); w (kh*kw*Cin, tau); optional
-    # bias (1, tau); out (1, th, wo, tau); acc scratch (th*wo, tau) f32.
-    x1_ref, x2_ref, w_ref, b_ref, o_ref, acc_ref = _split_refs(refs, halo, fused_bias)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
-    cin = x1_ref.shape[3]
-    img = x1_ref[0]
-    if halo:
-        # the tap window of the last output row in this tile reads up to
-        # stride*(th-1) + kh - 1 < 2*stride*th rows (stride*th >= kh), so
-        # the pair of adjacent row blocks always covers it.
-        img = jnp.concatenate([img, x2_ref[0]], axis=0)
-    for i in range(kh):
-        for j in range(kw):
-            lhs = _tap_patch(img, i, j, th, wo, stride)
-            rhs = w_ref[(i * kw + j) * cin : (i * kw + j + 1) * cin, :]
-            acc_ref[...] += jnp.dot(lhs, rhs, preferred_element_type=jnp.float32)
-    acc = _float_epilogue(acc_ref[...], b_ref, relu=relu, qout=qout)
-    o_ref[...] = acc.reshape(1, th, wo, -1).astype(o_ref.dtype)
+def _conv_tile(src, lead, w_ref, b_ref, o_ref, *, kh, kw, th, tw, stride,
+               dot, epilogue):
+    """Direct conv of one (th, tw) output tile.
+
+    ``src[lead]``: the (rows, cols, Cin) input window of the tile (``lead``
+    indexes the leading axes of ``src``; the kernel indexes the full ref
+    rather than taking a view of it, which Mosaic refuses for windows whose
+    minor dims are not tile-aligned); ``w_ref``: (K², Cin, τ); ``o_ref``:
+    (1, th, tw, τ).  Output rows are walked in
+    :func:`~repro.core.tiling.conv_chunk_rows` chunks; per chunk every tap is
+    one (rows·tw, Cin) × (Cin, τ) GEMM read straight from ``src`` (per-tap
+    strided slicing when ``stride`` > 1).
+    """
+    rc = conv_chunk_rows(th, tw)
+    cin = src.shape[-1]
+
+    def chunk(c, carry):
+        r0 = c * rc
+        acc = None
+        for i in range(kh):
+            for j in range(kw):
+                lhs = src[(*lead, _span(r0 * stride + i, rc, stride),
+                           _span(j, tw, stride), slice(None))]
+                d = dot(lhs.reshape(rc * tw, cin), w_ref[i * kw + j])
+                acc = d if acc is None else acc + d
+        out = epilogue(acc, b_ref)
+        o_ref[0, pl.ds(r0, rc), :, :] = out.reshape(rc, tw, -1).astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, th // rc, chunk, 0)
+
+
+def _conv_block_kernel(*refs, halo, fused_bias, **tile):
+    """Blocked regimes: refs = x1 (1, rows, Wp, Cin) image block [, x2 the
+    same-shape successor block], w, [bias,] out [, stitch scratch]."""
+    refs = list(refs)
+    x1 = refs.pop(0)
+    x2 = refs.pop(0) if halo else None
+    w_ref = refs.pop(0)
+    b_ref = refs.pop(0) if fused_bias else None
+    o_ref = refs.pop(0)
+    if not halo:
+        _conv_tile(x1, (0,), w_ref, b_ref, o_ref, **tile)
+        return
+    # the tap window of the last output row in this tile reads up to
+    # stride*(th-1) + kh - 1 < 2*stride*th rows (stride*th >= kh), so the
+    # pair of adjacent row blocks, stitched into one buffer, always covers it
+    (xs,) = refs
+    rows = x1.shape[1]
+    xs[:rows] = x1[0]
+    xs[rows:] = x2[0]
+    _conv_tile(xs, (), w_ref, b_ref, o_ref, **tile)
 
 
 # ---------------------------------------------------------------------------
@@ -139,8 +166,7 @@ def _conv_kernel(*refs, kh, kw, th, wo, stride, relu, qout, halo, fused_bias):
 # ---------------------------------------------------------------------------
 
 
-def _conv_dma_kernel(*refs, kh, kw, th, tw, stride, fixed_point, epilogue,
-                     fused_bias):
+def _conv_dma_kernel(*refs, fused_bias, **tile):
     """(𝒯, ℭ)-tiled direct conv with a manual-DMA input halo.
 
     The input operand lives in HBM (``memory_space=ANY``); each (r, c) tile
@@ -155,20 +181,21 @@ def _conv_dma_kernel(*refs, kh, kw, th, tw, stride, fixed_point, epilogue,
     x_hbm = refs.pop(0)  # (N, Hp', Wp', Cin), unblocked, HBM-resident
     w_ref = refs.pop(0)
     b_ref = refs.pop(0) if fused_bias else None
-    o_ref, xs_ref, sem, acc_ref = refs
+    o_ref, xs_ref, sem = refs
+    th, tw, stride = tile["th"], tile["tw"], tile["stride"]
     b = pl.program_id(0)
     r = pl.program_id(1)
     c = pl.program_id(2)
     t = pl.program_id(3)
     tiles_c = pl.num_programs(2)
     ways = pl.num_programs(3)
-    tile = r * tiles_c + c
+    tile_ix = r * tiles_c + c
     total = pl.num_programs(1) * tiles_c
-    rows_in, cols_in, cin = xs_ref.shape[1], xs_ref.shape[2], xs_ref.shape[3]
+    rows_in, cols_in = xs_ref.shape[1], xs_ref.shape[2]
 
-    def fetch(tile_ix, slot):
-        rr = tile_ix // tiles_c
-        cc = tile_ix % tiles_c
+    def fetch(ix, slot):
+        rr = ix // tiles_c
+        cc = ix % tiles_c
         return pltpu.make_async_copy(
             x_hbm.at[
                 b,
@@ -181,90 +208,21 @@ def _conv_dma_kernel(*refs, kh, kw, th, tw, stride, fixed_point, epilogue,
         )
 
     # warm-up: the first tile of each image has no predecessor to prefetch it
-    @pl.when((tile == 0) & (t == 0))
+    @pl.when((tile_ix == 0) & (t == 0))
     def _():
-        fetch(tile, tile % 2).start()
+        fetch(tile_ix, tile_ix % 2).start()
 
     # wait for this tile's window, once per tile (way 0)
     @pl.when(t == 0)
     def _():
-        fetch(tile, tile % 2).wait()
+        fetch(tile_ix, tile_ix % 2).wait()
 
     # prefetch the next tile's window into the other slot while computing
-    @pl.when((t == ways - 1) & (tile + 1 < total))
+    @pl.when((t == ways - 1) & (tile_ix + 1 < total))
     def _():
-        fetch(tile + 1, (tile + 1) % 2).start()
+        fetch(tile_ix + 1, (tile_ix + 1) % 2).start()
 
-    acc_ref[...] = jnp.zeros_like(acc_ref)
-    img = xs_ref[tile % 2]
-    for i in range(kh):
-        for j in range(kw):
-            lhs = _tap_patch(img, i, j, th, tw, stride)
-            rhs = w_ref[(i * kw + j) * cin : (i * kw + j + 1) * cin, :]
-            if fixed_point:
-                acc_ref[...] += jnp.dot(
-                    lhs.astype(jnp.int32), rhs.astype(jnp.int32),
-                    preferred_element_type=jnp.int32,
-                )
-            else:
-                acc_ref[...] += jnp.dot(lhs, rhs, preferred_element_type=jnp.float32)
-    out = epilogue(acc_ref[...], b_ref)
-    o_ref[...] = out.reshape(1, th, tw, -1).astype(o_ref.dtype)
-
-
-def _conv_dma_call(
-    x, wmat, bias_row, *, kh, kw, stride, ho, wo, cout, tau, coutp,
-    tile_rows, tile_cols, fixed_point, epilogue, out_dtype, acc_dtype,
-    interpret,
-):
-    """Shared pallas_call plumbing for the DMA-halo regime (float + q16).
-
-    Pads x so every tile's DMA window is in-bounds (zero rows/cols past the
-    image contribute zero products, so ragged edges stay exact), pads the
-    output grid to whole tiles, and slices both back to (Ho, Wo, Cout).
-    """
-    n, h, wdt, cin = x.shape
-    th = tile_rows if 0 < tile_rows < ho else ho
-    tw = tile_cols if 0 < tile_cols < wo else wo
-    tiles_r = -(-ho // th)
-    tiles_c = -(-wo // tw)
-    rows_in = stride * th + kh - stride
-    cols_in = stride * tw + kw - stride
-    need_h = stride * th * (tiles_r - 1) + rows_in
-    need_w = stride * tw * (tiles_c - 1) + cols_in
-    if need_h > h or need_w > wdt:
-        x = jnp.pad(
-            x, ((0, 0), (0, max(0, need_h - h)), (0, max(0, need_w - wdt)), (0, 0))
-        )
-    in_specs = [
-        pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-        pl.BlockSpec((kh * kw * cin, tau), lambda b, r, c, t: (0, t)),
-    ]
-    operands = [x, wmat]
-    if bias_row is not None:
-        operands.append(bias_row)
-        in_specs.append(pl.BlockSpec((1, tau), lambda b, r, c, t: (0, t)))
-    kernel = functools.partial(
-        _conv_dma_kernel, kh=kh, kw=kw, th=th, tw=tw, stride=stride,
-        fixed_point=fixed_point, epilogue=epilogue,
-        fused_bias=bias_row is not None,
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid=(n, tiles_r, tiles_c, coutp // tau),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, th, tw, tau), lambda b, r, c, t: (b, r, c, t)),
-        out_shape=jax.ShapeDtypeStruct(
-            (n, tiles_r * th, tiles_c * tw, coutp), out_dtype
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((2, rows_in, cols_in, cin), x.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.VMEM((th * tw, tau), acc_dtype),
-        ],
-        interpret=interpret,
-    )(*operands)
-    return out[:, :ho, :wo, :cout]
+    _conv_tile(xs_ref, (tile_ix % 2,), w_ref, b_ref, o_ref, **tile)
 
 
 def _halo_mode_for(tile_rows, tile_cols, ho, wo, halo_mode):
@@ -287,43 +245,125 @@ def _halo_mode_for(tile_rows, tile_cols, ho, wo, halo_mode):
     raise ValueError(f"unknown halo_mode {halo_mode!r}")
 
 
-def _conv_grid(x, kh, stride, ho, tile_rows):
-    """Shared grid/BlockSpec geometry for both conv kernels.
+def _conv_call(
+    x, w, bias_row, *, stride, tau, tile_rows, tile_cols, halo_mode, dot,
+    epilogue, out_dtype, vmem_limit_bytes,
+):
+    """Shared pallas_call plumbing of the float and fixed-point convs.
 
-    Returns (x, x_specs, grid_tiles, th, halo): ``x`` zero-row-padded so the
-    successor halo block of the last tile is always in range, ``th`` output
-    rows per grid step.
+    Pads Cout to whole τ-ways, pads x so every tile's input (blocked
+    successor or DMA window) is in-bounds — zero rows/cols past the image
+    contribute zero products, so ragged edges stay exact — pads the output
+    grid to whole tiles, and slices both back to (Ho, Wo, Cout).
     """
     n, h, wdt, cin = x.shape
+    kh, kw, cin2, cout = w.shape
+    assert cin == cin2
+    ho = (h - kh) // stride + 1
+    wo = (wdt - kw) // stride + 1
+    tau = min(tau, cout)
+    coutp = -(-cout // tau) * tau
+    if coutp != cout:
+        w = jnp.pad(w, ((0, 0), (0, 0), (0, 0), (0, coutp - cout)))
+        if bias_row is not None:
+            bias_row = jnp.pad(bias_row, ((0, 0), (0, coutp - cout)))
+    # tap-major (K², Cin, Cout): tap (i, j)'s slab is w[i*kw + j]
+    wtaps = w.reshape(kh * kw, cin, coutp)
+    mode = _halo_mode_for(tile_rows, tile_cols, ho, wo, halo_mode)
     th = tile_rows if 0 < tile_rows < ho else ho
-    tiles = -(-ho // th)
-    halo = tiles > 1
-    if not halo:
-        x_specs = [pl.BlockSpec((1, h, wdt, cin), lambda b, r, t: (b, 0, 0, 0))]
-        return x, x_specs, 1, th, False
-    row_in = stride * th  # input rows consumed per output-row tile
-    if row_in < kh:
-        raise ValueError(
-            f"tile_rows={th} too small: stride*tile_rows ({row_in}) must cover "
-            f"the {kh}-row tap window for the two-block halo scheme"
+    tw = tile_cols if 0 < tile_cols < wo else wo
+    tiles_r = -(-ho // th)
+    tiles_c = -(-wo // tw)
+    tile = dict(kh=kh, kw=kw, th=th, tw=tw, stride=stride, dot=dot,
+                epilogue=epilogue)
+    fused_bias = bias_row is not None
+    if mode == "dma":
+        # window copies move whole 128-lane channel tiles: zero channels
+        # (and zero weight rows) pad Cin up to one, exactly
+        cinp = -(-cin // 128) * 128
+        if cinp != cin:
+            x = jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, cinp - cin)))
+            wtaps = jnp.pad(wtaps, ((0, 0), (0, cinp - cin), (0, 0)))
+            cin = cinp
+        rows_in = stride * th + kh - stride
+        cols_in = dma_window_cols(stride * tw + kw - stride, x.dtype.itemsize)
+        need_h = stride * th * (tiles_r - 1) + rows_in
+        need_w = stride * tw * (tiles_c - 1) + cols_in
+        if need_h > h or need_w > wdt:
+            x = jnp.pad(
+                x, ((0, 0), (0, max(0, need_h - h)), (0, max(0, need_w - wdt)), (0, 0))
+            )
+        x_specs = [pl.BlockSpec(memory_space=pl.ANY)]
+        w_index = lambda b, r, c, t: (0, 0, t)  # noqa: E731
+        b_index = lambda b, r, c, t: (0, t)  # noqa: E731
+        grid = (n, tiles_r, tiles_c, coutp // tau)
+        out_index = lambda b, r, c, t: (b, r, c, t)  # noqa: E731
+        kernel = functools.partial(_conv_dma_kernel, fused_bias=fused_bias, **tile)
+        scratch = [
+            pltpu.VMEM((2, rows_in, cols_in, cin), x.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ]
+        # the prefetch pipeline carries state from tile to tile
+        semantics = ("parallel", "arbitrary", "arbitrary", "arbitrary")
+        operands = [x]
+    else:
+        halo = mode == "two_block"
+        if halo:
+            row_in = stride * th  # input rows consumed per output-row tile
+            if row_in < kh:
+                raise ValueError(
+                    f"tile_rows={th} too small: stride*tile_rows ({row_in}) must "
+                    f"cover the {kh}-row tap window for the two-block halo scheme"
+                )
+            # tile r reads blocks r and r+1; the last tile (and its ragged
+            # output rows) must see zeros past the real image
+            need = (tiles_r + 1) * row_in
+            if need > h:
+                x = jnp.pad(x, ((0, 0), (0, need - h), (0, 0), (0, 0)))
+            x_specs = [
+                pl.BlockSpec((1, row_in, wdt, cin), lambda b, r, t: (b, r, 0, 0)),
+                pl.BlockSpec((1, row_in, wdt, cin), lambda b, r, t: (b, r + 1, 0, 0)),
+            ]
+            scratch = [pltpu.VMEM((2 * row_in, wdt, cin), x.dtype)]
+            operands = [x, x]
+        else:
+            x_specs = [pl.BlockSpec((1, h, wdt, cin), lambda b, r, t: (b, 0, 0, 0))]
+            scratch = []
+            operands = [x]
+        w_index = lambda b, r, t: (0, 0, t)  # noqa: E731
+        b_index = lambda b, r, t: (0, t)  # noqa: E731
+        grid = (n, tiles_r, coutp // tau)
+        out_index = lambda b, r, t: (b, r, 0, t)  # noqa: E731
+        kernel = functools.partial(
+            _conv_block_kernel, halo=halo, fused_bias=fused_bias, **tile
         )
-    # tile r reads blocks r and r+1; the last tile (and its ragged output
-    # rows) must see zeros past the real image
-    need = (tiles + 1) * row_in
-    if need > h:
-        x = jnp.pad(x, ((0, 0), (0, need - h), (0, 0), (0, 0)))
-    x_specs = [
-        pl.BlockSpec((1, row_in, wdt, cin), lambda b, r, t: (b, r, 0, 0)),
-        pl.BlockSpec((1, row_in, wdt, cin), lambda b, r, t: (b, r + 1, 0, 0)),
-    ]
-    return x, x_specs, tiles, th, True
+        semantics = ("parallel", "parallel", "parallel")
+    in_specs = x_specs + [pl.BlockSpec((kh * kw, cin, tau), w_index)]
+    operands.append(wtaps)
+    if fused_bias:
+        in_specs.append(pl.BlockSpec((1, tau), b_index))
+        operands.append(bias_row)
+    out = pallas(
+        kernel,
+        name=f"conv_{mode}",
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, th, tw, tau), out_index),
+        out_shape=jax.ShapeDtypeStruct(
+            (n, tiles_r * th, tiles_c * tw, coutp), out_dtype
+        ),
+        scratch_shapes=scratch,
+        dimension_semantics=semantics,
+        vmem_limit_bytes=vmem_limit_bytes,
+    )(*operands)
+    return out[:, :ho, :wo, :cout]
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
         "stride", "tau", "relu", "qout", "tile_rows", "tile_cols", "halo_mode",
-        "interpret",
+        "vmem_limit_bytes",
     ),
 )
 def conv2d_pallas(
@@ -338,7 +378,7 @@ def conv2d_pallas(
     tile_rows: int = 0,
     tile_cols: int = 0,
     halo_mode: str = "two_block",
-    interpret: bool = False,
+    vmem_limit_bytes: int | None = None,
 ) -> jax.Array:
     """NHWC VALID conv, any stride.  x: (N,H,W,Cin), w: (K,K,Cin,Cout).
 
@@ -348,90 +388,22 @@ def conv2d_pallas(
     untiled on that axis); ``halo_mode`` picks the tiled input regime —
     "two_block" (blocked successor reads, rows only) or "dma" (exact-window
     async copies, required for column tiling).  The engine picks all three
-    so the working set fits VMEM (DESIGN.md §2).
+    so the working set fits ``vmem_limit_bytes`` (DESIGN.md §2).
     """
-    n, h, wdt, cin = x.shape
-    kh, kw, cin2, cout = w.shape
-    assert cin == cin2
-    ho = (h - kh) // stride + 1
-    wo = (wdt - kw) // stride + 1
-    tau = min(tau, cout)
-    coutp = -(-cout // tau) * tau
-    if coutp != cout:
-        w = jnp.pad(w, ((0, 0), (0, 0), (0, 0), (0, coutp - cout)))
-    # (kh*kw*cin, cout) with rows ordered (tap-major, cin-minor) to match the
-    # kernel's per-tap row slices.
-    wmat = w.reshape(kh * kw * cin, coutp)
-    if _halo_mode_for(tile_rows, tile_cols, ho, wo, halo_mode) == "dma":
-        bias_row = None
-        if bias is not None:
-            bias_row = jnp.pad(
-                bias.astype(jnp.float32), (0, coutp - cout)
-            ).reshape(1, coutp)
-        return _conv_dma_call(
-            x, wmat, bias_row, kh=kh, kw=kw, stride=stride, ho=ho, wo=wo,
-            cout=cout, tau=tau, coutp=coutp, tile_rows=tile_rows,
-            tile_cols=tile_cols, fixed_point=False,
-            epilogue=functools.partial(_float_epilogue, relu=relu, qout=qout),
-            out_dtype=x.dtype, acc_dtype=jnp.float32, interpret=interpret,
-        )
-    x, x_specs, tiles, th, halo = _conv_grid(x, kh, stride, ho, tile_rows)
-    operands = [x] * (2 if halo else 1) + [wmat]
-    in_specs = x_specs + [pl.BlockSpec((kh * kw * cin, tau), lambda b, r, t: (0, t))]
-    if bias is not None:
-        operands.append(
-            jnp.pad(bias.astype(jnp.float32), (0, coutp - cout)).reshape(1, coutp)
-        )
-        in_specs.append(pl.BlockSpec((1, tau), lambda b, r, t: (0, t)))
-
-    kernel = functools.partial(
-        _conv_kernel, kh=kh, kw=kw, th=th, wo=wo, stride=stride, relu=relu,
-        qout=qout, halo=halo, fused_bias=bias is not None,
+    bias_row = None if bias is None else bias.astype(jnp.float32).reshape(1, -1)
+    return _conv_call(
+        x, w, bias_row, stride=stride, tau=tau, tile_rows=tile_rows,
+        tile_cols=tile_cols, halo_mode=halo_mode, dot=_float_dot,
+        epilogue=functools.partial(_float_epilogue, relu=relu, qout=qout),
+        out_dtype=x.dtype, vmem_limit_bytes=vmem_limit_bytes,
     )
-    out = pl.pallas_call(
-        kernel,
-        grid=(n, tiles, coutp // tau),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, th, wo, tau), lambda b, r, t: (b, r, 0, t)),
-        out_shape=jax.ShapeDtypeStruct((n, tiles * th, wo, coutp), x.dtype),
-        scratch_shapes=[pltpu.VMEM((th * wo, tau), jnp.float32)],
-        interpret=interpret,
-    )(*operands)
-    return out[:, :ho, :, :cout]
-
-
-def _conv_q16_kernel(
-    *refs, kh, kw, th, wo, stride, relu, shift, bias_shift, raw_min, raw_max,
-    out_dtype, halo, fused_bias
-):
-    # Same dataflow as _conv_kernel, fixed point: int16/int8 taps accumulated
-    # in int32 (DESIGN.md §2), saturating round-shift write-back to the output
-    # Q format's storage rung.  ``shift`` = fa+fb-fo for x(Qa.fa) x w(Qb.fb)
-    # -> Qm.fo; ``bias_shift`` aligns the raw bias onto the 2^(fa+fb)
-    # accumulator.
-    x1_ref, x2_ref, w_ref, b_ref, o_ref, acc_ref = _split_refs(refs, halo, fused_bias)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
-    cin = x1_ref.shape[3]
-    img = x1_ref[0]
-    if halo:
-        img = jnp.concatenate([img, x2_ref[0]], axis=0)
-    for i in range(kh):
-        for j in range(kw):
-            lhs = _tap_patch(img, i, j, th, wo, stride).astype(jnp.int32)
-            rhs = w_ref[(i * kw + j) * cin : (i * kw + j + 1) * cin, :].astype(jnp.int32)
-            acc_ref[...] += jnp.dot(lhs, rhs, preferred_element_type=jnp.int32)
-    out = _q16_epilogue(
-        acc_ref[...], b_ref, relu=relu, shift=shift, bias_shift=bias_shift,
-        raw_min=raw_min, raw_max=raw_max, out_dtype=out_dtype,
-    )
-    o_ref[...] = out.reshape(1, th, wo, -1)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
         "stride", "tau", "relu", "fmt", "shift", "bias_shift", "tile_rows",
-        "tile_cols", "halo_mode", "interpret",
+        "tile_cols", "halo_mode", "vmem_limit_bytes",
     ),
 )
 def conv2d_q16_pallas(
@@ -448,7 +420,7 @@ def conv2d_q16_pallas(
     tile_rows: int = 0,
     tile_cols: int = 0,
     halo_mode: str = "two_block",
-    interpret: bool = False,
+    vmem_limit_bytes: int | None = None,
 ) -> jax.Array:
     """Fixed-point NHWC VALID conv, any stride.  int16/int8 raw Qm.n tensors.
 
@@ -456,77 +428,24 @@ def conv2d_q16_pallas(
     in :func:`conv2d_pallas`; zero-padded halo rows/columns contribute zero
     products and integer accumulation is order-exact, so every tiling (and
     both halo regimes) is bit-identical to the untiled kernel.  Mixed operand
-    widths are legal (both sides widen to int32 before the tap GEMMs) and the
+    widths are legal (the tap products are exact int32 either way) and the
     output is stored on ``fmt.storage_dtype``; ``shift`` / ``bias_shift``
     override the write-back scale gaps for mixed-format operands (default:
     same-format Qm.n semantics) — an int8-rung ``fmt`` with an int16-grid
     ``shift`` is the mixed-boundary epilogue of DESIGN.md §11.
     """
     assert xq.dtype in (jnp.int8, jnp.int16) and wq.dtype in (jnp.int8, jnp.int16)
-    n, h, wdt, cin = xq.shape
-    kh, kw, cin2, cout = wq.shape
-    assert cin == cin2
-    ho = (h - kh) // stride + 1
-    wo = (wdt - kw) // stride + 1
-    tau = min(tau, cout)
-    coutp = -(-cout // tau) * tau
-    if coutp != cout:
-        wq = jnp.pad(wq, ((0, 0), (0, 0), (0, 0), (0, coutp - cout)))
-    wmat = wq.reshape(kh * kw * cin, coutp)
-    if _halo_mode_for(tile_rows, tile_cols, ho, wo, halo_mode) == "dma":
-        bias_row = None
-        if bias is not None:
-            bias_row = jnp.pad(
-                bias.astype(jnp.int16), (0, coutp - cout)
-            ).reshape(1, coutp)
-        epilogue = functools.partial(
-            _q16_epilogue, relu=relu,
-            shift=fmt.frac_bits if shift is None else shift,
-            bias_shift=fmt.frac_bits if bias_shift is None else bias_shift,
-            raw_min=fmt.raw_min, raw_max=fmt.raw_max,
-            out_dtype=fmt.storage_dtype,
-        )
-        return _conv_dma_call(
-            xq, wmat, bias_row, kh=kh, kw=kw, stride=stride, ho=ho, wo=wo,
-            cout=cout, tau=tau, coutp=coutp, tile_rows=tile_rows,
-            tile_cols=tile_cols, fixed_point=True, epilogue=epilogue,
-            out_dtype=fmt.storage_dtype, acc_dtype=jnp.int32,
-            interpret=interpret,
-        )
-    xq, x_specs, tiles, th, halo = _conv_grid(xq, kh, stride, ho, tile_rows)
-    operands = [xq] * (2 if halo else 1) + [wmat]
-    in_specs = x_specs + [pl.BlockSpec((kh * kw * cin, tau), lambda b, r, t: (0, t))]
-    if bias is not None:
-        operands.append(
-            jnp.pad(bias.astype(jnp.int16), (0, coutp - cout)).reshape(1, coutp)
-        )
-        in_specs.append(pl.BlockSpec((1, tau), lambda b, r, t: (0, t)))
-
-    kernel = functools.partial(
-        _conv_q16_kernel,
-        kh=kh,
-        kw=kw,
-        th=th,
-        wo=wo,
-        stride=stride,
-        relu=relu,
+    bias_row = None if bias is None else bias.astype(jnp.int16).reshape(1, -1)
+    epilogue = functools.partial(
+        _q16_epilogue, relu=relu,
         shift=fmt.frac_bits if shift is None else shift,
         bias_shift=fmt.frac_bits if bias_shift is None else bias_shift,
-        raw_min=fmt.raw_min,
-        raw_max=fmt.raw_max,
+        raw_min=fmt.raw_min, raw_max=fmt.raw_max,
         out_dtype=fmt.storage_dtype,
-        halo=halo,
-        fused_bias=bias is not None,
     )
-    out = pl.pallas_call(
-        kernel,
-        grid=(n, tiles, coutp // tau),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, th, wo, tau), lambda b, r, t: (b, r, 0, t)),
-        out_shape=jax.ShapeDtypeStruct(
-            (n, tiles * th, wo, coutp), fmt.storage_dtype
-        ),
-        scratch_shapes=[pltpu.VMEM((th * wo, tau), jnp.int32)],
-        interpret=interpret,
-    )(*operands)
-    return out[:, :ho, :, :cout]
+    return _conv_call(
+        xq, wq, bias_row, stride=stride, tau=tau, tile_rows=tile_rows,
+        tile_cols=tile_cols, halo_mode=halo_mode, dot=int_dot,
+        epilogue=epilogue, out_dtype=fmt.storage_dtype,
+        vmem_limit_bytes=vmem_limit_bytes,
+    )

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .common import pallas
+
 __all__ = ["flash_attention_pallas"]
 
 _NEG_INF = -1e30
@@ -68,7 +70,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *, bq, bk, sca
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "bq", "bk", "interpret", "q_offset")
+    jax.jit, static_argnames=("causal", "bq", "bk", "q_offset")
 )
 def flash_attention_pallas(
     q: jax.Array,
@@ -79,7 +81,6 @@ def flash_attention_pallas(
     bq: int = 256,
     bk: int = 256,
     q_offset: int = 0,
-    interpret: bool = False,
 ) -> jax.Array:
     """q: (BH, Sq, D), k/v: (BH, Sk, D) -> (BH, Sq, D).
 
@@ -105,8 +106,9 @@ def flash_attention_pallas(
     kernel = functools.partial(
         _fa_kernel, bq=bq, bk=bk, scale=scale, causal=causal, q_offset=q_offset
     )
-    out = pl.pallas_call(
+    out = pallas(
         kernel,
+        name="flash_attention",
         grid=(bh, sqp // bq, skp // bk),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
@@ -120,6 +122,7 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        interpret=interpret,
+        # the streaming softmax carries (m, l, acc) across the kv axis
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
     )(q, k, v)
     return out[:, :sq, :]

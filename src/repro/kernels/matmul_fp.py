@@ -26,6 +26,8 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.quantization import QFormat
 from repro.core.tiling import MatmulBlock
 
+from .common import pallas
+
 __all__ = ["matmul_fp_pallas"]
 
 
@@ -42,8 +44,11 @@ def _mm_kernel(*refs, relu, qout):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # HIGHEST: f32 operands contract at full f32 precision on the MXU (its
+    # multi-pass mode) rather than rounded to one bf16 pass
     acc_ref[...] += jnp.dot(
-        x_ref[...], w_ref[...], preferred_element_type=jnp.float32
+        x_ref[...], w_ref[...], preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
@@ -60,18 +65,9 @@ def _mm_kernel(*refs, relu, qout):
         o_ref[...] = acc.astype(o_ref.dtype)
 
 
-def _compiler_params():
-    # grid axes: (m parallel, n parallel, k sequential/arbitrary)
-    params_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
-    )
-    if params_cls is None:  # pragma: no cover - very old jax
-        return None
-    return params_cls(dimension_semantics=("parallel", "parallel", "arbitrary"))
-
-
 @functools.partial(
-    jax.jit, static_argnames=("block", "relu", "qout", "interpret", "out_dtype")
+    jax.jit,
+    static_argnames=("block", "relu", "qout", "out_dtype", "vmem_limit_bytes"),
 )
 def matmul_fp_pallas(
     x: jax.Array,
@@ -81,13 +77,14 @@ def matmul_fp_pallas(
     block: MatmulBlock = MatmulBlock(256, 256, 256),
     relu: bool = False,
     qout: QFormat | None = None,
-    interpret: bool = False,
     out_dtype=None,
+    vmem_limit_bytes: int | None = None,
 ) -> jax.Array:
     """x: (m, k) @ w: (k, n) -> (m, n). Pads to block multiples internally.
 
     ``bias``: (n,) fused into the last-k write-back; ``relu``/``qout``: fused
     nonlinearity and (fake-)quantization, applied after bias.
+    ``vmem_limit_bytes``: the VMEM budget the block was planned against.
     """
     m, k = x.shape
     k2, n = w.shape
@@ -109,20 +106,16 @@ def matmul_fp_pallas(
         operands.append(jnp.pad(bias.astype(jnp.float32), (0, np_ - n)).reshape(1, np_))
         in_specs.append(pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)))
 
-    grid = (mp // bm, np_ // bn, kp // bk)
-    kwargs = {}
-    cp = _compiler_params()
-    if cp is not None and not interpret:
-        kwargs["compiler_params"] = cp
     kernel = functools.partial(_mm_kernel, relu=relu, qout=qout)
-    out = pl.pallas_call(
+    out = pallas(
         kernel,
-        grid=grid,
+        name="matmul_fp",
+        grid=(mp // bm, np_ // bn, kp // bk),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
-        **kwargs,
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=vmem_limit_bytes,
     )(*operands)
     return out[:m, :n]

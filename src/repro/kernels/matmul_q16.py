@@ -1,12 +1,13 @@
 """The paper's fixed-point compute unit as a Pallas kernel (int16 and int8).
 
-int16/int8 x int16/int8 products accumulated in int32 (TPU-native
+int16/int8 x int16/int8 products accumulated in int32 (the MXU's int8
+digit products, :func:`repro.kernels.common.int_dot`; TPU-native
 accumulator width; the FPGA DSP48 cascade is 48-bit — difference documented
 in DESIGN.md §2), then a saturating round-shift write-back onto the output
 format's storage rung (Q2.14 int16, Q1.7/Q2.6 int8, ...), exactly matching
 ``repro.core.quantization.qmatmul_ref`` / ``qtensor_matmul_ref``.  Mixed
-operand widths are legal — both sides widen to int32 before the MXU dot —
-and an int8-rung ``fmt`` with an int16-grid accumulator shift *is* the
+operand widths are legal — each side splits into as many int8 digits as
+its width needs — and an int8-rung ``fmt`` with an int16-grid accumulator shift *is* the
 mixed-boundary epilogue (DESIGN.md §11): the layer writes its successor's
 grid directly, no float hop.
 """
@@ -21,6 +22,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quantization import QFormat, Q2_14, shift_saturate_i32
 from repro.core.tiling import MatmulBlock
+
+from .common import int_dot, pallas
 
 __all__ = ["matmul_q16_pallas"]
 
@@ -38,11 +41,7 @@ def _qmm_kernel(*refs, shift, bias_shift, raw_min, raw_max, relu, wide,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        x_ref[...].astype(jnp.int32),
-        w_ref[...].astype(jnp.int32),
-        preferred_element_type=jnp.int32,
-    )
+    acc_ref[...] += int_dot(x_ref[...], w_ref[...])
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _write_back():
@@ -66,7 +65,9 @@ def _qmm_kernel(*refs, shift, bias_shift, raw_min, raw_max, relu, wide,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("fmt", "block", "relu", "shift", "bias_shift", "wide", "interpret"),
+    static_argnames=(
+        "fmt", "block", "relu", "shift", "bias_shift", "wide", "vmem_limit_bytes",
+    ),
 )
 def matmul_q16_pallas(
     xq: jax.Array,
@@ -79,18 +80,19 @@ def matmul_q16_pallas(
     shift: int | None = None,
     bias_shift: int | None = None,
     wide: bool = False,
-    interpret: bool = False,
+    vmem_limit_bytes: int | None = None,
 ) -> jax.Array:
     """xq: (m, k) raw @ wq: (k, n) raw -> (m, n) raw on ``fmt``'s rung.
 
-    Operands are int16 or int8 raws (mixed widths are fine — both widen to
-    int32 before the dot) and the output is stored as ``fmt.storage_dtype``.
+    Operands are int16 or int8 raws (mixed widths are fine — the product is
+    exact int32 either way) and the output is stored as ``fmt.storage_dtype``.
     ``bias``: (n,) int16/int8 raw, fused into the write-back; ``relu``:
     fused on the int32 accumulator before the saturating shift.  ``shift`` /
     ``bias_shift`` override the write-back scale gaps for mixed-format
     operands (default: same-format semantics, one ``fmt.frac_bits`` each);
     ``wide=True`` returns the raw int32 accumulator (no requantize) for the
-    final-layer read-out.
+    final-layer read-out.  ``vmem_limit_bytes``: the VMEM budget the block
+    was planned against.
     """
     assert xq.dtype in (jnp.int8, jnp.int16) and wq.dtype in (jnp.int8, jnp.int16)
     m, k = xq.shape
@@ -122,8 +124,9 @@ def matmul_q16_pallas(
         wide=wide,
         out_dtype=fmt.storage_dtype,
     )
-    out = pl.pallas_call(
+    out = pallas(
         kernel,
+        name="matmul_q16",
         grid=(mp // bm, np_ // bn, kp // bk),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
@@ -131,6 +134,7 @@ def matmul_q16_pallas(
             (mp, np_), jnp.int32 if wide else fmt.storage_dtype
         ),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        interpret=interpret,
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=vmem_limit_bytes,
     )(*operands)
     return out[:m, :n]

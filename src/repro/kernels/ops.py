@@ -90,13 +90,14 @@ def matmul_fp(
     relu: bool = False,
     qout: QFormat | None = None,
     block: MatmulBlock | None = None,
-    interpret: bool = False,
+    vmem_limit_bytes: int | None = None,
 ) -> jax.Array:
     m, k = x.shape
     n = w.shape[1]
     block = clamp_block(m, n, k, block or MatmulBlock(256, 256, 256))
     return matmul_fp_pallas(
-        x, w, bias, block=block, relu=relu, qout=qout, interpret=interpret
+        x, w, bias, block=block, relu=relu, qout=qout,
+        vmem_limit_bytes=vmem_limit_bytes,
     )
 
 
@@ -111,14 +112,14 @@ def matmul_q16(
     bias_shift: int | None = None,
     wide: bool = False,
     block: MatmulBlock | None = None,
-    interpret: bool = False,
+    vmem_limit_bytes: int | None = None,
 ) -> jax.Array:
     m, k = xq.shape
     n = wq.shape[1]
     block = clamp_block(m, n, k, block or MatmulBlock(256, 256, 256))
     return matmul_q16_pallas(
         xq, wq, bias, fmt=fmt, block=block, relu=relu, shift=shift,
-        bias_shift=bias_shift, wide=wide, interpret=interpret
+        bias_shift=bias_shift, wide=wide, vmem_limit_bytes=vmem_limit_bytes,
     )
 
 
@@ -142,7 +143,7 @@ def conv2d(
     tile_rows: int = 0,
     tile_cols: int = 0,
     halo_mode: str = "two_block",
-    interpret: bool = False,
+    vmem_limit_bytes: int | None = None,
 ) -> jax.Array:
     """NHWC conv on the unified compute unit, float path.
 
@@ -162,7 +163,7 @@ def conv2d(
         return conv2d_pallas(
             x, w, bias, stride=stride, tau=tau, relu=relu, qout=qout,
             tile_rows=tile_rows, tile_cols=tile_cols, halo_mode=halo_mode,
-            interpret=interpret,
+            vmem_limit_bytes=vmem_limit_bytes,
         )
     assert route == "im2col", route
     n = x.shape[0]
@@ -170,7 +171,7 @@ def conv2d(
     cols, ho, wo = im2col(x, kh, kw, stride)
     out = matmul_fp(
         cols, conv_gemm_weights(w), bias=bias, relu=relu, qout=qout,
-        block=block, interpret=interpret,
+        block=block, vmem_limit_bytes=vmem_limit_bytes,
     )
     return out.reshape(n, ho, wo, cout)
 
@@ -192,7 +193,7 @@ def conv2d_q16(
     tile_rows: int = 0,
     tile_cols: int = 0,
     halo_mode: str = "two_block",
-    interpret: bool = False,
+    vmem_limit_bytes: int | None = None,
 ) -> jax.Array:
     """NHWC conv, fixed-point path.  All tensors int16 raw Qm.n; ``shift`` /
     ``bias_shift`` carry mixed-format write-back gaps (see matmul_q16)."""
@@ -202,7 +203,8 @@ def conv2d_q16(
         return conv2d_q16_pallas(
             xq, wq, bias, stride=stride, tau=tau, relu=relu, fmt=fmt,
             shift=shift, bias_shift=bias_shift, tile_rows=tile_rows,
-            tile_cols=tile_cols, halo_mode=halo_mode, interpret=interpret,
+            tile_cols=tile_cols, halo_mode=halo_mode,
+            vmem_limit_bytes=vmem_limit_bytes,
         )
     assert route == "im2col", route
     n = xq.shape[0]
@@ -210,7 +212,8 @@ def conv2d_q16(
     cols, ho, wo = im2col(xq, kh, kw, stride)
     out = matmul_q16(
         cols, conv_gemm_weights(wq), bias=bias, relu=relu, fmt=fmt,
-        shift=shift, bias_shift=bias_shift, block=block, interpret=interpret,
+        shift=shift, bias_shift=bias_shift, block=block,
+        vmem_limit_bytes=vmem_limit_bytes,
     )
     return out.reshape(n, ho, wo, cout)
 
@@ -224,7 +227,6 @@ def flash_attention(
     q_offset: int = 0,
     bq: int = 256,
     bk: int = 256,
-    interpret: bool = False,
 ) -> jax.Array:
     """GQA-aware attention.  q: (B, Hq, Sq, D), k/v: (B, Hkv, Sk, D).
 
@@ -243,6 +245,6 @@ def flash_attention(
     kf = jnp.broadcast_to(k[:, :, None], (b, hkv, g, sk, d)).reshape(b * hkv * g, sk, d)
     vf = jnp.broadcast_to(v[:, :, None], (b, hkv, g, sk, d)).reshape(b * hkv * g, sk, d)
     out = flash_attention_pallas(
-        qf, kf, vf, causal=causal, q_offset=q_offset, bq=bq, bk=bk, interpret=interpret
+        qf, kf, vf, causal=causal, q_offset=q_offset, bq=bq, bk=bk
     )
     return out.reshape(b, hq, sq, d)

@@ -7,7 +7,9 @@ import jax.numpy as jnp
 from repro.core.quantization import (
     QFormat,
     Q2_14,
+    QTensor,
     qmatmul_ref as _qmatmul_core,
+    requantize_i32,
     requantize_i32_to_i16,
 )
 
@@ -19,6 +21,7 @@ __all__ = [
     "conv2d_ref",
     "conv2d_fused_ref",
     "conv2d_q16_ref",
+    "conv2d_qtensor_ref",
     "attention_ref",
 ]
 
@@ -126,6 +129,16 @@ def conv2d_q16_ref(
     """
     if padding:
         xq = jnp.pad(xq, ((0, 0), (padding, padding), (padding, padding), (0, 0)))
+    acc = _conv_i32(xq, wq, stride)
+    if bq is not None:
+        acc = acc + (bq.astype(jnp.int32) << fmt.frac_bits)
+    if relu:
+        acc = jnp.maximum(acc, 0)
+    return requantize_i32_to_i16(acc, fmt)
+
+
+def _conv_i32(xq: jax.Array, wq: jax.Array, stride: int) -> jax.Array:
+    """Exact int32 VALID conv accumulator by the tap loop (no padding)."""
     n, h, wd, cin = xq.shape
     kh, kw, _, cout = wq.shape
     ho = (h - kh) // stride + 1
@@ -142,11 +155,37 @@ def conv2d_q16_ref(
             acc = acc + jnp.einsum(
                 "nhwc,cd->nhwd", patch, wq[i, j].astype(jnp.int32)
             )
-    if bq is not None:
-        acc = acc + (bq.astype(jnp.int32) << fmt.frac_bits)
+    return acc
+
+
+def conv2d_qtensor_ref(
+    x: QTensor,
+    w: QTensor,
+    out_fmt: QFormat,
+    bias: QTensor | None = None,
+    *,
+    stride: int = 1,
+    padding: int = 0,
+    relu: bool = False,
+) -> QTensor:
+    """Mixed-format oracle for the grid-resident conv (DESIGN.md §8) — the
+    conv twin of :func:`repro.core.quantization.qtensor_matmul_ref`.
+
+    x: (N,H,W,Cin) Qa.fa, w: (K,K,Cin,Cout) Qb.fb -> (N,Ho,Wo,Cout) on
+    ``out_fmt``: exact int32 tap-loop accumulation at scale 2^(fa+fb), the
+    raw bias aligned onto it by ``fa + fb - fc``, ReLU on the accumulator,
+    then the saturating round-shift write-back.
+    """
+    xq = x.raw
+    if padding:
+        xq = jnp.pad(xq, ((0, 0), (padding, padding), (padding, padding), (0, 0)))
+    acc = _conv_i32(xq, w.raw, stride)
+    acc_frac = x.fmt.frac_bits + w.fmt.frac_bits
+    if bias is not None:
+        acc = acc + (bias.raw.astype(jnp.int32) << (acc_frac - bias.fmt.frac_bits))
     if relu:
         acc = jnp.maximum(acc, 0)
-    return requantize_i32_to_i16(acc, fmt)
+    return QTensor(requantize_i32(acc, acc_frac - out_fmt.frac_bits, out_fmt), out_fmt)
 
 
 def attention_ref(

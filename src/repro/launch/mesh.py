@@ -12,12 +12,17 @@ Topology (TPU v5e):
 "model" maps to the intra-pod ICI dimension with the densest wiring (TP and
 EP collectives are latency-bound); "data"/"pod" carry the FSDP/DP collectives
 (bandwidth-bound all-gather / reduce-scatter, DCN-tolerant across pods).
+
+Every mesh of the repo is built by :func:`make_mesh`, whose axes are Auto:
+the sharding layer steers GSPMD with ``with_sharding_constraint``
+(parallel/sharding.py), which only Auto axes accept.
 """
 from __future__ import annotations
 
 import jax
 
 __all__ = [
+    "make_mesh",
     "make_production_mesh",
     "make_test_mesh",
     "mesh_name",
@@ -26,17 +31,31 @@ __all__ = [
 ]
 
 
+def make_mesh(shape, axes, *, devices=None):
+    """The one mesh constructor: ``shape`` over ``axes``, every axis Auto
+    (``jax.make_mesh`` defaults to Explicit axes, which the sharding
+    constraints of parallel/sharding.py refuse).  ``devices`` defaults to
+    ``jax.devices()``; pass a topology's devices to compile for a chip that
+    is described rather than attached."""
+    from jax.sharding import AxisType
+
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(*, multi_pod: bool = False):
     """Shrunken topology for CI-scale dry-run tests (8 host devices)."""
     shape = (2, 2, 2) if multi_pod else (2, 2)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def mesh_name(mesh) -> str:

@@ -30,6 +30,7 @@ from repro.launch.scheduler import (
     replay_trace,
     sampler_fn,
 )
+from repro.launch.mesh import make_mesh
 from repro.launch.router import ReplicaRouter
 from repro.models import transformer as T
 
@@ -43,7 +44,7 @@ def shards_mesh(shards: int):
     if n % shards:
         raise SystemExit(
             f"--shards {shards} does not divide the {n} visible devices")
-    return jax.make_mesh((n // shards, shards), ("data", "model"))
+    return make_mesh((n // shards, shards), ("data", "model"))
 
 
 def run_router(cfg, params, tpl, *, replicas: int, mesh=None,
@@ -324,4 +325,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

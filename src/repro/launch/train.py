@@ -16,6 +16,8 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
+import tempfile
 import time
 
 import jax
@@ -50,7 +52,8 @@ def main(argv=None):
     ap.add_argument("--reduced", action="store_true", default=True)
     ap.add_argument("--full", dest="reduced", action="store_false")
     ap.add_argument("--mesh", choices=["none", "single", "multi"], default="none")
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir",
+                    default=os.path.join(tempfile.gettempdir(), "repro_ckpt"))
     ap.add_argument("--ckpt-every", type=int, default=10)
     ap.add_argument("--accum", type=int, default=1)
     ap.add_argument("--lr", type=float, default=1e-3)
@@ -175,4 +178,7 @@ class _null_ctx:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -45,6 +45,7 @@ __all__ = [
     "calibrate_cnn_policy",
     "calibrate_cnn_precision",
     "cnn_forward",
+    "cnn_forward_ref",
 ]
 
 
@@ -214,35 +215,35 @@ class NetworkPlan:
     feat_h: int = 0  # global H entering the conv→FC flatten gather
 
     def describe(self) -> list[str]:
-        """One line per layer: route, τ, spatial tiles, modeled VMEM.
+        """One line per layer: route, τ, halo regime, spatial tiles and the
+        modeled VMEM bytes of a grid step (the budget the kernel is compiled
+        with must hold them).
 
         The human-readable face of the plan — ``benchmarks/kernel_table.py``
-        prints it so route/tile regressions show up in benchmark diffs
-        between PRs.
+        and ``chip_smoke.py`` print it so route/tile regressions show up in
+        benchmark diffs between PRs.
         """
         lines = []
         for i, cp in enumerate(self.convs):
+            # (𝒯, ℭ) tile grid and per-tile output dims, e.g.
+            # "tiles=2x4(256rx128c)"; 0 = the whole extent on that axis
+            tiling = ""
             if cp.spatial_tiles > 1 or cp.col_tiles > 1:
-                # (𝒯, ℭ) tile grid, per-tile output dims, and halo regime,
-                # e.g. "tiles=2x4(256rx128c,dma)" or "tiles=4x1(8r,two_block)"
-                dims = f"{cp.tile_rows}r"
-                if cp.col_tiles > 1:
-                    dims += f"x{cp.tile_cols}c"
                 tiling = (
-                    f"tiles={cp.spatial_tiles}x{cp.col_tiles}"
-                    f"({dims},{cp.halo_mode})"
+                    f" tiles={cp.spatial_tiles}x{cp.col_tiles}"
+                    f"({cp.tile_rows}rx{cp.tile_cols}c)"
                 )
-            else:
-                tiling = "untiled"
             halo = ""
             if cp.halo is not None:
                 halo = (
                     f" halo=S{cp.halo.shards}"
                     f"(up{cp.halo.up},dn{cp.halo.dn},win{cp.halo.win})"
                 )
+            mode = cp.halo_mode if cp.route == "direct" else "-"
             lines.append(
-                f"conv{i}: route={cp.route} tau={cp.tau} {tiling} "
-                f"vmem={cp.vmem_bytes / 2**20:.1f}MiB gemm={cp.gemm}{halo}"
+                f"conv{i}: route={cp.route} tau={cp.tau} halo_mode={mode}"
+                f"{tiling} vmem={cp.vmem_bytes}B "
+                f"({cp.vmem_bytes / 2**20:.1f}MiB) gemm={cp.gemm}{halo}"
             )
         for i, gp in enumerate(self.fcs):
             blk = (gp.block.bm, gp.block.bn, gp.block.bk) if gp.block else None
@@ -577,16 +578,21 @@ def cnn_forward(
         if plan.spatial > 1:
             h = _gather_slabs(h, plan.feat_h)
         h = h.reshape(h.shape[0], -1)
-        last = len(params["fcs"]) - 1
-        for i, (p, gp) in enumerate(zip(params["fcs"], plan.fcs)):
-            if i < last:
-                h = tpl.linear(h, p["w"], p["b"], relu=True,
-                               qout=policy.fmt_for(names[nc + i + 1]), plan=gp)
-            else:
-                # final classifier: exact accumulator read-out (the single
-                # counted dequantize of the whole network)
-                h = tpl.linear(h, p["w"], p["b"], wide=True, plan=gp)
-        return h
+
+        def head(h, fcs):
+            last = len(fcs) - 1
+            for i, (p, gp) in enumerate(zip(fcs, plan.fcs)):
+                if i < last:
+                    h = tpl.linear(h, p["w"], p["b"], relu=True,
+                                   qout=policy.fmt_for(names[nc + i + 1]),
+                                   plan=gp)
+                else:
+                    # final classifier: exact accumulator read-out (the
+                    # single counted dequantize of the whole network)
+                    h = tpl.linear(h, p["w"], p["b"], wide=True, plan=gp)
+            return h
+
+        return _fc_head(plan, head, h, params["fcs"])
     plan = plan or plan_cnn(tpl, spec, x.shape)
     halos = plan.pool_halos or (None,) * len(plan.convs)
     fq = (lambda a: fake_quant_fmt(a, fmt)) if quantized else (lambda a: a)
@@ -606,10 +612,89 @@ def cnn_forward(
     if plan.spatial > 1:
         h = _gather_slabs(h, plan.feat_h)
     h = h.reshape(h.shape[0], -1)
+
+    def head(h, fcs):
+        last = len(fcs) - 1
+        for i, (p, gp) in enumerate(zip(fcs, plan.fcs)):
+            h = tpl.linear(
+                h, fq(p["w"]), fq(p["b"]),
+                relu=i < last, qout=qo if i < last else None, plan=gp,
+            )
+        return h
+
+    return _fc_head(plan, head, h, params["fcs"])
+
+
+def _fc_head(plan: NetworkPlan, head, h, fcs):
+    """Run the FC layers after the flatten seam.  Under a spatial plan the
+    gathered features are replicated and so is the head: every device runs
+    it whole (:func:`~repro.parallel.sharding.on_every_device` — a Pallas
+    kernel in a multi-device program has to be placed explicitly)."""
+    if plan.spatial > 1:
+        from repro.parallel.sharding import on_every_device
+
+        return on_every_device(head, h, fcs)
+    return head(h, fcs)
+
+
+def cnn_forward_ref(spec: CNNSpec, params, x: jax.Array, *,
+                    policy: Optional[NumericsPolicy] = None) -> jax.Array:
+    """Plain-``jax.numpy`` reference of :func:`cnn_forward` — no template,
+    no plan, no Pallas — for checking the engine's forward on a chip.
+
+    Float (``policy`` None or float): f32 convs and dense layers at full f32
+    precision (``Precision.HIGHEST``), bias, ReLU, max pool.  Quantized
+    (a quantized ``policy`` with a :func:`quantize_cnn_params` tree): the
+    grid-resident semantics of :func:`cnn_forward` op for op on the int
+    raws — the input quantized once, every conv/FC the mixed-format oracle
+    (:func:`repro.kernels.ref.conv2d_qtensor_ref`,
+    :func:`repro.core.quantization.qtensor_matmul_ref`) writing its
+    successor's grid, pools on the raws, and the classifier's int32
+    accumulator read out exactly — so it is bit-identical to the engine.
+    """
+    from repro.core.quantization import qtensor_matmul_ref, quantize
+    from repro.kernels.ref import conv2d_qtensor_ref
+
+    hi = jax.lax.Precision.HIGHEST
+    if policy is None or not policy.quantized:
+        h = x.astype(jnp.float32)
+        for p, (cout, k, stride, pad, pool) in zip(params["convs"], spec.convs):
+            h = jax.lax.conv_general_dilated(
+                h, p["w"].astype(jnp.float32), (stride, stride),
+                [(pad, pad), (pad, pad)],
+                dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=hi,
+            )
+            h = jnp.maximum(h + p["b"], 0.0)
+            if pool:
+                h = _maxpool(h, pool)
+        h = h.reshape(h.shape[0], -1)
+        last = len(params["fcs"]) - 1
+        for i, p in enumerate(params["fcs"]):
+            h = jnp.dot(h, p["w"].astype(jnp.float32), precision=hi) + p["b"]
+            if i < last:
+                h = jnp.maximum(h, 0.0)
+        return h
+    names = cnn_layer_names(spec)
+    fmt0 = policy.fmt_for(names[0])
+    h = QTensor(quantize(x, fmt0), fmt0)
+    nc = len(spec.convs)
+    for i, (p, (cout, k, stride, pad, pool)) in enumerate(
+        zip(params["convs"], spec.convs)
+    ):
+        h = conv2d_qtensor_ref(h, p["w"], policy.fmt_for(names[i + 1]),
+                               p["b"], stride=stride, padding=pad, relu=True)
+        if pool:
+            h = _maxpool(h, pool)
+    h = h.reshape(h.shape[0], -1)
     last = len(params["fcs"]) - 1
-    for i, (p, gp) in enumerate(zip(params["fcs"], plan.fcs)):
-        h = tpl.linear(
-            h, fq(p["w"]), fq(p["b"]),
-            relu=i < last, qout=qo if i < last else None, plan=gp,
-        )
-    return h
+    for i, p in enumerate(params["fcs"]):
+        if i < last:
+            h = qtensor_matmul_ref(h, p["w"], policy.fmt_for(names[nc + i + 1]),
+                                   bias=p["b"], relu=True)
+            continue
+        acc_frac = h.fmt.frac_bits + p["w"].fmt.frac_bits
+        acc = jnp.dot(h.raw.astype(jnp.int32), p["w"].raw.astype(jnp.int32),
+                      preferred_element_type=jnp.int32)
+        acc = acc + (p["b"].raw.astype(jnp.int32)
+                     << (acc_frac - p["b"].fmt.frac_bits))
+        return acc.astype(jnp.float32) * 2.0 ** -acc_frac

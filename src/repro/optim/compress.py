@@ -100,15 +100,13 @@ def compressed_grad_reduce(grads, mesh, axis: str = "data",
 
         grads, ef_state = apply_error_feedback(grads, ef_state, comp, decomp)
 
-    from jax.experimental.shard_map import shard_map
-
     n = mesh.shape[axis]
 
     def reduce_fn(g):
         return jax.tree.map(lambda x: compressed_psum(x, axis) / n, g)
 
     spec = jax.tree.map(lambda _: P(), grads)
-    fn = shard_map(
-        reduce_fn, mesh=mesh, in_specs=(spec,), out_specs=spec, check_rep=False
+    fn = jax.shard_map(
+        reduce_fn, mesh=mesh, in_specs=(spec,), out_specs=spec, check_vma=False
     )
     return fn(grads), ef_state

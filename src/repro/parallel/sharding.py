@@ -41,6 +41,8 @@ __all__ = [
     "logical_to_spec",
     "constrain",
     "constrain_slabs",
+    "map_slabs",
+    "on_every_device",
     "named_sharding",
     "tree_shardings",
     "plan_spatial_halo",
@@ -490,6 +492,42 @@ def constrain_slabs(v: jax.Array, axis: Optional[str]) -> jax.Array:
     return jax.lax.with_sharding_constraint(
         v, NamedSharding(mesh, P(axis))
     )
+
+
+def map_slabs(fn, v: jax.Array, *consts, axis: Optional[str]):
+    """Run ``fn(v_local, *consts)`` on each device's own slabs.
+
+    ``v`` is slab-major with its leading (slab) dim sharded over ``axis``
+    of the active mesh; ``consts`` (weights, bias) are replicated.  A Pallas
+    kernel is a custom call GSPMD cannot partition (Mosaic refuses to
+    compile one in a multi-device program outside a ``shard_map``), so the
+    map is what puts each slab's conv on the device that holds the slab.  Plain
+    ``fn(v, *consts)`` without a mesh that has ``axis`` or when the slab
+    count does not divide it (the module's one drop rule).
+    """
+    mesh = _CTX.mesh
+    if (axis is None or mesh is None or axis not in mesh.axis_names
+            or v.shape[0] % mesh.shape[axis]):
+        return fn(v, *consts)
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=(P(axis),) + (P(),) * len(consts),
+        out_specs=P(axis), check_vma=False,
+    )(v, *consts)
+
+
+def on_every_device(fn, *args):
+    """Run ``fn(*args)`` whole on every device of the active mesh, inputs
+    and output replicated.  GSPMD cannot partition a Pallas kernel, so a
+    kernel in a multi-device program is placed explicitly — here, or per
+    shard by :func:`map_slabs`.  Plain ``fn(*args)`` without a mesh of more
+    than one device."""
+    mesh = _CTX.mesh
+    if mesh is None or mesh.size == 1:
+        return fn(*args)
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=(P(),) * len(args), out_specs=P(),
+        check_vma=False,
+    )(*args)
 
 
 def spatial_halo_bytes(hs: SpatialHalo, n: int, w: int, c: int,

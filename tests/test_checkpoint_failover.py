@@ -16,6 +16,7 @@ from repro.runtime import (
     run_with_restarts,
 )
 from repro.runtime.failover import plan_elastic_remesh
+from repro.launch.mesh import make_mesh
 
 
 def _tree(seed=0):
@@ -82,7 +83,7 @@ def test_elastic_restore_different_rules(tmp_path):
 
     t = {"w": jnp.arange(16, dtype=jnp.float32).reshape(4, 4)}
     save(str(tmp_path), 2, t)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sh = {"w": NamedSharding(mesh, P())}
     back = restore(str(tmp_path), 2, t, sh)
     np.testing.assert_array_equal(np.asarray(back["w"]), np.asarray(t["w"]))

@@ -31,7 +31,7 @@ def _rand(shape, scale=0.3, salt=0):
 def test_direct_conv_float_vs_ref(stride, padding):
     from repro.kernels import ref
 
-    eng = Engine(TemplateConfig(backend="pallas", interpret=True))
+    eng = Engine(TemplateConfig(backend="pallas"))
     x = _rand((2, 13, 13, 5), salt=1)
     w = _rand((3, 3, 5, 8), salt=2)
     b = _rand((8,), scale=0.1, salt=3)
@@ -55,7 +55,7 @@ def test_direct_conv_q16_vs_ref(stride, padding):
     xq, wq, bq = quantize(x), quantize(w), quantize(b)
     pad = 1 if padding == "SAME" else 0
     out = ops.conv2d_q16(
-        xq, wq, bias=bq, stride=stride, padding=pad, relu=True, interpret=True
+        xq, wq, bias=bq, stride=stride, padding=pad, relu=True
     )
     want = ref.conv2d_q16_ref(xq, wq, bq, stride=stride, padding=pad, relu=True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
@@ -68,7 +68,7 @@ def test_conv_odd_cout_tau_padding(route):
 
     x = _rand((1, 9, 9, 4), salt=7)
     w = _rand((3, 3, 4, 10), salt=8)
-    out = ops.conv2d(x, w, stride=2, padding=1, tau=8, route=route, interpret=True)
+    out = ops.conv2d(x, w, stride=2, padding=1, tau=8, route=route)
     want = ref.conv2d_ref(x, w, stride=2, padding=1)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-4, rtol=1e-4)
 
@@ -79,7 +79,7 @@ def test_conv_q16_odd_cout_tau_padding():
     x = _rand((1, 9, 9, 4), salt=9)
     w = _rand((3, 3, 4, 10), salt=10)
     xq, wq = quantize(x), quantize(w)
-    out = ops.conv2d_q16(xq, wq, stride=1, padding=1, tau=8, interpret=True)
+    out = ops.conv2d_q16(xq, wq, stride=1, padding=1, tau=8)
     want = ref.conv2d_q16_ref(xq, wq, stride=1, padding=1)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
 
@@ -95,7 +95,7 @@ def test_matmul_fp_fused_epilogue():
     x = _rand((33, 47), salt=11)
     w = _rand((47, 19), salt=12)
     b = _rand((19,), scale=0.1, salt=13)
-    out = ops.matmul_fp(x, w, bias=b, relu=True, qout=Q2_14, interpret=True)
+    out = ops.matmul_fp(x, w, bias=b, relu=True, qout=Q2_14)
     want = ref.matmul_fused_ref(x, w, b, relu=True, qout=Q2_14)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-6, rtol=1e-6)
 
@@ -107,7 +107,7 @@ def test_matmul_q16_fused_epilogue():
     w = _rand((40, 16), salt=15)
     b = _rand((16,), scale=0.1, salt=16)
     xq, wq, bq = quantize(x), quantize(w), quantize(b)
-    out = ops.matmul_q16(xq, wq, bias=bq, relu=True, interpret=True)
+    out = ops.matmul_q16(xq, wq, bias=bq, relu=True)
     want = ref.matmul_q16_fused_ref(xq, wq, bq, relu=True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
 
@@ -152,7 +152,7 @@ def test_plan_conv_direct_selection_is_plan_cached(monkeypatch):
 
     monkeypatch.setattr(dse, "default_conv_tile_for", counting)
     cache = PlanCache()
-    eng = Engine(TemplateConfig(backend="pallas", interpret=True), plan_cache=cache)
+    eng = Engine(TemplateConfig(backend="pallas"), plan_cache=cache)
     p1 = eng.plan_conv((1, 32, 32, 8), (3, 3, 8, 16))
     p2 = eng.plan_conv((1, 32, 32, 8), (3, 3, 8, 16))
     assert p1 == p2 and p1.route == "direct"
@@ -231,7 +231,7 @@ def test_conv_vmem_overflow_falls_back_to_im2col():
     # this layer (ISSUE 8 halved the direct route's residency, so the old
     # 64 KiB budget now legitimately fits a direct config)
     hw = dataclasses.replace(TPU_V5E, vmem_bytes=16 * 1024)
-    eng = Engine(TemplateConfig(backend="pallas", interpret=True, hw=hw))
+    eng = Engine(TemplateConfig(backend="pallas", hw=hw))
     plan = eng.plan_conv((1, 64, 64, 32), (3, 3, 32, 64))
     assert plan.route == "im2col"
     assert plan.block is not None
@@ -331,7 +331,7 @@ def test_adhoc_matmul_plans_local_shape_under_mesh():
     sharded program executes — not the global one."""
     from repro.parallel.sharding import TRAIN_RULES, use_mesh
 
-    eng = Engine(TemplateConfig(backend="pallas", interpret=True),
+    eng = Engine(TemplateConfig(backend="pallas"),
                  plan_cache=PlanCache())
     x = jax.random.normal(KEY, (8, 16))
     w = jax.random.normal(jax.random.fold_in(KEY, 1), (16, 32))
@@ -348,7 +348,7 @@ def test_adhoc_matmul_plans_local_shape_under_mesh():
 def test_adhoc_conv2d_plans_local_shape_under_mesh():
     from repro.parallel.sharding import TRAIN_RULES, use_mesh
 
-    eng = Engine(TemplateConfig(backend="pallas", interpret=True),
+    eng = Engine(TemplateConfig(backend="pallas"),
                  plan_cache=PlanCache())
     x = jax.random.normal(KEY, (4, 8, 8, 4)) * 0.3
     w = jax.random.normal(jax.random.fold_in(KEY, 2), (3, 3, 4, 8)) * 0.3

@@ -1,4 +1,4 @@
-"""Per-kernel shape/dtype sweeps vs the pure-jnp oracles (interpret=True)."""
+"""Per-kernel shape/dtype sweeps vs the pure-jnp oracles (kernels interpreted on the CPU)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -35,7 +35,7 @@ MM_SHAPES = [
 def test_matmul_fp_vs_ref(m, k, n, dtype):
     x = _rand((m, k), dtype)
     w = _rand((k, n), dtype)
-    out = ops.matmul_fp(x, w, interpret=True)
+    out = ops.matmul_fp(x, w)
     want = ref.matmul_ref(x, w)
     assert out.dtype == want.dtype
     tol = 1e-4 if dtype == jnp.float32 else 2e-2
@@ -46,7 +46,7 @@ def test_matmul_fp_vs_ref(m, k, n, dtype):
 def test_matmul_fp_custom_block():
     x = _rand((64, 96))
     w = _rand((96, 80))
-    out = ops.matmul_fp(x, w, block=MatmulBlock(32, 128, 128), interpret=True)
+    out = ops.matmul_fp(x, w, block=MatmulBlock(32, 128, 128))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref.matmul_ref(x, w)),
                                atol=1e-4, rtol=1e-4)
 
@@ -63,7 +63,7 @@ def test_matmul_q16_vs_ref(m, k, n, fmt):
     x = _rand((m, k), scale=0.2)
     w = _rand((k, n), scale=0.2)
     xq, wq = quantize(x, fmt), quantize(w, fmt)
-    out = ops.matmul_q16(xq, wq, fmt=fmt, interpret=True)
+    out = ops.matmul_q16(xq, wq, fmt=fmt)
     want = ref.matmul_q16_ref(xq, wq, fmt)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
 
@@ -86,7 +86,7 @@ CONV_CASES = [
 def test_conv2d_vs_ref(n, h, w, cin, cout, k, stride, pad):
     x = _rand((n, h, w, cin))
     wt = _rand((k, k, cin, cout), scale=0.3)
-    out = ops.conv2d(x, wt, stride=stride, padding=pad, interpret=True)
+    out = ops.conv2d(x, wt, stride=stride, padding=pad)
     want = ref.conv2d_ref(x, wt, stride=stride, padding=pad)
     assert out.shape == want.shape
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-3, rtol=2e-3)
@@ -111,7 +111,7 @@ def test_flash_attention_vs_ref(b, hq, hkv, sq, sk, d, causal):
     q = _rand((b, hq, sq, d), scale=0.5)
     k = _rand((b, hkv, sk, d), scale=0.5)
     v = _rand((b, hkv, sk, d), scale=0.5)
-    out = ops.flash_attention(q, k, v, causal=causal, bq=32, bk=32, interpret=True)
+    out = ops.flash_attention(q, k, v, causal=causal, bq=32, bk=32)
     g = hq // hkv
     qf = q.reshape(b, hkv, g, sq, d).reshape(b * hq, sq, d)
     kf = jnp.broadcast_to(k[:, :, None], (b, hkv, g, sk, d)).reshape(b * hq, sk, d)
@@ -127,7 +127,7 @@ def test_flash_attention_q_offset():
     k = _rand((b, h, sk, d), scale=0.5)
     v = _rand((b, h, sk, d), scale=0.5)
     out = ops.flash_attention(q, k, v, causal=True, q_offset=sk - sq,
-                              bq=16, bk=16, interpret=True)
+                              bq=16, bk=16)
     want = ref.attention_ref(
         q.reshape(b * h, sq, d), k.reshape(b * h, sk, d), v.reshape(b * h, sk, d),
         causal=True, q_offset=sk - sq,

@@ -85,7 +85,7 @@ def test_engine_mixed_width_matmul_bitexact_vs_oracle(seed, widths, out_fmt):
     """Engine grid-resident GEMM with any q8/q16 operand combination and
     either output rung == qtensor_matmul_ref bit-for-bit, bias + relu
     fused — the mixed-boundary epilogue is the same shift write-back."""
-    eng = Engine(TemplateConfig(backend="q16", interpret=True))
+    eng = Engine(TemplateConfig(backend="q16"))
     xf = Q2_6 if widths.startswith("q8") else Q2_14
     wf = Q3_5 if widths.endswith("q8") else Q3_13
     rng = np.random.default_rng(seed)

@@ -13,6 +13,7 @@ from repro.core.template import default_template
 from repro.launch.steps import make_train_step
 from repro.models import transformer as T
 from repro.optim import adamw_init
+from repro.launch.mesh import make_mesh
 
 ARCHS = sorted(all_configs())
 TPL = default_template()
@@ -131,7 +132,7 @@ def test_param_axes_structure_matches_params():
     from repro.models.transformer import _is_axes_leaf
     from repro.parallel.sharding import TRAIN_RULES, tree_shardings
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     for name in ARCHS:
         cfg = reduced(all_configs()[name])
         params = jax.eval_shape(lambda c=cfg: T.init_params(jax.random.PRNGKey(0), c))

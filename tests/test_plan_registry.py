@@ -50,11 +50,11 @@ def _populated_registry():
     """A registry holding a GEMM block, a direct conv tile, and — via a
     tiny-VMEM spec — a cached no-fit sentinel plus the fallback GEMM block."""
     reg = PlanRegistry()
-    eng = Engine(TemplateConfig(backend="pallas", interpret=True), plan_cache=reg)
+    eng = Engine(TemplateConfig(backend="pallas"), plan_cache=reg)
     g = eng.plan_gemm(256, 512, 256)
     c = eng.plan_conv((1, 32, 32, 8), (3, 3, 8, 16), stride=1, padding=1)
     tiny = Engine(
-        TemplateConfig(backend="pallas", interpret=True, hw=TINY_HW), plan_cache=reg
+        TemplateConfig(backend="pallas", hw=TINY_HW), plan_cache=reg
     )
     c_nofit = tiny.plan_conv((1, 64, 64, 32), (3, 3, 32, 64))
     assert c.route == "direct" and c_nofit.route == "im2col"
@@ -88,11 +88,11 @@ def test_round_trip_bit_identical(tmp_path, monkeypatch):
     assert loaded.misses == 0 and loaded.hits == 0, "loads are not lookups"
 
     _forbid_searches(monkeypatch)
-    eng = Engine(TemplateConfig(backend="pallas", interpret=True), plan_cache=loaded)
+    eng = Engine(TemplateConfig(backend="pallas"), plan_cache=loaded)
     assert eng.plan_gemm(256, 512, 256) == g
     assert eng.plan_conv((1, 32, 32, 8), (3, 3, 8, 16), stride=1, padding=1) == c
     tiny = Engine(
-        TemplateConfig(backend="pallas", interpret=True, hw=TINY_HW), plan_cache=loaded
+        TemplateConfig(backend="pallas", hw=TINY_HW), plan_cache=loaded
     )
     assert tiny.plan_conv((1, 64, 64, 32), (3, 3, 32, 64)) == c_nofit
     assert loaded.misses == 0
@@ -180,7 +180,7 @@ def test_v2_store_migrates_gemm_and_conv_without_precision(tmp_path):
     assert loaded._conv_tiles == reg._conv_tiles
     assert loaded.precision_plan("lenet") == {}
     # the migrated plans still serve without a search
-    eng = Engine(TemplateConfig(backend="pallas", interpret=True),
+    eng = Engine(TemplateConfig(backend="pallas"),
                  plan_cache=loaded)
     assert eng.plan_gemm(256, 512, 256) == g
     assert eng.plan_conv((1, 32, 32, 8), (3, 3, 8, 16), stride=1, padding=1) == c
@@ -290,7 +290,21 @@ def test_missing_file_rejected_unless_missing_ok(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def test_measure_and_pin_overwrites_with_provenance(tmp_path):
+def _time_here(monkeypatch):
+    """Let measure_and_pin time the interpreted kernels of this CPU process:
+    the pin mechanism is under test here, not the chip's timings."""
+    import repro.core.engine as engine_mod
+
+    monkeypatch.setattr(engine_mod, "_timing_device", lambda: None)
+
+
+def test_measure_and_pin_refuses_the_interpreter():
+    with pytest.raises(RuntimeError, match="TPU"):
+        PlanRegistry().measure_and_pin(128, 256, 128, reps=1)
+
+
+def test_measure_and_pin_overwrites_with_provenance(tmp_path, monkeypatch):
+    _time_here(monkeypatch)
     reg = PlanRegistry()
     analytic = reg.block_for(128, 256, 128)
     assert reg.source_for(128, 256, 128) == "analytic"
@@ -312,9 +326,10 @@ def test_measure_and_pin_overwrites_with_provenance(tmp_path):
     del analytic
 
 
-def test_measure_and_pin_picks_from_candidates():
+def test_measure_and_pin_picks_from_candidates(monkeypatch):
     from repro.core.tiling import MatmulBlock
 
+    _time_here(monkeypatch)
     reg = PlanRegistry()
     cands = [MatmulBlock(128, 128, 128), MatmulBlock(256, 128, 128)]
     best = reg.measure_and_pin(256, 128, 128, candidates=cands, reps=1)
@@ -325,6 +340,7 @@ def test_merge_never_downgrades_measured_pins(tmp_path, monkeypatch):
     """A concurrent analytic writer must not clobber a measured pin — in
     merge_from, in load, and through the shared-store save cycle."""
     reset_plan_caches()
+    _time_here(monkeypatch)
     path = str(tmp_path / "shared.json")
     # writer A: measured pin, saved to the shared store
     a = PlanRegistry()
@@ -366,9 +382,10 @@ def test_cell_gemm_plans_pallas_template_warms_registry():
     reset_plan_caches()
 
 
-def test_engine_measure_and_pin_uses_engine_spec():
+def test_engine_measure_and_pin_uses_engine_spec(monkeypatch):
+    _time_here(monkeypatch)
     reg = PlanRegistry()
-    eng = Engine(TemplateConfig(backend="pallas", interpret=True), plan_cache=reg)
+    eng = Engine(TemplateConfig(backend="pallas"), plan_cache=reg)
     blk = eng.measure_and_pin(128, 128, 128, reps=1)
     assert reg.source_for(128, 128, 128, TPU_V5E) == "measured"
     assert eng.plan_gemm(128, 128, 128).block == blk
@@ -502,7 +519,7 @@ def test_local_conv_shapes_batch_and_cout():
 
 def test_plan_gemm_mesh_vs_single_from_one_registry():
     reg = PlanRegistry()
-    eng = Engine(TemplateConfig(backend="pallas", interpret=True), plan_cache=reg)
+    eng = Engine(TemplateConfig(backend="pallas"), plan_cache=reg)
     single = eng.plan_gemm(256, 512, 128)
     local = eng.plan_gemm(256, 512, 128, mesh=_StubMesh())
     assert single.logical == () and (single.m, single.n, single.k) == (256, 512, 128)
@@ -572,7 +589,7 @@ _MESH_SCRIPT = textwrap.dedent(
     from repro.launch.mesh import make_test_mesh, gemm_partition
 
     mesh = make_test_mesh()  # (2, 2) ("data", "model")
-    eng = Engine(TemplateConfig(backend="pallas", interpret=True))
+    eng = Engine(TemplateConfig(backend="pallas"))
     m, n, k = 256, 512, 128
     p_single = eng.plan_gemm(m, n, k)
     p_mesh = eng.plan_gemm(m, n, k, mesh=mesh)

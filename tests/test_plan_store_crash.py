@@ -38,7 +38,7 @@ _CRASH_SCRIPT = textwrap.dedent(
     from repro.core.engine import Engine, plan_cache_for, save_plan_store
     from repro.core.template import TemplateConfig
 
-    eng = Engine(TemplateConfig(backend="pallas", interpret=True),
+    eng = Engine(TemplateConfig(backend="pallas"),
                  plan_cache=plan_cache_for())
     eng.plan_gemm(64, 64, 64)
     save_plan_store(store)          # complete store: 1 entry
@@ -73,7 +73,7 @@ _RECOVER_SCRIPT = textwrap.dedent(
                                    save_plan_store)
     from repro.core.template import TemplateConfig
 
-    eng = Engine(TemplateConfig(backend="pallas", interpret=True),
+    eng = Engine(TemplateConfig(backend="pallas"),
                  plan_cache=plan_cache_for())
     eng.plan_gemm(128, 64, 64)
     save_plan_store(store)

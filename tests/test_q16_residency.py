@@ -186,7 +186,7 @@ def test_q16_decode_tracks_float_path(q16_setup):
 def test_grid_matmul_matches_mixed_format_oracle():
     """Engine grid-resident GEMM == qtensor_matmul_ref bit-for-bit, formats
     mixed (calibrated weight grid != activation grid), bias + relu fused."""
-    eng = Engine(TemplateConfig(backend="q16", interpret=True))
+    eng = Engine(TemplateConfig(backend="q16"))
     key = jax.random.PRNGKey(0)
     x = jax.random.normal(key, (6, 16)) * 0.4
     w = jax.random.normal(jax.random.fold_in(key, 1), (16, 8)) * 0.05
@@ -204,7 +204,7 @@ def test_grid_matmul_matches_mixed_format_oracle():
 def test_wide_head_readout_is_exact():
     """wide=True returns the int32 accumulator exactly descaled — no
     saturation even when the true product leaves the int16 grid's range."""
-    eng = Engine(TemplateConfig(backend="q16", interpret=True))
+    eng = Engine(TemplateConfig(backend="q16"))
     # true value 4 * 0.81 = 3.24 > 2 (outside Q2.14's range) while the int32
     # accumulator stays inside 2^31 (the documented wraparound bound)
     xq = quantize_qtensor(jnp.full((1, 4), 0.9), Q2_14)

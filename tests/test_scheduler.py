@@ -339,7 +339,7 @@ def test_padding_never_changes_real_position_logits(s):
 def test_bucket_ladder_round_trips_plan_registry(tmp_path):
     """Every rung's plan persists through the store and replans with 0 misses."""
     reg = PlanRegistry()
-    eng = Engine(TemplateConfig(backend="pallas", interpret=True), plan_cache=reg)
+    eng = Engine(TemplateConfig(backend="pallas"), plan_cache=reg)
     ladder = (8, 32, 128)
     plans = eng.plan_gemm_ladder(ladder, 96, 64)
     assert sorted(plans) == sorted(ladder)
@@ -348,7 +348,7 @@ def test_bucket_ladder_round_trips_plan_registry(tmp_path):
     reg.save(path)
     warm = PlanRegistry()
     warm.load(path)
-    eng2 = Engine(TemplateConfig(backend="pallas", interpret=True), plan_cache=warm)
+    eng2 = Engine(TemplateConfig(backend="pallas"), plan_cache=warm)
     plans2 = eng2.plan_gemm_ladder(ladder, 96, 64)
     assert warm.misses == 0 and warm.hits == len(ladder)
     assert plans2 == plans

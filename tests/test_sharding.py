@@ -14,11 +14,12 @@ from repro.parallel.sharding import (
     tree_shardings,
     use_mesh,
 )
+from repro.launch.mesh import make_mesh
 
 
 @pytest.fixture
 def mesh1():
-    return jax.make_mesh((1,), ("data",))
+    return make_mesh((1,), ("data",))
 
 
 def test_rule_lookup_and_override():
@@ -43,7 +44,7 @@ def test_small_dim_replicated():
 
     With a 1-device test mesh, axis size 1 always divides, so we exercise
     the drop through the rules math on a fake 4-way axis size."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     spec = logical_to_spec(("batch",), mesh=mesh, rules=TRAIN_RULES, dim_sizes=(1,))
     assert spec in (P(), P("data"))  # size-1 axis: equivalent to replicated
     from repro.parallel.sharding import _axis_size
@@ -57,7 +58,7 @@ def test_divisibility_enforced_only_for_inputs(mesh1):
     assert s1 == P("data")
     # input path drops it (jit boundary cannot pad)... with data=1 all divides;
     # simulate with a fake 2-way mesh via dim math instead:
-    mesh2 = jax.make_mesh((1,), ("data",))
+    mesh2 = make_mesh((1,), ("data",))
     s2 = logical_to_spec(("experts",), mesh=mesh2, rules=rules, dim_sizes=(3,),
                          require_divisible=True)
     assert s2 == P("data")  # 3 % 1 == 0 -> kept
