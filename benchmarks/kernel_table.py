@@ -77,34 +77,18 @@ def correctness_pass() -> dict:
     return {"matmul_fp": mm, "matmul_q16_raw": q, "conv2d": cv, "flash_attention": fa}
 
 
-def _time_conv(route: str, x, w, reps: int = 3) -> float:
-    fn = lambda: jax.block_until_ready(
-        ops.conv2d(x, w, stride=1, padding=1, route=route)
-    )
-    fn()  # compile
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        fn()
-    return (time.perf_counter() - t0) / reps
-
-
 def im2col_vs_direct_row(n=1, hw=16, cin=16, cout=32, k=3, pad=1) -> dict:
-    """Structural + measured comparison of the two conv routes, as JSON.
+    """Structural comparison of the two conv routes, as JSON.
 
     Bytes are the HBM traffic of each route's GEMM stage (f32): im2col must
     materialize the (N·Ho·Wo, Cin·K²) column matrix, the direct kernel
-    streams the image slab once. Wall time on the CPU is interpreted (it
-    measures the Pallas interpreter, not the MXU — useful only as a relative
-    trajectory between PRs; the structural bytes are the hardware story).
+    streams the image slab once.
     """
     ho = wo = hw + 2 * pad - k + 1
     m, nn, kk = n * ho * wo, cout, cin * k * k
     im2col_bytes = (m * kk + kk * nn + m * nn) * 4
     hp = hw + 2 * pad
     direct_bytes = (n * hp * hp * cin + k * k * cin * cout + n * ho * wo * cout) * 4
-    key = jax.random.PRNGKey(0)
-    x = jax.random.normal(key, (n, hw, hw, cin)) * 0.3
-    w = jax.random.normal(jax.random.fold_in(key, 1), (k, k, cin, cout)) * 0.3
     return {
         "bench": "conv_route_comparison",
         "conv": {"n": n, "hw": hw, "cin": cin, "cout": cout, "k": k, "pad": pad},
@@ -112,8 +96,6 @@ def im2col_vs_direct_row(n=1, hw=16, cin=16, cout=32, k=3, pad=1) -> dict:
         "im2col_gemm_bytes": im2col_bytes,
         "direct_gemm_bytes": direct_bytes,
         "bytes_ratio_im2col_over_direct": round(im2col_bytes / direct_bytes, 2),
-        "im2col_wall_s_interpret": round(_time_conv("im2col", x, w), 4),
-        "direct_wall_s_interpret": round(_time_conv("direct", x, w), 4),
     }
 
 
@@ -422,9 +404,7 @@ def scheduler_mixed_trace_row() -> dict:
     trace = synthetic_trace(6, seed=2, vocab=cfg.vocab, ladder=ladder, max_new=3)
     for r in trace:
         r.max_new = 3  # fixed budget: the coalescing ratio is then structural
-    t0 = time.perf_counter()
     stats = replay_trace(sched, trace, tick=1.0)
-    wall = time.perf_counter() - t0
     # delta captured here: the unbatched parity references below legitimately
     # plan their own exact-length (non-bucketed) shapes
     post_warmup_misses = sched.registry.misses - m0
@@ -459,7 +439,6 @@ def scheduler_mixed_trace_row() -> dict:
         "ttft_p99": round(stats["ttft"].get("p99", 0.0), 3),
         "mean_occupancy": stats["mean_occupancy"],
         "tokens": c["tokens"],
-        "wall_s_interpret": round(wall, 3),
         "post_warmup_misses": post_warmup_misses,
         "byte_identical_vs_unbatched": parity,
     }
@@ -512,9 +491,7 @@ def router_failover_row() -> dict:
             fault_plan=FaultPlan(kills=((2, 0),)),
             checkpoint_dir=ckpt, checkpoint_every=1)
         kill_trace = trace(51_000)
-        t0 = time.perf_counter()
         stats = router.run(kill_trace)
-        wall = time.perf_counter() - t0
     got = {i: router.ledger.tokens(r.rid) for i, r in enumerate(kill_trace)}
     router.assert_exactly_once()
     c = stats["counters"]
@@ -533,7 +510,6 @@ def router_failover_row() -> dict:
         "duplicates_suppressed": stats["duplicates_suppressed"],
         "ledger_tokens": c.get("ledger_tokens", 0),
         "byte_identical_vs_unkilled": got == ref,
-        "wall_s_interpret": round(wall, 3),
         "stats_line": router.stats_line(),
     }
 

@@ -20,10 +20,10 @@ chips against the unsharded one-chip forward, q16 bit-identical and float
 allclose, and prints where the slabs were placed.
 
 Every compiled Pallas program must hold a ``tpu_custom_call`` (a kernel that
-fell back to the interpreter or to XLA would not).  Compile and
-steady-state wall times are printed as smoke timings, not metrics.  Any
-failed check raises; the last line of stdout is the JSON result.  Without a
-TPU the script exits non-zero before printing a result.
+fell back to the interpreter or to XLA would not).  Timing is the
+benchmark's (``bench/run.py``), not this script's.  Any failed check
+raises; the last line of stdout is the JSON result.  Without a TPU the
+script exits non-zero before printing a result.
 """
 from __future__ import annotations
 
@@ -31,7 +31,6 @@ import argparse
 import json
 import os
 import sys
-import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "src"))
 
@@ -52,7 +51,6 @@ FLOAT_RTOL = 1e-4
 #: bit-identical oracle check instead.
 ARGMAX_MIN = 0.75
 BATCHES = (1, 8)
-STEADY_REPS = 5
 
 
 def log(msg: str) -> None:
@@ -78,22 +76,13 @@ def require_tpu(count: int):
 
 def compiled_phase(name: str, fn, *args, pallas: bool = True):
     """jit + lower + compile ``fn``, check for the Pallas custom call, run
-    once and STEADY_REPS more times; returns (compiled, output)."""
-    t0 = time.perf_counter()
+    it once; returns its output."""
     compiled = jax.jit(fn).lower(*args).compile()
-    t_compile = time.perf_counter() - t0
     if pallas:
         n_kernels = compiled.as_text().count("custom_call_target=\"tpu_custom_call\"")
         check(n_kernels > 0, f"{name}: no tpu_custom_call in the compiled HLO")
-    out = jax.block_until_ready(compiled(*args))
-    t0 = time.perf_counter()
-    for _ in range(STEADY_REPS):
-        out = jax.block_until_ready(compiled(*args))
-    t_step = (time.perf_counter() - t0) / STEADY_REPS
-    kern = f" pallas_kernels={n_kernels}" if pallas else ""
-    log(f"  smoke timing (not a metric) {name}: compile {t_compile:.2f}s, "
-        f"steady {t_step * 1e3:.3f}ms/call{kern}")
-    return out
+        log(f"  {name}: pallas_kernels={n_kernels}")
+    return jax.block_until_ready(compiled(*args))
 
 
 def check_float(name, out, ref):
@@ -162,12 +151,11 @@ def one_chip(seed: int, spec=None) -> None:
             lambda p, a: C.cnn_forward(tq, spec, p, a, policy=policy, plan=pq),
             qp, x)
         with jax.default_device(cpu):
-            t0 = time.perf_counter()
             ref_q = jax.jit(
                 lambda p, a: C.cnn_forward_ref(spec, p, a, policy=policy)
             )(qp_cpu, jax.device_put(x, cpu))
             ref_q = jax.block_until_ready(ref_q)
-        log(f"  q16 CPU oracle: {time.perf_counter() - t0:.2f}s on {cpu}")
+        log(f"  q16 CPU oracle on {cpu}")
         check_bitwise(f"q16 pallas vs CPU integer oracle, batch {n}", out_q, ref_q)
         agree = float(np.mean(np.argmax(np.asarray(out_q), -1)
                               == np.argmax(np.asarray(out_f), -1)))

@@ -30,6 +30,7 @@ from repro.core.quantization import (
     fake_quant_fmt,
 )
 from repro.core.template import Template
+from repro.runtime.spans import layer
 
 __all__ = [
     "CNNSpec",
@@ -283,7 +284,19 @@ def plan_cnn(
     stay shard-local, and the FCs are planned at the logical shape (the
     flatten seam gathers the slabs, so ``mesh``/``partition`` do not apply
     to spatial plans).
+
+    Recorded as the set-up span ``plan``, whose ``dse_searches`` is the
+    PlanRegistry's miss delta: 0 when every layer was a hit.
     """
+    with layer("plan") as attrs:
+        with tpl.engine.plan_cache.scope() as delta:
+            plan = _plan_cnn(tpl, spec, input_shape, force_route, mesh,
+                             partition, spatial)
+        attrs["dse_searches"] = delta["misses"]
+    return plan
+
+
+def _plan_cnn(tpl, spec, input_shape, force_route, mesh, partition, spatial):
     spatial_n, spatial_ax = 1, None
     if spatial is not None:
         from repro.parallel.sharding import spatial_shards
@@ -414,7 +427,11 @@ def quantize_cnn_params(tpl: Template, spec: CNNSpec, params,
             "fcs": [qdense(p, names[nc + i]) for i, p in enumerate(params["fcs"])],
         }
 
-    return eng.qparams_for(params, policy, build)
+    with layer("quantize_params") as attrs:
+        built = eng.counters["qparam_builds"]
+        qp = eng.qparams_for(params, policy, build)
+        attrs["built"] = eng.counters["qparam_builds"] - built
+    return qp
 
 
 def calibrate_cnn_policy(tpl: Template, spec: CNNSpec, params, x,
@@ -426,10 +443,11 @@ def calibrate_cnn_policy(tpl: Template, spec: CNNSpec, params, x,
     import dataclasses
 
     base = base or NumericsPolicy("q16")
-    probe_qp = quantize_cnn_params(tpl, spec, params, base)
-    fmt = tpl.engine.calibrate_activation_format(
-        lambda: cnn_forward(tpl, spec, probe_qp, x, policy=base)
-    )
+    with layer("calibrate"):
+        probe_qp = quantize_cnn_params(tpl, spec, params, base)
+        fmt = tpl.engine.calibrate_activation_format(
+            lambda: cnn_forward(tpl, spec, probe_qp, x, policy=base)
+        )
     policy = dataclasses.replace(base, fmt=fmt)
     if policy != base:
         tpl.engine.drop_qparams(params, base)  # release the probe tree
@@ -552,36 +570,50 @@ def cnn_forward(
     the exact int32 read-out of the final classifier — one quantize and one
     dequantize for the entire forward (DESIGN.md §8).
     """
-    if policy is not None and policy.quantized and isinstance(
-        params["convs"][0]["w"], QTensor
-    ):
-        eng = tpl.engine
-        plan = plan or plan_cnn(tpl, spec, x.shape)
-        halos = plan.pool_halos or (None,) * len(plan.convs)
-        names = cnn_layer_names(spec)
-        # each layer writes its *successor's* input grid in-kernel — the
-        # mixed-boundary epilogue (DESIGN.md §11): an int8 layer feeds an
-        # int16 layer (and vice versa) with zero float round-trips.  Pooling
-        # is grid-transparent, so conv output and pooled map share the grid.
+    with layer("forward", traced=isinstance(x, jax.core.Tracer)):
+        if policy is not None and policy.quantized and isinstance(
+            params["convs"][0]["w"], QTensor
+        ):
+            return _forward_q(tpl, spec, params, x, plan, policy)
+        return _forward_float(tpl, spec, params, x, quantized, fmt, plan)
+
+
+# Every layer of the forward runs under a span named as
+# bench/roofline.py:layer_counts names it (conv{i}, fc{i}), plus quantize,
+# pool{i} (numbered by the conv it follows) and gather: its device
+# operations carry the name in their op_name, its trace time a host span.
+
+
+def _forward_q(tpl, spec, params, x, plan, policy):
+    eng = tpl.engine
+    plan = plan or plan_cnn(tpl, spec, x.shape)
+    halos = plan.pool_halos or (None,) * len(plan.convs)
+    names = cnn_layer_names(spec)
+    # each layer writes its *successor's* input grid in-kernel — the
+    # mixed-boundary epilogue (DESIGN.md §11): an int8 layer feeds an
+    # int16 layer (and vice versa) with zero float round-trips.  Pooling
+    # is grid-transparent, so conv output and pooled map share the grid.
+    with layer("quantize"):
         h = eng.quant(x, policy.fmt_for(names[0]))
-        if plan.spatial > 1:
-            h = _to_slabs(h, plan.spatial)
-        nc = len(plan.convs)
-        for i, (p, (cout, k, stride, pad, pool), cp, ph) in enumerate(zip(
-            params["convs"], spec.convs, plan.convs, halos
-        )):
+    if plan.spatial > 1:
+        h = _to_slabs(h, plan.spatial)
+    nc = len(plan.convs)
+    for i, (p, (cout, k, stride, pad, pool), cp, ph) in enumerate(zip(
+        params["convs"], spec.convs, plan.convs, halos
+    )):
+        with layer(f"conv{i}"):
             h = tpl.conv2d(h, p["w"], stride=stride, padding=pad,
                            bias=p["b"], relu=True,
                            qout=policy.fmt_for(names[i + 1]), plan=cp)
-            if pool:
+        if pool:
+            with layer(f"pool{i}"):
                 h = _maxpool_spatial(h, pool, ph) if ph is not None else _maxpool(h, pool)
-        if plan.spatial > 1:
-            h = _gather_slabs(h, plan.feat_h)
-        h = h.reshape(h.shape[0], -1)
+    h = _flatten(h, plan)
 
-        def head(h, fcs):
-            last = len(fcs) - 1
-            for i, (p, gp) in enumerate(zip(fcs, plan.fcs)):
+    def head(h, fcs):
+        last = len(fcs) - 1
+        for i, (p, gp) in enumerate(zip(fcs, plan.fcs)):
+            with layer(f"fc{i}"):
                 if i < last:
                     h = tpl.linear(h, p["w"], p["b"], relu=True,
                                    qout=policy.fmt_for(names[nc + i + 1]),
@@ -590,9 +622,12 @@ def cnn_forward(
                     # final classifier: exact accumulator read-out (the
                     # single counted dequantize of the whole network)
                     h = tpl.linear(h, p["w"], p["b"], wide=True, plan=gp)
-            return h
+        return h
 
-        return _fc_head(plan, head, h, params["fcs"])
+    return _fc_head(plan, head, h, params["fcs"])
+
+
+def _forward_float(tpl, spec, params, x, quantized, fmt, plan):
     plan = plan or plan_cnn(tpl, spec, x.shape)
     halos = plan.pool_halos or (None,) * len(plan.convs)
     fq = (lambda a: fake_quant_fmt(a, fmt)) if quantized else (lambda a: a)
@@ -600,29 +635,38 @@ def cnn_forward(
     h = fq(x)
     if plan.spatial > 1:
         h = _to_slabs(h, plan.spatial)
-    for p, (cout, k, stride, pad, pool), cp, ph in zip(
+    for i, (p, (cout, k, stride, pad, pool), cp, ph) in enumerate(zip(
         params["convs"], spec.convs, plan.convs, halos
-    ):
-        h = tpl.conv2d(
-            h, fq(p["w"]), stride=stride, padding=pad,
-            bias=fq(p["b"]), relu=True, qout=qo, plan=cp,
-        )
+    )):
+        with layer(f"conv{i}"):
+            h = tpl.conv2d(
+                h, fq(p["w"]), stride=stride, padding=pad,
+                bias=fq(p["b"]), relu=True, qout=qo, plan=cp,
+            )
         if pool:
-            h = _maxpool_spatial(h, pool, ph) if ph is not None else _maxpool(h, pool)
-    if plan.spatial > 1:
-        h = _gather_slabs(h, plan.feat_h)
-    h = h.reshape(h.shape[0], -1)
+            with layer(f"pool{i}"):
+                h = _maxpool_spatial(h, pool, ph) if ph is not None else _maxpool(h, pool)
+    h = _flatten(h, plan)
 
     def head(h, fcs):
         last = len(fcs) - 1
         for i, (p, gp) in enumerate(zip(fcs, plan.fcs)):
-            h = tpl.linear(
-                h, fq(p["w"]), fq(p["b"]),
-                relu=i < last, qout=qo if i < last else None, plan=gp,
-            )
+            with layer(f"fc{i}"):
+                h = tpl.linear(
+                    h, fq(p["w"]), fq(p["b"]),
+                    relu=i < last, qout=qo if i < last else None, plan=gp,
+                )
         return h
 
     return _fc_head(plan, head, h, params["fcs"])
+
+
+def _flatten(h, plan: NetworkPlan):
+    """The conv→FC seam: gather the H slabs of a spatial plan, flatten."""
+    with layer("gather"):
+        if plan.spatial > 1:
+            h = _gather_slabs(h, plan.feat_h)
+        return h.reshape(h.shape[0], -1)
 
 
 def _fc_head(plan: NetworkPlan, head, h, fcs):

@@ -110,3 +110,29 @@ def test_vgg16_fc_gemm_compiles(one_chip, dtype):
             x, w, bias=b, relu=True, block=gp.block, vmem_limit_bytes=vmem)
     _compile(fn, one_chip, ((gp.m, gp.k), dtype), ((gp.k, gp.n), dtype),
              ((gp.n,), dtype))
+
+
+@pytest.mark.parametrize("backend", ["pallas", "q16"])
+def test_every_kernel_of_the_forward_names_its_layer(one_chip, backend):
+    """The whole forward of the tiny VGG (bench/tests/bench_tiny.py): each
+    ``tpu_custom_call`` carries its layer scope (``conv{i}``, ``fc{i}``) in
+    its op_name, which is how a device trace splits by layer."""
+    import re
+
+    import numpy as np
+
+    from test_layer_scopes import LAYER, _params, tiny_spec
+
+    spec = tiny_spec()
+    x = np.random.default_rng(0).standard_normal((2, 32, 32, 3), dtype=np.float32)
+    tpl, params, policy = _params(backend, spec, x)
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), (params, x))
+    hlo = jax.jit(lambda p, x: C.cnn_forward(tpl, spec, p, x, policy=policy)).lower(
+        *shapes).compile().as_text()
+    layers = []
+    for line in hlo.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+            layers.append([c for c in op_name.split("/") if LAYER.match(c)][-1])
+    assert sorted(layers) == ["conv0", "conv1", "fc0", "fc1"]
