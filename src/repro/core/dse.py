@@ -12,7 +12,13 @@ analytic and exhaustive over a quantized grid:
 * :func:`explore_tpu_block` — TPU plane: enumerate Pallas (bm, bn, bk) blocks
   within the VMEM budget and rank by a roofline score (MXU occupancy ×
   min(1, intensity/ridge)).  This picks the compute-unit configuration the
-  Pallas kernels use.
+  Pallas kernels use.  A skinny M (below one MXU edge: an FC head at batch
+  1-8, a decode step) takes :func:`explore_skinny_block` instead: one
+  sublane-rounded row block, a reduction tile set by K alone, and output
+  tiles that divide N, ranked by modeled time — grid steps × (fixed
+  per-step cost + the longer of the tile's copy and its MXU/VPU work) plus
+  the unoverlapped first copy and last work, with constants from a chip
+  sweep (``TpuSpec.grid_step_s``, ``TpuSpec.skinny_weight_s``).
 
 * :func:`explore_conv_spatial` — TPU plane, direct conv: enumerate the
   direct-conv kernel's (τ, tile_rows, tile_cols, halo_mode) grid —
@@ -53,6 +59,10 @@ __all__ = [
     "explore_conv_spatial",
     "default_block_for",
     "default_conv_tile_for",
+    "explore_skinny_block",
+    "gemm_vmem_bytes",
+    "skinny_m",
+    "skinny_time_s",
     "direct_conv_vmem",
     "direct_conv_hbm_traffic",
     "direct_conv_ideal_traffic",
@@ -140,7 +150,14 @@ def explore_tpu_block(
     bk_range: Sequence[int] = (128, 256, 512, 1024, 2048),
     top: int = 5,
 ) -> list[tuple[MatmulBlock, float]]:
-    """Enumerate legal Pallas blocks for an (m, n, k) GEMM; rank by score."""
+    """Enumerate legal Pallas blocks for an (m, n, k) GEMM; rank by score.
+
+    A skinny M (:func:`skinny_m`) takes :func:`explore_skinny_block`
+    instead, whose score is the weight-streaming floor over the modeled
+    time; the ranges apply to the large-M search only.
+    """
+    if skinny_m(m, spec):
+        return explore_skinny_block(m, n, k, spec, dtype_bytes, top)
     out: list[tuple[MatmulBlock, float]] = []
     for bm, bn, bk in itertools.product(bm_range, bn_range, bk_range):
         block = MatmulBlock(bm=bm, bn=bn, bk=bk)
@@ -159,6 +176,100 @@ def default_block_for(m: int, n: int, k: int, spec: TpuSpec = TPU_V5E) -> Matmul
     from .tiling import clamp_block
 
     return clamp_block(m, n, k, MatmulBlock(128, 128, 128), spec)
+
+
+# ---------------------------------------------------------------------------
+# TPU plane: skinny-M GEMMs (an FC head at batch 1-8, a decode step)
+# ---------------------------------------------------------------------------
+
+#: Deepest reduction tile of a skinny-M block.  The chip sweep behind the
+#: model (PERF.md §6) found no gain from deeper tiles at equal tile size,
+#: and a bk set by K alone keeps every GEMM over one K accumulating in one
+#: order whatever its M and N: a GEMM sharded over N stays bitwise equal to
+#: the whole one (DESIGN.md §9).
+SKINNY_BK_MAX = 1024
+
+#: Bytes of in-kernel temporaries per operand element, the larger of the
+#: two GEMM kernels': ``int_dot`` widens an int16 tile to int32 (4) and
+#: splits it into two int8 digits (2); the float kernel's HIGHEST dot
+#: splits each f32 into three bf16 terms (6).
+GEMM_TEMP_BYTES = 6
+
+
+def skinny_m(m: int, spec: TpuSpec = TPU_V5E) -> bool:
+    """M below one MXU edge: the GEMM streams every weight once for a few
+    rows, so its time is the weight stream plus a fixed cost per grid
+    step, and its blocks are planned by :func:`explore_skinny_block`."""
+    return m < spec.mxu_dim
+
+
+def _lane_divisors(dim: int, spec: TpuSpec = TPU_V5E) -> list[int]:
+    """Lane-aligned tiles that divide ``dim`` rounded up to whole lanes,
+    smallest first: a kernel pads its operand to a multiple of the tile,
+    so with one of these it pads no more than the lane round-up (none
+    when ``dim`` is lane-aligned, 1000 -> 1024 otherwise)."""
+    units = ceil_div(dim, spec.lane)
+    return [spec.lane * d for d in range(1, units + 1) if units % d == 0]
+
+
+def gemm_vmem_bytes(block: MatmulBlock, spec: TpuSpec = TPU_V5E) -> int:
+    """VMEM of one grid step of either GEMM kernel at 4-byte operands, as
+    the chip lays them out: the double-buffered x, weight, bias and output
+    tiles, the int32/f32 accumulator, and :data:`GEMM_TEMP_BYTES` per x
+    and weight element.  The plan registry keys a block by shape alone, so
+    the q16 and float kernels share it and it must fit the wider one."""
+    bm, bn, bk = block.bm, block.bn, block.bk
+    tiles = 2 * (padded_bytes(bm, bk, 4, spec) + padded_bytes(bk, bn, 4, spec)
+                 + padded_bytes(1, bn, 4, spec) + padded_bytes(bm, bn, 4, spec))
+    acc = padded_bytes(bm, bn, 4, spec)
+    return tiles + acc + (bm * bk + bk * bn) * GEMM_TEMP_BYTES
+
+
+def skinny_time_s(
+    block: MatmulBlock, m: int, n: int, k: int, spec: TpuSpec = TPU_V5E,
+    dtype_bytes: int = 2,
+) -> float:
+    """Modeled seconds of a skinny-M GEMM on ``block``: every grid step
+    costs the fixed ``spec.grid_step_s`` plus the longer of its tile's copy
+    from HBM and its MXU + VPU work (the pipeline overlaps the two), and
+    the first tile's copy and the last tile's work, which nothing
+    overlaps, are paid once more."""
+    steps = ceil_div(m, block.bm) * ceil_div(n, block.bn) * ceil_div(k, block.bk)
+    copy_s = (block.bm * block.bk + block.bk * block.bn) * dtype_bytes / spec.hbm_bw
+    work_s = block.bk * block.bn * spec.skinny_weight_s
+    return steps * (spec.grid_step_s + max(copy_s, work_s)) + copy_s + work_s
+
+
+def _skinny_bk(k: int, spec: TpuSpec = TPU_V5E) -> int:
+    """The reduction tile of a skinny-M block: the largest of
+    :func:`_lane_divisors` of K not above :data:`SKINNY_BK_MAX`."""
+    return max(d for d in _lane_divisors(k, spec) if d <= SKINNY_BK_MAX)
+
+
+def explore_skinny_block(
+    m: int, n: int, k: int, spec: TpuSpec = TPU_V5E, dtype_bytes: int = 2,
+    top: int = 5,
+) -> list[tuple[MatmulBlock, float]]:
+    """Blocks for a skinny-M GEMM, ranked by :func:`skinny_time_s`.
+
+    ``bm`` is M rounded up to a sublane and ``bk`` is :func:`_skinny_bk`
+    of K; ``bn`` ranges over :func:`_lane_divisors` of N, so the kernel
+    never pads the weight beyond the lane round-up, within
+    :func:`gemm_vmem_bytes` <= the VMEM budget.  The score is the
+    weight-streaming floor (K·N weights over HBM bandwidth) over the
+    modeled time.
+    """
+    bm = ceil_div(m, spec.sublane) * spec.sublane
+    bk = _skinny_bk(k, spec)
+    floor_s = k * n * dtype_bytes / spec.hbm_bw
+    out: list[tuple[MatmulBlock, float]] = []
+    for bn in _lane_divisors(n, spec):
+        block = MatmulBlock(bm=bm, bn=bn, bk=bk)
+        if gemm_vmem_bytes(block, spec) > spec.vmem_bytes:
+            continue
+        out.append((block, floor_s / skinny_time_s(block, m, n, k, spec, dtype_bytes)))
+    out.sort(key=lambda t: -t[1])
+    return out[:top]
 
 
 # ---------------------------------------------------------------------------

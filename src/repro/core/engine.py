@@ -199,7 +199,8 @@ class PlanRegistry:
     pre-loaded from a persisted store (:meth:`load`) or pinned by the
     measured-time autotuner (:meth:`measure_and_pin`).  Every entry carries
     ``source`` provenance: ``"analytic"`` (grid-search score) or
-    ``"measured"`` (timed kernel launches).
+    ``"measured"`` (timed kernel launches).  ``skinny`` counts the GEMM
+    searches that took the skinny-M branch (:func:`dse.skinny_m`).
     """
 
     def __init__(self) -> None:
@@ -211,6 +212,7 @@ class PlanRegistry:
         self._prec_src: dict = {}
         self.hits = 0
         self.misses = 0
+        self.skinny = 0
 
     # -- lookups (memoized searches) ----------------------------------------
 
@@ -219,6 +221,8 @@ class PlanRegistry:
         blk = self._blocks.get(key)
         if blk is None:
             self.misses += 1
+            if dse.skinny_m(m, spec):
+                self.skinny += 1
             blk = dse.default_block_for(m, n, k, spec)
             self._blocks[key] = blk
             self._block_src[key] = "analytic"
@@ -365,18 +369,20 @@ class PlanRegistry:
         """Count hits/misses attributable to one region (per-bucket stats).
 
         Yields a dict that, on exit, holds the hit/miss *delta* incurred
-        inside the with-block; when ``into`` is given the delta is also
+        inside the with-block (and ``skinny``, the skinny-M GEMM searches
+        among the misses); when ``into`` is given the hit/miss delta is also
         accumulated there (``into["hits"] += ...``).  The scheduler wraps
         each bucket's prefill trace and the decode trace in a scope so its
         stats line can attribute plan work to individual ladder rungs.
         """
-        delta = {"hits": 0, "misses": 0}
-        h0, m0 = self.hits, self.misses
+        delta = {"hits": 0, "misses": 0, "skinny": 0}
+        h0, m0, s0 = self.hits, self.misses, self.skinny
         try:
             yield delta
         finally:
             delta["hits"] = self.hits - h0
             delta["misses"] = self.misses - m0
+            delta["skinny"] = self.skinny - s0
             if into is not None:
                 into["hits"] = into.get("hits", 0) + delta["hits"]
                 into["misses"] = into.get("misses", 0) + delta["misses"]
@@ -393,6 +399,7 @@ class PlanRegistry:
         self._prec_src.clear()
         self.hits = 0
         self.misses = 0
+        self.skinny = 0
 
     # -- serialization (DESIGN.md §6 schema) ---------------------------------
 

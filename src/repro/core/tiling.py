@@ -140,6 +140,13 @@ class TpuSpec:
     mxu_dim: int = 128  # systolic array edge
     lane: int = 128  # last-dim register lane count
     sublane: int = 8  # second-minor dim granularity (f32)
+    # the skinny-M GEMM model (core/dse.py), fitted to a chip sweep of the
+    # GEMM kernels (benchmarks/skinny_gemm_sweep.py): the fixed cost of one
+    # Pallas grid step, and the q16 kernel's work per weight element at
+    # M = 8 (int_dot's digit dots and splits; each weight tile is latched
+    # in the MXU for a few rows)
+    grid_step_s: float = 0.25e-6
+    skinny_weight_s: float = 2.7e-12
 
 
 #: TPU v5e peaks: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,

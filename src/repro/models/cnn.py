@@ -30,6 +30,7 @@ from repro.core.quantization import (
     fake_quant_fmt,
 )
 from repro.core.template import Template
+from repro.core.tiling import ceil_div
 from repro.runtime.spans import layer
 
 __all__ = [
@@ -247,8 +248,12 @@ class NetworkPlan:
                 f"({cp.vmem_bytes / 2**20:.1f}MiB) gemm={cp.gemm}{halo}"
             )
         for i, gp in enumerate(self.fcs):
-            blk = (gp.block.bm, gp.block.bn, gp.block.bk) if gp.block else None
-            lines.append(f"fc{i}: m={gp.m} n={gp.n} k={gp.k} block={blk}")
+            blk = steps = None
+            if gp.block:
+                b = gp.block
+                blk = (b.bm, b.bn, b.bk)
+                steps = ceil_div(gp.m, b.bm) * ceil_div(gp.n, b.bn) * ceil_div(gp.k, b.bk)
+            lines.append(f"fc{i}: m={gp.m} n={gp.n} k={gp.k} block={blk} steps={steps}")
         return lines
 
 
@@ -286,13 +291,16 @@ def plan_cnn(
     to spatial plans).
 
     Recorded as the set-up span ``plan``, whose ``dse_searches`` is the
-    PlanRegistry's miss delta: 0 when every layer was a hit.
+    PlanRegistry's miss delta (0 when every layer was a hit) and whose
+    ``skinny_gemms`` counts the searches among them that took the skinny-M
+    GEMM branch (an FC head at batch 1-8: 3 for VGG16).
     """
     with layer("plan") as attrs:
         with tpl.engine.plan_cache.scope() as delta:
             plan = _plan_cnn(tpl, spec, input_shape, force_route, mesh,
                              partition, spatial)
         attrs["dse_searches"] = delta["misses"]
+        attrs["skinny_gemms"] = delta["skinny"]
     return plan
 
 

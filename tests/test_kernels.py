@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.quantization import Q2_14, QFormat, quantize
+from repro.core.quantization import Q2_14, QFormat, qmatmul_ref, quantize
 from repro.core.tiling import MatmulBlock
 from repro.kernels import ops, ref
 
@@ -66,6 +66,48 @@ def test_matmul_q16_vs_ref(m, k, n, fmt):
     out = ops.matmul_q16(xq, wq, fmt=fmt)
     want = ref.matmul_q16_ref(xq, wq, fmt)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
+# ---------------------------------------------------------------------------
+# skinny-M blocks (an FC head at batch 1-8; fc0's reduction cut to 3584)
+# ---------------------------------------------------------------------------
+
+#: One 8-row block, weight tiles of the skinny-M branch: two k steps (the
+#: accumulator carries) by two n blocks.
+SKINNY_BLOCK = MatmulBlock(8, 256, 1792)
+
+
+@pytest.mark.parametrize("m", [1, 8])
+@pytest.mark.parametrize("epilogue", ["plain", "bias_relu", "wide"])
+def test_matmul_q16_skinny_block_bit_exact(m, epilogue):
+    k, n, fmt = 3584, 512, Q2_14
+    x = _rand((m, k), scale=0.05)
+    w = _rand((k, n), scale=0.05)
+    xq, wq = quantize(x, fmt), quantize(w, fmt)
+    bq = quantize(_rand((n,), scale=0.5), fmt)
+    acc = jnp.dot(xq.astype(jnp.int32), wq.astype(jnp.int32),
+                  preferred_element_type=jnp.int32)
+    if epilogue == "plain":
+        out = ops.matmul_q16(xq, wq, fmt=fmt, block=SKINNY_BLOCK)
+        want = qmatmul_ref(xq, wq, fmt)
+    elif epilogue == "bias_relu":
+        out = ops.matmul_q16(xq, wq, bias=bq, relu=True, fmt=fmt, block=SKINNY_BLOCK)
+        want = ref.matmul_q16_fused_ref(xq, wq, bq, fmt=fmt, relu=True)
+    else:
+        out = ops.matmul_q16(xq, wq, bias=bq, relu=True, wide=True, fmt=fmt,
+                             block=SKINNY_BLOCK)
+        want = jnp.maximum(acc + (bq.astype(jnp.int32) << fmt.frac_bits), 0)
+    assert out.shape == (m, n) and out.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
+@pytest.mark.parametrize("m", [1, 8])
+def test_matmul_fp_skinny_block(m):
+    k, n = 3584, 512
+    x, w, b = _rand((m, k)), _rand((k, n)), _rand((n,))
+    out = ops.matmul_fp(x, w, bias=b, relu=True, block=SKINNY_BLOCK)
+    want = ref.matmul_fused_ref(x, w, b, relu=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-4, rtol=1e-4)
 
 
 # ---------------------------------------------------------------------------

@@ -105,6 +105,22 @@ def test_plan_counts_dse_searches_cold_and_none_warm():
     assert cold.attrs["dse_searches"] > 0 and warm.attrs["dse_searches"] == 0
 
 
+def test_plan_counts_skinny_gemms_of_the_fc_head():
+    # VGG16 at batch 1 on emptied caches: its three FC GEMMs (M = 1) take
+    # the skinny-M branch; the memoized second plan searches nothing
+    from repro.core.engine import reset_plan_caches
+
+    reset_plan_caches()
+    tpl = default_template("q16")
+    first = C.plan_cnn(tpl, C.VGG16, (1, 224, 224, 3))
+    cold = _named("plan")[-1]
+    again = C.plan_cnn(tpl, C.VGG16, (1, 224, 224, 3))
+    warm = _named("plan")[-1]
+    assert again is first and warm.index > cold.index
+    assert cold.attrs["skinny_gemms"] == 3 and warm.attrs["skinny_gemms"] == 0
+    assert [gp.block.bm for gp in first.fcs] == [8, 8, 8]
+
+
 def test_set_up_spans_of_calibration_and_quantization():
     spec = C.CNNSpec("spans-q16", 16, 3, 10, convs=((8, 3, 1, 1, 2),), fcs=(16,))
     tpl = default_template("q16")

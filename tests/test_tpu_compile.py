@@ -96,20 +96,23 @@ def test_vgg16_512_dma_halo_conv_compiles(one_chip, backend):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.int8, jnp.int16])
 def test_vgg16_fc_gemm_compiles(one_chip, dtype):
-    """fc0 (k=25088 -> 4096) at batch 8: matmul_fp in f32, matmul_q16 with
-    int8 operands (one MXU pass per tile) and int16 (four int8 digits)."""
+    """VGG16's FC GEMMs (fc0: k=25088 -> 4096) at batch 8 on their skinny-M
+    blocks: matmul_fp in f32, matmul_q16 with int8 operands (one MXU pass
+    per tile) and int16 (four int8 digits)."""
     tpl = default_template("pallas" if dtype == jnp.float32 else "q16")
-    gp = C.plan_cnn(tpl, C.VGG16, (8, 224, 224, 3)).fcs[0]
-    assert (gp.m, gp.n, gp.k) == (8, 4096, 25088)
+    fcs = C.plan_cnn(tpl, C.VGG16, (8, 224, 224, 3)).fcs
+    assert (fcs[0].m, fcs[0].n, fcs[0].k) == (8, 4096, 25088)
     vmem = tpl.config.hw.vmem_bytes
-    if dtype == jnp.float32:
-        fn = lambda x, w, b: ops.matmul_fp(  # noqa: E731
-            x, w, bias=b, relu=True, block=gp.block, vmem_limit_bytes=vmem)
-    else:
-        fn = lambda x, w, b: ops.matmul_q16(  # noqa: E731
-            x, w, bias=b, relu=True, block=gp.block, vmem_limit_bytes=vmem)
-    _compile(fn, one_chip, ((gp.m, gp.k), dtype), ((gp.k, gp.n), dtype),
-             ((gp.n,), dtype))
+    for gp in fcs:
+        assert gp.block.bm == 8 and gp.k % gp.block.bk == 0
+        if dtype == jnp.float32:
+            fn = lambda x, w, b, gp=gp: ops.matmul_fp(  # noqa: E731
+                x, w, bias=b, relu=True, block=gp.block, vmem_limit_bytes=vmem)
+        else:
+            fn = lambda x, w, b, gp=gp: ops.matmul_q16(  # noqa: E731
+                x, w, bias=b, relu=True, block=gp.block, vmem_limit_bytes=vmem)
+        _compile(fn, one_chip, ((gp.m, gp.k), dtype), ((gp.k, gp.n), dtype),
+                 ((gp.n,), dtype))
 
 
 @pytest.mark.parametrize("backend", ["pallas", "q16"])
