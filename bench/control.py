@@ -11,8 +11,8 @@ program's place: the upper reading.
 
 * fixed point: the program's own int8 path (every layer's activation
   grid moved to its int8 rung, the precision ladder of the engine);
-* float32 at ``highest``: the reference at ``high`` (three bfloat16
-  passes) run on the chip as the forward.
+* float32 at ``highest``: the form's reference at ``high`` (three
+  bfloat16 passes) run on the chip as the forward.
 
 One JSON line per seed: the number compared and its limit.  The
 benchmark's own runs never run this.
@@ -24,52 +24,65 @@ import contextlib
 import dataclasses
 import json
 import sys
-from functools import partial
 from pathlib import Path
 
 sys.path[:] = [p for p in sys.path if Path(p or ".").resolve() != Path(__file__).resolve().parent]
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
-from bench import reference  # noqa: E402
 from bench import run as R  # noqa: E402
 
 
 @contextlib.contextmanager
-def int8_rung_everywhere():
-    """While open, the program's calibrated policy puts every layer on the
-    int8 rung of its grid."""
+def int8_rung_everywhere(form):
+    """While open, the program that ``form`` binds puts every layer of its
+    calibrated policy on the int8 rung of its grid."""
     from repro.core.quantization import int8_rung
-    from repro.models import cnn as C
 
-    calibrate = C.calibrate_cnn_policy
+    program = form.program
 
-    def lowered(tpl, spec, params, x, base=None):
-        policy = calibrate(tpl, spec, params, x, base=base)
-        low = int8_rung(policy.fmt)
-        fmts = tuple(sorted((name, low) for name in C.cnn_layer_names(spec)))
-        return dataclasses.replace(policy, name="mixed", layer_fmts=fmts)
+    def lowered(cfg):
+        prog = program(cfg)
 
-    C.calibrate_cnn_policy = lowered
+        def calibrate(*args, **kwargs):
+            policy = prog.calibrate(*args, **kwargs)
+            low = int8_rung(policy.fmt)
+            fmts = tuple(sorted((name, low) for name in prog.layer_names()))
+            return dataclasses.replace(policy, name="mixed", layer_fmts=fmts)
+
+        return dataclasses.replace(prog, calibrate=calibrate)
+
+    form.program = lowered
     try:
         yield
     finally:
-        C.calibrate_cnn_policy = calibrate
+        form.program = program
 
 
-def float_high(cfg: dict):
-    """A forward wrapper: the reference at ``high`` in the program's place
-    (the program's parameters are the float32 weights)."""
-    import jax
+def float_high(form, cfg: dict):
+    """A forward wrapper: the form's float reference at ``high`` in the
+    program's place (the program's parameters are the float32 weights),
+    built on the first call."""
+    def wrap(forward):
+        ref = None
 
-    return lambda forward: jax.jit(partial(reference.float_forward, cfg, precision="high"))
+        def run(params, x):
+            nonlocal ref
+            if ref is None:
+                ref = form.float_reference(cfg, params, "high")
+            return ref(x)
+
+        return run
+
+    return wrap
 
 
 def control_run(cell, seed: int, seconds: float, devices, log=R.log) -> dict:
     """One run of ``cell`` with the control in the program's place."""
     if cell.cfg["reference"]["kind"] == "fixed":
-        with int8_rung_everywhere():
+        with int8_rung_everywhere(cell.form):
             return R.run_cell(cell, seed, seconds, False, devices, log=log)
-    return R.run_cell(cell, seed, seconds, False, devices, fault=float_high(cell.cfg), log=log)
+    return R.run_cell(cell, seed, seconds, False, devices,
+                      fault=float_high(cell.form, cell.cfg), log=log)
 
 
 def main(argv=None) -> None:

@@ -1,10 +1,12 @@
 """Device time of a traced window by layer of the network, and the
 program's own host spans.
 
-The program names each layer with a ``jax.named_scope`` (``quantize``,
-``conv{i}``, ``pool{i}``, ``gather``, ``fc{i}``, the names
-:func:`bench.roofline.layer_counts` uses), so every HLO instruction traced
-inside carries it in its ``op_name``
+The program names each layer with a ``jax.named_scope``: ``quantize``
+and ``gather`` around a network's layers, and inside them the scopes of
+its form's ``layer_pattern`` (``bench/networks/<form>.py``; the layer
+table's ``conv{i}``, ``pool{i}`` and ``fc{i}`` are the names its
+``layer_counts`` uses).  Every HLO instruction traced inside carries the
+scope in its ``op_name``
 (``jit(fwd)/forward/conv3/jit(conv2d_q16_pallas)/.../pallas_call``).  The
 profiler keeps that ``op_name`` as the ``tf_op`` statistic of each
 operation's event metadata on the device plane; ``jax.profiler.ProfileData``
@@ -23,15 +25,18 @@ built on this return None; so do they where the program records no spans.
 """
 from __future__ import annotations
 
+import functools
 import re
 from dataclasses import dataclass
 from pathlib import Path
 
+from bench import forms
 from bench import trace as tr
 
 #: Where bench/run.py writes the profile of a traced window.
 TRACE_DIR = Path(__file__).resolve().parents[1] / ".bench_trace"
-LAYER = re.compile(r"^(quantize|conv\d+|pool\d+|gather|fc\d+)$")
+#: the program's scopes around a network's layers
+PROGRAM_SCOPES = ("quantize", "gather")
 NO_LAYER = "-"
 KINDS = ("kernel", "collective", "other")
 COLLECTIVES = ("all-gather", "all-reduce", "all-to-all", "collective-permute",
@@ -122,9 +127,25 @@ def op_names(path: str, planes: list) -> dict:
     return out
 
 
+def _either(patterns) -> re.Pattern:
+    return re.compile("^(" + "|".join(p for p in patterns if p) + ")$")
+
+
+@functools.cache
+def scopes() -> tuple:
+    """(every layer scope, the scopes whose operations are the layer's
+    own work and not glue): :data:`PROGRAM_SCOPES` with the
+    ``layer_pattern`` of every form in ``bench/networks``, and ``quantize``
+    with every form's ``xla_layer_pattern``."""
+    found = forms.every_form()
+    return (_either([*PROGRAM_SCOPES, *(f.layer_pattern for f in found)]),
+            _either(["quantize", *(f.xla_layer_pattern for f in found)]))
+
+
 def layer_of(op_name: str) -> str:
     """The innermost layer scope in an ``op_name`` path, or NO_LAYER."""
-    found = [c for c in op_name.split("/") if LAYER.match(c)]
+    layer = scopes()[0]
+    found = [c for c in op_name.split("/") if layer.match(c)]
     return found[-1] if found else NO_LAYER
 
 
@@ -199,10 +220,11 @@ def busiest(tables: dict) -> dict:
 
 def glue_ns(table: dict) -> float:
     """Operation time that is neither a kernel nor a collective, in the
-    conv, FC and gather scopes and outside every scope: the pads, copies,
-    converts and relayouts around the kernels."""
+    scopes of kernel layers and ``gather`` and outside every scope: the
+    pads, copies, converts and relayouts around the kernels."""
+    own = scopes()[1]
     return sum(row["other"] for layer, row in table.items()
-               if layer == NO_LAYER or not layer.startswith(("quantize", "pool")))
+               if layer == NO_LAYER or not own.match(layer))
 
 
 def program_spans() -> list:
@@ -215,24 +237,18 @@ def program_spans() -> list:
     return spans.spans()
 
 
-def _order(layer: str):
-    m = re.match(r"([a-z]+)(\d*)$", layer)
-    stage = {"quantize": 0, "conv": 1, "pool": 1, "gather": 2, "fc": 3}
-    if not m or m.group(1) not in stage:
-        return (9, 0, 0)
-    return (stage[m.group(1)], int(m.group(2) or 0), m.group(1) == "pool")
-
-
 def describe(tables: dict, forwards: int, least_s: dict) -> list:
-    """One line per chip and layer: ms per request of kernel, collective
-    and other operations, and for conv and FC layers the kernel's share of
-    the layer's least time (``least_s``: layer -> seconds per forward)."""
+    """One line per chip and layer, in the order the layers first ran and
+    the time outside every scope last: ms per request of kernel,
+    collective and other operations, and for the layers of ``least_s``
+    (layer -> seconds per forward) the kernel's share of that least
+    time."""
     lines = []
     per = max(forwards, 1)
     for chip, table in tables.items():
         lines.append(f"{chip}: {100 * scoped_share(table):.2f}% of operation time in a "
                      f"layer scope; glue {glue_ns(table) / 1e6 / per:.4f} ms/request")
-        for layer in sorted(table, key=_order):
+        for layer in sorted(table, key=lambda layer: layer == NO_LAYER):
             row = table[layer]
             ms = {k: v / 1e6 / per for k, v in row.items()}
             roof = ""

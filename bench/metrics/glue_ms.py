@@ -1,7 +1,7 @@
 """Device milliseconds per request of the operations around the kernels:
-those in a conv, FC or gather layer scope, or in none, that are neither a
-Pallas kernel nor a collective (pads, copies, converts, relayouts; see
-bench/layers.py), on the busiest chip.  None where the program names no
+those in the scope of a kernel layer or of ``gather``, or in none, that
+are neither a Pallas kernel nor a collective (pads, copies, converts,
+relayouts; see bench/layers.py), on the busiest chip.  None where the program names no
 layer."""
 from bench import layers
 

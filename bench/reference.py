@@ -1,5 +1,8 @@
-"""Plain references of a configuration's network, written from the layer
-table in its file and independent of the system under test.
+"""Plain references of a layer-table network (the form of
+``bench/networks/layer_table.py``, which is what calls the functions that
+read a configuration's rows), written from the rows in its file and
+independent of the system under test.  :func:`seed_key`,
+:func:`grid_frac` and the bfloat16 split serve any form.
 
 * :func:`init_params`: the benchmark's weights, made from the seed in one
   jitted call (He-init convs and FCs, small random biases).

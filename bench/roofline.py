@@ -1,5 +1,7 @@
-"""Operations, bytes and least times of a CNN configuration's layers, and
-the table of chip peaks they are measured against.
+"""Operations, bytes and least times of a layer-table configuration's
+layers (:func:`layer_counts` and :func:`fc_params`, read through
+``bench/networks/layer_table.py``), and the table of chip peaks any form's
+counts are measured against (:func:`load_peaks`, :func:`least_time_s`).
 
 Counts are of the work each layer defines, whatever implements it:
 

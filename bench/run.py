@@ -2,26 +2,29 @@
 
     python3 bench/run.py --workload vgg16-224-q16.b1 --seed 7 --seconds 15 --trace 0
 
-A cell names a configuration (``bench/configs/<config>.json``: the layer
-table, the numerics, the check) and a traffic mix
+A cell names a configuration (``bench/configs/<config>.json``: the
+network's sizes, the numerics, the check) and a traffic mix
 (``bench/traffic/<traffic>.json``: batch, requests in flight, spatial
-shards, frame pool, requests checked).  Per-layer metrics are read by
-``bench/metrics/<stem>.py`` (the metric's name up to its first ``.``:
-``conv_roofline.py`` reads ``conv_roofline.latency`` and
+shards, frame pool, requests checked).  The configuration's ``"form"``
+names the file ``bench/networks/<form>.py`` (``bench/forms.py``) that
+holds all that depends on the network's shape: its weights, its binding
+to the program, its plain reference and its roofline counts.  Per-layer
+metrics are read by ``bench/metrics/<stem>.py`` (the metric's name up to
+its first ``.``: ``conv_roofline.py`` reads ``conv_roofline.latency`` and
 ``conv_roofline.throughput``), each a ``read(ctx)`` that returns a number
-or None.  Nothing here names a
-cell, a configuration or a metric: new ones are new files.
+or None.  Nothing here names a cell, a configuration, a metric or a
+network's form: new ones are new files.
 
 A run: set-up (weights made on the device from the seed, the program's
 numerics preparation, the plan, compile through the persistent cache,
 warm-up of the cell's own shape), then a closed loop of requests for
 ``--seconds``.  A request is a host float32 frame batch from the seeded
-pool: ``device_put``, the jitted ``cnn_forward``, the logits fetched to
-the host; its latency runs from submission to logits on the host.  After
-the window a sample of the completed requests drawn from the seed is
-compared with the plain reference of ``bench/reference.py``, on the host
-CPU.  The last line of stdout is the JSON result; the numbers compared,
-each with its limit, are the last lines of stderr.
+pool: ``device_put``, the program's jitted forward, the logits fetched
+to the host; its latency runs from submission to logits on the host.
+After the window a sample of the completed requests drawn from the seed
+is compared with the form's plain reference, on the host CPU.  The last
+line of stdout is the JSON result; the numbers compared, each with its
+limit, are the last lines of stderr.
 """
 from __future__ import annotations
 
@@ -51,7 +54,7 @@ for p in (str(ROOT / "src"), str(ROOT)):
 
 import numpy as np  # noqa: E402
 
-from bench import reference, roofline  # noqa: E402
+from bench import forms, reference, roofline  # noqa: E402
 from bench import trace as tr  # noqa: E402
 
 TRACE_DIR = ROOT / ".bench_trace"
@@ -70,6 +73,7 @@ class Cell:
     end_to_end: list
     per_layer: list
     readers: dict
+    form: object  # the module of bench/networks/<cfg["form"]>.py
 
 
 def _applies(metric: dict, workload: str) -> bool:
@@ -106,6 +110,7 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
         per_layer=per_layer,
         readers={m["name"]: find_reader(m["name"], root / "bench" / "metrics")
                  for m in per_layer},
+        form=forms.find_form(cfg, root / "bench" / "networks"),
     )
 
 
@@ -144,10 +149,10 @@ def build(cell: Cell, seed: int, devices, log=log) -> Setup:
     from repro.core.quantization import NumericsPolicy
     from repro.core.template import default_template
     from repro.launch.mesh import make_mesh
-    from repro.models import cnn as C
     from repro.parallel import sharding as sh
 
     cfg, traffic = cell.cfg, cell.traffic
+    prog = cell.form.program(cfg)
     spans: dict = {}
 
     @contextlib.contextmanager
@@ -164,22 +169,18 @@ def build(cell: Cell, seed: int, devices, log=log) -> Setup:
         batches = pool.reshape(traffic["pool"] // b, b, hw, hw, ch)
     dev0 = SingleDeviceSharding(devices[0])
     with span("weights"):
-        weights = jax.jit(partial(reference.init_params, cfg), out_shardings=dev0)(
+        weights = jax.jit(partial(cell.form.init_params, cfg), out_shardings=dev0)(
             reference.seed_key(seed))
         jax.block_until_ready(weights)
-    spec = C.CNNSpec(cfg["network"], hw, ch, cfg["n_classes"],
-                     convs=tuple(tuple(c) for c in cfg["convs"]), fcs=tuple(cfg["fcs"]))
     tpl = default_template(cfg["template"])
     policy = None
     params = weights
     if cfg["policy"]:
         with span("calibrate"):
             x_cal = jax.device_put(batches[0], dev0)
-            policy = C.calibrate_cnn_policy(tpl, spec, weights, x_cal,
-                                            base=NumericsPolicy(cfg["policy"]))
+            policy = prog.calibrate(tpl, weights, x_cal, NumericsPolicy(cfg["policy"]))
         with span("quantize"):
-            params = jax.block_until_ready(
-                C.quantize_cnn_params(tpl, spec, weights, policy))
+            params = jax.block_until_ready(prog.quantize(tpl, weights, policy))
         log(f"activation grid (calibrated): {policy.fmt}")
     mesh_ctx = contextlib.nullcontext
     x_sharding = dev0
@@ -192,12 +193,12 @@ def build(cell: Cell, seed: int, devices, log=log) -> Setup:
         plan_kw = {"mesh": mesh, "spatial": "data"}
     with mesh_ctx():
         with span("plan"):
-            plan = C.plan_cnn(tpl, spec, (b, hw, hw, ch), **plan_kw)
+            plan = prog.plan(tpl, (b, hw, hw, ch), **plan_kw)
         for line in plan.describe():
             log(f"  plan {line}")
 
         def fwd(p, x):
-            return C.cnn_forward(tpl, spec, p, x, policy=policy, plan=plan)
+            return prog.forward(tpl, p, x, policy, plan)
 
         with span("compile"):
             x_spec = jax.ShapeDtypeStruct((b, hw, hw, ch), np.float32, sharding=x_sharding)
@@ -265,8 +266,8 @@ def closed_loop(st: Setup, traffic: dict, seconds: float, n_classes: int,
 
 
 def check(cell: Cell, st: Setup, win: Window, seed: int) -> dict:
-    """Compare a seeded sample of the window's requests with the plain
-    reference on the host CPU: {number name: {"value", "limit"}}.  Fixed
+    """Compare a seeded sample of the window's requests with the form's
+    plain reference on the host CPU: {number name: {"value", "limit"}}.  Fixed
     point counts the logits that differ; float32 takes the largest gap
     over the largest reference logit."""
     import jax
@@ -284,17 +285,10 @@ def check(cell: Cell, st: Setup, win: Window, seed: int) -> dict:
     weights = jax.device_put(jax.device_get(st.weights), cpu)
     with jax.default_device(cpu):
         if ref_cfg["kind"] == "fixed":
-            bits = ref_cfg["bits"]
-            act = reference.grid_frac(float(np.abs(st.batches[0]).max()), bits)
-            q = reference.quantize_params(weights, act, bits)
-            fracs = [layer.pop("frac") for layer in q]
-            fn = jax.jit(lambda q, x: reference.fixed_forward(
-                cfg, [dict(layer, frac=f) for layer, f in zip(q, fracs)], x, act))
+            fn = cell.form.fixed_reference(cfg, weights, st.batches[0], ref_cfg["bits"])
         else:
-            q = weights
-            fn = jax.jit(partial(reference.float_forward, cfg,
-                                 precision=ref_cfg["precision"]))
-        ref = np.asarray(fn(q, jax.device_put(x, cpu)))
+            fn = cell.form.float_reference(cfg, weights, ref_cfg["precision"])
+        ref = np.asarray(fn(jax.device_put(x, cpu)))
     if ref_cfg["kind"] == "fixed":
         value = int(np.sum(got != ref))
     else:
@@ -314,7 +308,7 @@ class Context:
     traffic: dict
     chips: int
     peaks: dict
-    counts: list  # roofline.layer_counts at the cell's batch, per forward
+    counts: list  # the form's layer_counts at the cell's batch, per forward
     compile_s: float
     forwards: int  # forwards run inside the traced window
     images: int  # images completed inside the traced window
@@ -453,7 +447,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
         ctx = Context(
             cfg=cell.cfg, traffic=traffic, chips=len(devices),
             peaks=roofline.load_peaks(d0.device_kind),
-            counts=roofline.layer_counts(cell.cfg, traffic["batch"]),
+            counts=cell.form.layer_counts(cell.cfg, traffic["batch"]),
             compile_s=st.compile_s, forwards=win.attempted,
             images=win.images_in_window, window_s=win.seconds, trace=t, lo=lo, hi=hi)
         metrics = {}
