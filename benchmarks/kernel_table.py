@@ -126,14 +126,14 @@ def spatial_tiling_row() -> dict:
     # best legal two-block config on the same layer (large top: the DMA
     # configs dominate the ranking, the two-block baseline sits further down)
     two_blk = next(c for c in explore_conv_spatial(
-        514, 514, 64, 3, 3, 512, 512, 64, 1, TPU_V5E, 4, top=4096)
+        514, 514, 64, 3, 3, 512, 512, 64, TPU_V5E, 4, top=4096)
         if c.halo_mode == "two_block")
     vm = {mode: direct_conv_vmem(
         514, 514, 64, 3, 3, 512, 512, two_blk.tau, 4,
         tile_rows=two_blk.tile_rows, halo_mode=mode)
         for mode in ("two_block", "dma")}
     tr = {mode: direct_conv_input_traffic(
-        514, 514, 64, 3, 3, 512, 512, 64, 1, two_blk.tau, 4,
+        514, 514, 64, 3, 3, 512, 512, 64, two_blk.tau, 4,
         tile_rows=two_blk.tile_rows, halo_mode=mode)
         for mode in ("two_block", "dma")}
     # numeric differential at a budget that forces tiling on a small layer

@@ -295,10 +295,10 @@ def _infer_halo_mode(ho: int, wo: int, th: int, tw: int, halo_mode) -> str:
     return "two_block" if th < ho else "none"
 
 
-def _dma_window(th: int, tw: int, kh: int, kw: int, stride: int, in_bytes: int):
+def _dma_window(th: int, tw: int, kh: int, kw: int, in_bytes: int):
     """(rows, cols) of the input window one DMA-regime tile copies — exactly
     what kernels/conv2d.py allocates and fetches."""
-    return stride * th + kh - stride, dma_window_cols(stride * tw + kw - stride, in_bytes)
+    return th + kh - 1, dma_window_cols(tw + kw - 1, in_bytes)
 
 
 def _lanes(c: int) -> int:
@@ -307,10 +307,14 @@ def _lanes(c: int) -> int:
 
 def direct_conv_vmem(
     hp: int, wp: int, cin: int, kh: int, kw: int, ho: int, wo: int, tau: int,
-    in_bytes: int, acc_bytes: int = 4, *, stride: int = 1, tile_rows: int = 0,
+    in_bytes: int, acc_bytes: int = 4, *, tile_rows: int = 0,
     tile_cols: int = 0, halo_mode: Optional[str] = None,
 ) -> int:
     """VMEM working set of one direct-conv grid step (double-buffered I/O).
+
+    The geometry is the stride-1 conv the kernel runs: a strided layer's is
+    that over its space-to-depth input
+    (:func:`~repro.core.tiling.space_to_depth_geometry`).
 
     Every buffer is counted as the chip lays it out
     (:func:`~repro.core.tiling.padded_bytes`): channels pad to 128 lanes and
@@ -320,13 +324,13 @@ def direct_conv_vmem(
     * ``"none"`` — untiled: the whole padded image slab is resident
       (double-buffered).
     * ``"two_block"`` — row-tiled with blocked successor reads: each step
-      holds *two* adjacent ``stride·tile_rows``-row full-width input blocks
-      (the tile plus the successor supplying the ``kh − stride`` halo rows)
+      holds *two* adjacent ``tile_rows``-row full-width input blocks
+      (the tile plus the successor supplying the ``kh − 1`` halo rows)
       plus the same-sized buffer the kernel stitches them into — a ~6×
       tile-rows residency.
     * ``"dma"`` — (𝒯, ℭ)-tiled with manual async copies: exactly the
-      ``stride·tile_rows + kh − stride`` × ``stride·tile_cols + kw −
-      stride`` input window a tile reads, double-buffered (×2) for the
+      ``tile_rows + kh − 1`` × ``tile_cols + kw − 1`` input window a
+      tile reads, double-buffered (×2) for the
       prefetch pipeline — roughly half the two-block residency at equal
       tile_rows, and the only regime that tiles the width.
 
@@ -344,9 +348,9 @@ def direct_conv_vmem(
         if tw < wo:
             raise ValueError("two_block halo cannot tile columns (use 'dma')")
         # two double-buffered input blocks + the in-kernel stitch buffer
-        x = 3 * 2 * stride * th * padded_bytes(wp, cin, in_bytes)
+        x = 3 * 2 * th * padded_bytes(wp, cin, in_bytes)
     elif mode == "dma":
-        rows_in, cols_in = _dma_window(th, tw, kh, kw, stride, in_bytes)
+        rows_in, cols_in = _dma_window(th, tw, kh, kw, in_bytes)
         x = 2 * rows_in * padded_bytes(cols_in, cin, in_bytes)
     else:
         raise ValueError(f"unknown halo_mode {mode!r}")
@@ -360,7 +364,7 @@ def direct_conv_vmem(
 
 def direct_conv_hbm_traffic(
     hp: int, wp: int, cin: int, kh: int, kw: int, ho: int, wo: int, cout: int,
-    stride: int, tau: int, in_bytes: int, *, tile_rows: int = 0,
+    tau: int, in_bytes: int, *, tile_rows: int = 0,
     tile_cols: int = 0, halo_mode: Optional[str] = None,
 ) -> int:
     """Modeled HBM bytes one forward pass of the layer actually moves.
@@ -372,7 +376,7 @@ def direct_conv_hbm_traffic(
     * the image streams once per τ-way (ceil(cout/τ) output-channel tiles);
       the two-block regime additionally re-streams every full-width block
       ~2× (each block is also its predecessor's halo), while the DMA regime
-      fetches each tile's exact window once — only the ``kh/kw − stride``
+      fetches each tile's exact window once — only the ``kh/kw − 1``
       overlap between neighbouring windows is paid twice,
     * the τ-wide weight slab is re-fetched once per spatial tile,
     * padded output tiles (tiles·th ≥ ho etc.) and padded channels
@@ -389,9 +393,9 @@ def direct_conv_hbm_traffic(
     if mode == "none":
         x_traffic = ways * hp * wp * lanes
     elif mode == "two_block":
-        x_traffic = ways * tiles_r * 2 * stride * th * wp * lanes
+        x_traffic = ways * tiles_r * 2 * th * wp * lanes
     elif mode == "dma":
-        rows_in, cols_in = _dma_window(th, tw, kh, kw, stride, in_bytes)
+        rows_in, cols_in = _dma_window(th, tw, kh, kw, in_bytes)
         x_traffic = ways * tiles * min(hp, rows_in) * min(wp, cols_in) * lanes
     else:
         raise ValueError(f"unknown halo_mode {mode!r}")
@@ -402,7 +406,7 @@ def direct_conv_hbm_traffic(
 
 def direct_conv_input_traffic(
     hp: int, wp: int, cin: int, kh: int, kw: int, ho: int, wo: int, cout: int,
-    stride: int, tau: int, in_bytes: int, *, tile_rows: int = 0,
+    tau: int, in_bytes: int, *, tile_rows: int = 0,
     tile_cols: int = 0, halo_mode: Optional[str] = None,
 ) -> int:
     """The input-stream component of :func:`direct_conv_hbm_traffic` alone.
@@ -412,7 +416,7 @@ def direct_conv_input_traffic(
     it is what the bench table's ≤ 0.6× DMA-vs-two-block gate compares.
     """
     full = direct_conv_hbm_traffic(
-        hp, wp, cin, kh, kw, ho, wo, cout, stride, tau, in_bytes,
+        hp, wp, cin, kh, kw, ho, wo, cout, tau, in_bytes,
         tile_rows=tile_rows, tile_cols=tile_cols, halo_mode=halo_mode,
     )
     th, tw = _eff_tiles(ho, wo, tile_rows, tile_cols)
@@ -472,7 +476,7 @@ def conv_choice_from_doc(doc: dict) -> ConvTileChoice:
 
 def _conv_tile_score(
     tau: int, th: int, tw: int, halo_mode: str, hp: int, wp: int, cin: int,
-    kh: int, kw: int, ho: int, wo: int, cout: int, stride: int, spec: TpuSpec,
+    kh: int, kw: int, ho: int, wo: int, cout: int, spec: TpuSpec,
     in_bytes: int,
 ) -> float:
     """Compute-unit utilization of one (τ, 𝒯, ℭ, regime) configuration.
@@ -487,7 +491,7 @@ def _conv_tile_score(
     the two-sided halo overlap shrinks with the perimeter-to-area ratio.
     """
     traffic = direct_conv_hbm_traffic(
-        hp, wp, cin, kh, kw, ho, wo, cout, stride, tau, in_bytes,
+        hp, wp, cin, kh, kw, ho, wo, cout, tau, in_bytes,
         tile_rows=th, tile_cols=tw, halo_mode=halo_mode,
     )
     ideal = direct_conv_ideal_traffic(hp, wp, cin, kh, kw, ho, wo, cout, in_bytes)
@@ -523,7 +527,6 @@ def explore_conv_spatial(
     ho: int,
     wo: int,
     cout: int,
-    stride: int,
     spec: TpuSpec = TPU_V5E,
     in_bytes: int = 4,
     top: int = 5,
@@ -534,13 +537,14 @@ def explore_conv_spatial(
     τ ladder: min(lane, cout) halved down to 8 (same ladder the engine used
     pre-tiling).  Tile ladders (:func:`_tile_ladder`): halving steps plus
     every exact divisor of the extent.  Three regimes are enumerated:
-    untiled whole-slab, row-tiled two-block (legality: stride·tile_rows ≥
-    kh so the successor block covers the tap window), and (𝒯, ℭ)-tiled
+    untiled whole-slab, row-tiled two-block (legality: tile_rows ≥ kh so
+    the successor block covers the tap window), and (𝒯, ℭ)-tiled
     manual-DMA — whose window always covers the taps and which is the only
     regime that tiles the width, so extreme-width layers stay direct
     instead of falling back to im2col.  Its one legality bound is the
     chip's: a packed (16/8-bit) window starts on an 8-column group, so a
-    column tile must advance a multiple of 8 input columns.
+    column tile must advance a multiple of 8 input columns.  The geometry
+    is the stride-1 conv the kernel runs (see :func:`direct_conv_vmem`).
     """
     tau0 = min(spec.lane, cout)
     taus = []
@@ -550,30 +554,29 @@ def explore_conv_spatial(
         if t <= 8:
             break
         t //= 2
-    th_two_min = max(1, ceil_div(kh, stride))
     configs: list[tuple[int, int, str]] = [(ho, wo, "none")]
-    for th in _tile_ladder(ho, th_two_min):
-        if th < ho and stride * th >= kh:
+    for th in _tile_ladder(ho, kh):
+        if th < ho and th >= kh:
             configs.append((th, wo, "two_block"))
     for th in _tile_ladder(ho, 1):
         for tw in _tile_ladder(wo, 1):
             if th >= ho and tw >= wo:
                 continue  # the untiled regime already covers the whole slab
-            if tw < wo and in_bytes < 4 and (stride * tw) % spec.sublane:
+            if tw < wo and in_bytes < 4 and tw % spec.sublane:
                 # packed (16/8-bit) windows must start on an 8-column group
                 continue
             configs.append((th, tw, "dma"))
     out: list[ConvTileChoice] = []
     for tau, (th, tw, mode) in itertools.product(taus, configs):
         vmem = direct_conv_vmem(
-            hp, wp, cin, kh, kw, ho, wo, tau, in_bytes, stride=stride,
+            hp, wp, cin, kh, kw, ho, wo, tau, in_bytes,
             tile_rows=th, tile_cols=tw, halo_mode=mode,
         )
         if vmem > spec.vmem_bytes:
             continue
         score = _conv_tile_score(
-            tau, th, tw, mode, hp, wp, cin, kh, kw, ho, wo, cout, stride,
-            spec, in_bytes,
+            tau, th, tw, mode, hp, wp, cin, kh, kw, ho, wo, cout, spec,
+            in_bytes,
         )
         out.append(
             ConvTileChoice(
@@ -605,14 +608,11 @@ def default_conv_tile_for(
     ho: int,
     wo: int,
     cout: int,
-    stride: int,
     spec: TpuSpec = TPU_V5E,
     in_bytes: int = 4,
 ) -> Optional[ConvTileChoice]:
     """Best-scoring legal direct-conv config, or None (→ im2col fallback)."""
-    ranked = explore_conv_spatial(
-        hp, wp, cin, kh, kw, ho, wo, cout, stride, spec, in_bytes
-    )
+    ranked = explore_conv_spatial(hp, wp, cin, kh, kw, ho, wo, cout, spec, in_bytes)
     return ranked[0] if ranked else None
 
 

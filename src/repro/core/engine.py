@@ -60,7 +60,7 @@ from .quantization import (
     quantize,
     quantize_qtensor,
 )
-from .tiling import MatmulBlock, TPU_V5E, TpuSpec, clamp_block
+from .tiling import MatmulBlock, TPU_V5E, TpuSpec, clamp_block, space_to_depth_geometry
 
 __all__ = [
     "PLAN_STORE_ENV",
@@ -133,13 +133,17 @@ def batch_rungs(slots: int) -> tuple:
 PLAN_STORE_FORMAT = "repro-plan-store"
 #: v2 (PR 8) added the ConvTileChoice column-tiling fields (tile_cols,
 #: col_tiles, halo_mode).  v3 (PR 10) added the per-layer precision section
-#: (the drift-aware int8/int16 grid assignments, DESIGN.md §11).  Older
-#: stores still load leniently: v2 keeps its gemm *and* conv entries (their
-#: schemas are unchanged) and simply has no precision pins; v1 keeps gemm
-#: only — its pre-column-tiling conv entries are dropped so those layers
-#: re-plan against the three-regime DSE instead of raising PlanStoreError.
-PLAN_STORE_VERSION = 3
-PLAN_STORE_COMPAT_VERSIONS = (1, 2)
+#: (the drift-aware int8/int16 grid assignments, DESIGN.md §11).  v4 keys a
+#: conv entry by the stride-1 conv the kernel runs, without the layer's
+#: stride (a strided layer plans over its space-to-depth input).  Older
+#: stores still load leniently: v2 and v3 keep their gemm entries and the
+#: conv entries of stride-1 layers (v2 has no precision pins); their strided
+#: conv entries, planned over strided windows, are dropped and re-plan; v1
+#: keeps gemm only — its pre-column-tiling conv entries are dropped so those
+#: layers re-plan against the three-regime DSE instead of raising
+#: PlanStoreError.
+PLAN_STORE_VERSION = 4
+PLAN_STORE_COMPAT_VERSIONS = (1, 2, 3)
 #: Env var naming the default persisted plan-store path.  When set, the
 #: launch drivers (serve/train) and the benchmark harness warm-start from it
 #: and write newly planned shapes back on exit.
@@ -233,16 +237,16 @@ class PlanRegistry:
     def conv_tile_for(
         self,
         hp: int, wp: int, cin: int, kh: int, kw: int, ho: int, wo: int,
-        cout: int, stride: int, in_bytes: int, spec: TpuSpec = TPU_V5E,
+        cout: int, in_bytes: int, spec: TpuSpec = TPU_V5E,
     ):
         """Memoized :func:`dse.default_conv_tile_for` (None = no fit cached)."""
-        key = (hp, wp, cin, kh, kw, ho, wo, cout, stride, in_bytes, spec)
+        key = (hp, wp, cin, kh, kw, ho, wo, cout, in_bytes, spec)
         if key in self._conv_tiles:
             self.hits += 1
             return self._conv_tiles[key]
         self.misses += 1
         choice = dse.default_conv_tile_for(
-            hp, wp, cin, kh, kw, ho, wo, cout, stride, spec, in_bytes
+            hp, wp, cin, kh, kw, ho, wo, cout, spec, in_bytes
         )
         self._conv_tiles[key] = choice
         self._conv_src[key] = "analytic"
@@ -464,7 +468,8 @@ class PlanRegistry:
         version (``PLAN_STORE_COMPAT_VERSIONS``) loads leniently: gemm
         entries merge from every compat version (their schema is unchanged),
         conv entries merge from v2+ (v1's pre-column-tiling docs are dropped
-        so those layers re-plan under the current DSE), and precision pins
+        so those layers re-plan under the current DSE; v2/v3 keys lose their
+        stride, and their strided entries are dropped), and precision pins
         merge from v3+ (older stores simply have none, so those networks
         re-run the drift sweep) — a warm fleet store survives the upgrade
         instead of crashing the loader.
@@ -509,8 +514,16 @@ class PlanRegistry:
                     # pre-column-tiling choice docs lack (tile_cols,
                     # halo_mode); dropping them re-plans those layers
                     continue
-                key = tuple(int(v) for v in e["key"]) + (spec_at(e["spec"]),)
-                if len(key) != 11:
+                shape = [int(v) for v in e["key"]]
+                if version < 4:
+                    # v2/v3 keys carry the layer's stride (index 8); a
+                    # strided entry was planned over strided windows
+                    if len(shape) != 10:
+                        raise PlanStoreError(f"bad conv key of length {len(shape)}")
+                    if shape.pop(8) != 1:
+                        continue
+                key = tuple(shape) + (spec_at(e["spec"]),)
+                if len(key) != 10:
                     raise PlanStoreError(f"bad conv key of length {len(key)}")
                 choice = e["choice"]
                 conv_tiles[key] = (
@@ -832,6 +845,10 @@ class ConvPlan:
         (S, N, lx, W, C) layout via :meth:`Engine._conv2d_spatial`; ``pad``
         is then 0 (the halo exchange's zero fill *is* the H padding, and
         the executor pre-pads W by ``halo.pad``).
+
+    A strided direct conv runs as the stride-1 conv it equals over its
+    space-to-depth input (:attr:`space_to_depth`); τ, tiles and VMEM are
+    those of that stride-1 conv.
     """
 
     route: str
@@ -847,6 +864,13 @@ class ConvPlan:
     col_tiles: int = 1
     halo_mode: str = "none"
     halo: Optional[object] = None  # SpatialHalo (kept untyped: lazy import)
+
+    @property
+    def space_to_depth(self) -> bool:
+        """Whether the kernel runs over the layer's space-to-depth input:
+        the direct kernels read unit-stride windows only, so a strided
+        direct conv does (:func:`repro.kernels.ops.space_to_depth_operands`)."""
+        return self.route == "direct" and self.stride > 1
 
 
 #: VMEM working-set model of one direct-conv grid step — lives with the rest
@@ -1038,8 +1062,11 @@ class Engine:
             return ConvPlan("xla", stride, pad, 0, None, gemm, 0)
         if route != "im2col":
             in_bytes = (self.config.qformat.total_bits // 8) if backend == "q16" else 4
+            # planned as the stride-1 conv the kernel runs (the layer's
+            # space-to-depth input when strided)
             choice = self.plan_cache.conv_tile_for(
-                hp, wp, cin, kh, kw, ho, wo, cout, stride, in_bytes, self.config.hw
+                *space_to_depth_geometry(hp, wp, cin, kh, kw, stride),
+                ho, wo, cout, in_bytes, self.config.hw,
             )
             if choice is not None:
                 tile_rows = 0 if choice.tile_rows >= ho else choice.tile_rows

@@ -32,6 +32,7 @@ __all__ = [
     "device_spec",
     "dma_window_cols",
     "padded_bytes",
+    "space_to_depth_geometry",
     "tpu_spec",
 ]
 
@@ -205,6 +206,25 @@ def dma_window_cols(cols: int, itemsize: int, spec: TpuSpec = TPU_V5E) -> int:
     if itemsize >= 4:
         return cols
     return ceil_div(cols, spec.sublane) * spec.sublane
+
+
+def space_to_depth_geometry(
+    hp: int, wp: int, cin: int, kh: int, kw: int, stride: int
+) -> tuple[int, int, int, int, int]:
+    """(rows, cols, channels, kh, kw) of the stride-1 conv the direct conv
+    kernels run for a stride-``stride`` VALID conv of a (kh, kw) kernel over
+    an (hp, wp, cin) input: the conv itself at stride 1, else the conv over
+    its space-to-depth input (``kernels/ops.py:space_to_depth_operands``).
+    Input pixel (s·a + p, s·b + q) becomes channel group (p, q) of pixel
+    (a, b), and tap (s·i + p, s·j + q) becomes tap (i, j) of that group.
+    Only the phases a tap reads are kept (one for a 1×1 kernel: a
+    subsample), so the kernel is ceil(k / s) taps a side over min(s, k)²
+    channel groups.
+    """
+    s = stride
+    ho, wo = (hp - kh) // s + 1, (wp - kw) // s + 1
+    qh, qw = ceil_div(kh, s), ceil_div(kw, s)
+    return ho + qh - 1, wo + qw - 1, min(s, kh) * min(s, kw) * cin, qh, qw
 
 
 #: Output pixels (GEMM rows) one in-kernel chunk of the direct conv

@@ -14,9 +14,10 @@ chunk accumulating its K² tap products in registers before the fused
 epilogue writes it back, so the compiled kernel's size does not grow with
 the tile.
 
-Strided convs (AlexNet conv1) are handled *directly*: each tap reads a
-strided slice of the resident image slab (per-tap strided slicing), so the
-same kernel covers stride ∈ {1, 2, 4, ...} without falling back to im2col.
+The kernels read unit-stride windows only: Mosaic loads no strided window
+of 16/8-bit data, nor of 32-bit data wider than 128 lanes, so the
+``kernels/ops.py`` wrappers run a strided conv as the stride-1 conv over
+its space-to-depth input (``space_to_depth_operands``).
 
 The float and fixed-point kernels are one kernel with two contractions: f32
 taps at full f32 MXU precision, or int16/int8 taps through
@@ -27,22 +28,22 @@ slab exceeds the VMEM budget, ``tile_rows`` adds an output-row tile axis to
 the grid, in one of two halo regimes (DESIGN.md §2):
 
 * ``halo_mode="two_block"`` (PR 2, row tiling only): each grid step reads
-  the tile's ``stride·tile_rows``-row input block plus its *successor*
+  the tile's ``tile_rows``-row input block plus its *successor*
   block as ordinary blocked BlockSpecs and concatenates them in-kernel —
-  the second block supplies the ``kh - stride`` halo rows a tap window
-  reads past the tile boundary.  Legality: ``stride·tile_rows ≥ kh`` so one
+  the second block supplies the ``kh - 1`` halo rows a tap window
+  reads past the tile boundary.  Legality: ``tile_rows ≥ kh`` so one
   successor block always covers the halo.  Residency tax: ~2× the tile's
   input rows live in VMEM, and every input block streams from HBM twice
   (once as a tile, once as its predecessor's halo).
 
 * ``halo_mode="dma"``: the input stays an unblocked HBM/ANY operand and the
   kernel issues an explicit async copy of *exactly* the window a tile
-  reads — ``stride·tile_rows + kh − stride`` input rows (and, when
-  ``tile_cols`` also tiles the width, ``stride·tile_cols + kw − stride``
-  columns) — into a double-buffered VMEM scratch; the next tile's window
-  prefetches while the current one computes.  No successor block, no
-  concat copy, no ``stride·tile_rows ≥ kh`` legality bound, and each input
-  byte streams from HBM once per τ-way plus the (kh−stride)-row overlap.
+  reads — ``tile_rows + kh − 1`` input rows (and, when ``tile_cols``
+  also tiles the width, ``tile_cols + kw − 1`` columns) — into a
+  double-buffered VMEM scratch; the next tile's window prefetches while
+  the current one computes.  No successor block, no concat copy, no
+  ``tile_rows ≥ kh`` legality bound, and each input byte streams from HBM
+  once per τ-way plus the (kh−1)-row overlap.
   ``tile_cols`` adds the paper's ℭ column-tile axis so extreme-width
   layers tile as (𝒯, ℭ) blocks instead of spilling to im2col.
 
@@ -82,10 +83,6 @@ def _float_dot(lhs, rhs):
                    precision=jax.lax.Precision.HIGHEST)
 
 
-def _span(start, size, stride):
-    return pl.ds(start, size, stride) if stride > 1 else pl.ds(start, size)
-
-
 def _float_epilogue(acc, b_ref, *, relu, qout):
     """Fused bias/ReLU/fake-quant on the f32 accumulator (DESIGN.md §3)."""
     if b_ref is not None:
@@ -107,8 +104,8 @@ def _q16_epilogue(acc, b_ref, *, relu, shift, bias_shift, raw_min, raw_max,
     return shift_saturate_i32(acc, shift, raw_min, raw_max, out_dtype)
 
 
-def _conv_tile(src, lead, w_ref, b_ref, o_ref, *, kh, kw, th, tw, stride,
-               dot, epilogue):
+def _conv_tile(src, lead, w_ref, b_ref, o_ref, *, kh, kw, th, tw, dot,
+               epilogue):
     """Direct conv of one (th, tw) output tile.
 
     ``src[lead]``: the (rows, cols, Cin) input window of the tile (``lead``
@@ -117,8 +114,7 @@ def _conv_tile(src, lead, w_ref, b_ref, o_ref, *, kh, kw, th, tw, stride,
     minor dims are not tile-aligned); ``w_ref``: (K², Cin, τ); ``o_ref``:
     (1, th, tw, τ).  Output rows are walked in
     :func:`~repro.core.tiling.conv_chunk_rows` chunks; per chunk every tap is
-    one (rows·tw, Cin) × (Cin, τ) GEMM read straight from ``src`` (per-tap
-    strided slicing when ``stride`` > 1).
+    one (rows·tw, Cin) × (Cin, τ) GEMM read straight from ``src``.
     """
     rc = conv_chunk_rows(th, tw)
     cin = src.shape[-1]
@@ -128,8 +124,7 @@ def _conv_tile(src, lead, w_ref, b_ref, o_ref, *, kh, kw, th, tw, stride,
         acc = None
         for i in range(kh):
             for j in range(kw):
-                lhs = src[(*lead, _span(r0 * stride + i, rc, stride),
-                           _span(j, tw, stride), slice(None))]
+                lhs = src[(*lead, pl.ds(r0 + i, rc), pl.ds(j, tw), slice(None))]
                 d = dot(lhs.reshape(rc * tw, cin), w_ref[i * kw + j])
                 acc = d if acc is None else acc + d
         out = epilogue(acc, b_ref)
@@ -152,7 +147,7 @@ def _conv_block_kernel(*refs, halo, fused_bias, **tile):
         _conv_tile(x1, (0,), w_ref, b_ref, o_ref, **tile)
         return
     # the tap window of the last output row in this tile reads up to
-    # stride*(th-1) + kh - 1 < 2*stride*th rows (stride*th >= kh), so the
+    # th - 1 + kh - 1 < 2*th rows (th >= kh), so the
     # pair of adjacent row blocks, stitched into one buffer, always covers it
     (xs,) = refs
     rows = x1.shape[1]
@@ -170,8 +165,8 @@ def _conv_dma_kernel(*refs, fused_bias, **tile):
     """(𝒯, ℭ)-tiled direct conv with a manual-DMA input halo.
 
     The input operand lives in HBM (``memory_space=ANY``); each (r, c) tile
-    copies exactly its ``stride·th + kh − stride`` × ``stride·tw + kw −
-    stride`` input window into one slot of a double-buffered VMEM scratch.
+    copies exactly its ``th + kh − 1`` × ``tw + kw − 1`` input window into
+    one slot of a double-buffered VMEM scratch.
     The copy for tile k+1 is started on tile k's last τ-way, so the fetch
     overlaps the K² tap GEMMs of the current tile (the classic
     prefetch/compute pipeline); the τ axis is innermost, so each window is
@@ -182,7 +177,7 @@ def _conv_dma_kernel(*refs, fused_bias, **tile):
     w_ref = refs.pop(0)
     b_ref = refs.pop(0) if fused_bias else None
     o_ref, xs_ref, sem = refs
-    th, tw, stride = tile["th"], tile["tw"], tile["stride"]
+    th, tw = tile["th"], tile["tw"]
     b = pl.program_id(0)
     r = pl.program_id(1)
     c = pl.program_id(2)
@@ -199,8 +194,8 @@ def _conv_dma_kernel(*refs, fused_bias, **tile):
         return pltpu.make_async_copy(
             x_hbm.at[
                 b,
-                pl.ds(rr * stride * th, rows_in),
-                pl.ds(cc * stride * tw, cols_in),
+                pl.ds(rr * th, rows_in),
+                pl.ds(cc * tw, cols_in),
                 :,
             ],
             xs_ref.at[slot],
@@ -246,8 +241,8 @@ def _halo_mode_for(tile_rows, tile_cols, ho, wo, halo_mode):
 
 
 def _conv_call(
-    x, w, bias_row, *, stride, tau, tile_rows, tile_cols, halo_mode, dot,
-    epilogue, out_dtype, vmem_limit_bytes,
+    x, w, bias_row, *, tau, tile_rows, tile_cols, halo_mode, dot, epilogue,
+    out_dtype, vmem_limit_bytes,
 ):
     """Shared pallas_call plumbing of the float and fixed-point convs.
 
@@ -259,8 +254,8 @@ def _conv_call(
     n, h, wdt, cin = x.shape
     kh, kw, cin2, cout = w.shape
     assert cin == cin2
-    ho = (h - kh) // stride + 1
-    wo = (wdt - kw) // stride + 1
+    ho = h - kh + 1
+    wo = wdt - kw + 1
     tau = min(tau, cout)
     coutp = -(-cout // tau) * tau
     if coutp != cout:
@@ -274,8 +269,7 @@ def _conv_call(
     tw = tile_cols if 0 < tile_cols < wo else wo
     tiles_r = -(-ho // th)
     tiles_c = -(-wo // tw)
-    tile = dict(kh=kh, kw=kw, th=th, tw=tw, stride=stride, dot=dot,
-                epilogue=epilogue)
+    tile = dict(kh=kh, kw=kw, th=th, tw=tw, dot=dot, epilogue=epilogue)
     fused_bias = bias_row is not None
     if mode == "dma":
         # window copies move whole 128-lane channel tiles: zero channels
@@ -285,10 +279,10 @@ def _conv_call(
             x = jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, cinp - cin)))
             wtaps = jnp.pad(wtaps, ((0, 0), (0, cinp - cin), (0, 0)))
             cin = cinp
-        rows_in = stride * th + kh - stride
-        cols_in = dma_window_cols(stride * tw + kw - stride, x.dtype.itemsize)
-        need_h = stride * th * (tiles_r - 1) + rows_in
-        need_w = stride * tw * (tiles_c - 1) + cols_in
+        rows_in = th + kh - 1
+        cols_in = dma_window_cols(tw + kw - 1, x.dtype.itemsize)
+        need_h = th * (tiles_r - 1) + rows_in
+        need_w = tw * (tiles_c - 1) + cols_in
         if need_h > h or need_w > wdt:
             x = jnp.pad(
                 x, ((0, 0), (0, max(0, need_h - h)), (0, max(0, need_w - wdt)), (0, 0))
@@ -309,11 +303,11 @@ def _conv_call(
     else:
         halo = mode == "two_block"
         if halo:
-            row_in = stride * th  # input rows consumed per output-row tile
+            row_in = th  # input rows consumed per output-row tile
             if row_in < kh:
                 raise ValueError(
-                    f"tile_rows={th} too small: stride*tile_rows ({row_in}) must "
-                    f"cover the {kh}-row tap window for the two-block halo scheme"
+                    f"tile_rows={th} too small: it must cover the {kh}-row tap "
+                    f"window for the two-block halo scheme"
                 )
             # tile r reads blocks r and r+1; the last tile (and its ragged
             # output rows) must see zeros past the real image
@@ -362,7 +356,7 @@ def _conv_call(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "stride", "tau", "relu", "qout", "tile_rows", "tile_cols", "halo_mode",
+        "tau", "relu", "qout", "tile_rows", "tile_cols", "halo_mode",
         "vmem_limit_bytes",
     ),
 )
@@ -371,7 +365,6 @@ def conv2d_pallas(
     w: jax.Array,
     bias: jax.Array | None = None,
     *,
-    stride: int = 1,
     tau: int = 128,
     relu: bool = False,
     qout: QFormat | None = None,
@@ -380,7 +373,7 @@ def conv2d_pallas(
     halo_mode: str = "two_block",
     vmem_limit_bytes: int | None = None,
 ) -> jax.Array:
-    """NHWC VALID conv, any stride.  x: (N,H,W,Cin), w: (K,K,Cin,Cout).
+    """NHWC VALID conv, stride 1.  x: (N,H,W,Cin), w: (K,K,Cin,Cout).
 
     ``bias``: (Cout,) fused into the write-back; ``relu``/``qout``: fused
     nonlinearity and (fake-)quantization to a Q format, applied after bias.
@@ -392,7 +385,7 @@ def conv2d_pallas(
     """
     bias_row = None if bias is None else bias.astype(jnp.float32).reshape(1, -1)
     return _conv_call(
-        x, w, bias_row, stride=stride, tau=tau, tile_rows=tile_rows,
+        x, w, bias_row, tau=tau, tile_rows=tile_rows,
         tile_cols=tile_cols, halo_mode=halo_mode, dot=_float_dot,
         epilogue=functools.partial(_float_epilogue, relu=relu, qout=qout),
         out_dtype=x.dtype, vmem_limit_bytes=vmem_limit_bytes,
@@ -402,7 +395,7 @@ def conv2d_pallas(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "stride", "tau", "relu", "fmt", "shift", "bias_shift", "tile_rows",
+        "tau", "relu", "fmt", "shift", "bias_shift", "tile_rows",
         "tile_cols", "halo_mode", "vmem_limit_bytes",
     ),
 )
@@ -411,7 +404,6 @@ def conv2d_q16_pallas(
     wq: jax.Array,
     bias: jax.Array | None = None,
     *,
-    stride: int = 1,
     tau: int = 128,
     relu: bool = False,
     fmt: QFormat = Q2_14,
@@ -422,7 +414,7 @@ def conv2d_q16_pallas(
     halo_mode: str = "two_block",
     vmem_limit_bytes: int | None = None,
 ) -> jax.Array:
-    """Fixed-point NHWC VALID conv, any stride.  int16/int8 raw Qm.n tensors.
+    """Fixed-point NHWC VALID conv, stride 1.  int16/int8 raw Qm.n tensors.
 
     ``tile_rows`` / ``tile_cols`` / ``halo_mode`` tile the output exactly as
     in :func:`conv2d_pallas`; zero-padded halo rows/columns contribute zero
@@ -444,7 +436,7 @@ def conv2d_q16_pallas(
         out_dtype=fmt.storage_dtype,
     )
     return _conv_call(
-        xq, wq, bias_row, stride=stride, tau=tau, tile_rows=tile_rows,
+        xq, wq, bias_row, tau=tau, tile_rows=tile_rows,
         tile_cols=tile_cols, halo_mode=halo_mode, dot=int_dot,
         epilogue=epilogue, out_dtype=fmt.storage_dtype,
         vmem_limit_bytes=vmem_limit_bytes,

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.quantization import QFormat, Q2_14
-from repro.core.tiling import MatmulBlock, clamp_block
+from repro.core.tiling import MatmulBlock, clamp_block, space_to_depth_geometry
 
 from . import ref
 from .conv2d import conv2d_pallas, conv2d_q16_pallas
@@ -28,6 +28,7 @@ from .matmul_q16 import matmul_q16_pallas
 __all__ = [
     "im2col",
     "conv_gemm_weights",
+    "space_to_depth_operands",
     "matmul_fp",
     "matmul_q16",
     "conv2d",
@@ -69,6 +70,33 @@ def im2col(x: jax.Array, kh: int, kw: int, stride: int = 1):
     if cast is not None:
         cols = cols.astype(cast)
     return cols, ho, wo
+
+
+def space_to_depth_operands(x: jax.Array, w: jax.Array, stride: int):
+    """A stride-``stride`` VALID conv's (already padded) input and weights
+    -> those of the stride-1 conv the direct kernels run for it, which read
+    unit-stride windows only
+    (:func:`repro.core.tiling.space_to_depth_geometry`): at stride 1 the
+    operands themselves; else the input's phase (p, q) pixels stacked as
+    channel group (p, q), each kernel tap moved to its phase's group, zero
+    taps where the kernel ends.  Exact in any numerics (a zero tap adds
+    zero products)."""
+    if stride == 1:
+        return x, w
+    n, h, wd, c = x.shape
+    kh, kw, _, cout = w.shape
+    s = stride
+    hs, ws, _, qh, qw = space_to_depth_geometry(h, wd, c, kh, kw, s)
+    ph, pw = min(s, kh), min(s, kw)
+    # rows past ``s·hs`` feed no output; rows a zero tap reads may be absent
+    x = x[:, :s * hs, :s * ws]
+    x = jnp.pad(x, ((0, 0), (0, s * hs - x.shape[1]), (0, s * ws - x.shape[2]), (0, 0)))
+    x = x.reshape(n, hs, s, ws, s, c)[:, :, :ph, :, :pw]
+    x = x.transpose(0, 1, 3, 2, 4, 5).reshape(n, hs, ws, ph * pw * c)
+    w = jnp.pad(w, ((0, s * qh - kh), (0, s * qw - kw), (0, 0), (0, 0)))
+    w = w.reshape(qh, s, qw, s, c, cout)[:, :ph, :, :pw]
+    w = w.transpose(0, 2, 1, 3, 4, 5).reshape(qh, qw, ph * pw * c, cout)
+    return x, w
 
 
 def conv_gemm_weights(w: jax.Array) -> jax.Array:
@@ -155,13 +183,17 @@ def conv2d(
     route == "im2col": im2col + the Pallas matmul kernel — same unified-GEMM
     semantics; used when no direct (τ, tile_rows, tile_cols) config fits
     the VMEM budget (DESIGN.md §2).  Epilogue (bias/ReLU/quant) is fused on
-    both routes.
+    both routes.  A strided direct conv runs as the stride-1 conv over its
+    space-to-depth input (:func:`space_to_depth_operands`): Mosaic loads no
+    strided window of 16/8-bit data, nor of 32-bit data wider than 128
+    lanes.
     """
     if padding:
         x = jnp.pad(x, ((0, 0), (padding, padding), (padding, padding), (0, 0)))
     if route == "direct":
+        x, w = space_to_depth_operands(x, w, stride)
         return conv2d_pallas(
-            x, w, bias, stride=stride, tau=tau, relu=relu, qout=qout,
+            x, w, bias, tau=tau, relu=relu, qout=qout,
             tile_rows=tile_rows, tile_cols=tile_cols, halo_mode=halo_mode,
             vmem_limit_bytes=vmem_limit_bytes,
         )
@@ -196,12 +228,15 @@ def conv2d_q16(
     vmem_limit_bytes: int | None = None,
 ) -> jax.Array:
     """NHWC conv, fixed-point path.  All tensors int16 raw Qm.n; ``shift`` /
-    ``bias_shift`` carry mixed-format write-back gaps (see matmul_q16)."""
+    ``bias_shift`` carry mixed-format write-back gaps (see matmul_q16); a
+    strided direct conv runs over its space-to-depth input, as in
+    :func:`conv2d`."""
     if padding:
         xq = jnp.pad(xq, ((0, 0), (padding, padding), (padding, padding), (0, 0)))
     if route == "direct":
+        xq, wq = space_to_depth_operands(xq, wq, stride)
         return conv2d_q16_pallas(
-            xq, wq, bias, stride=stride, tau=tau, relu=relu, fmt=fmt,
+            xq, wq, bias, tau=tau, relu=relu, fmt=fmt,
             shift=shift, bias_shift=bias_shift, tile_rows=tile_rows,
             tile_cols=tile_cols, halo_mode=halo_mode,
             vmem_limit_bytes=vmem_limit_bytes,

@@ -8,6 +8,12 @@ ReLU are fused into the compute unit's write-back (DESIGN.md §3).
 ``quantized=True`` inference reproduces the deployed numerics: weights and
 activations fake- or fully-quantized to Q2.14 around every GEMM.
 
+A spec is one of two shapes: a chain (convs with optional max pools, then
+FC layers: AlexNet, VGG16, LeNet) or a bottleneck residual network
+(ResNet-50 v1.5: a stem, stages of 1×1 → 3×3 → 1×1 blocks whose outputs
+join their shortcut in a residual add, a global average pool and the
+classifier).  The same entry points serve both.
+
 Following the paper's plan-then-execute flow, :func:`plan_cnn` compiles the
 whole network's kernel routes and Pallas blocks **once** per (template
 config, spec, input shape) and every ``cnn_forward`` step reuses that plan —
@@ -38,8 +44,10 @@ __all__ = [
     "ALEXNET",
     "VGG16",
     "LENET",
+    "RESNET50",
     "CNN_ZOO",
     "NetworkPlan",
+    "conv_shapes",
     "init_cnn",
     "plan_cnn",
     "cnn_layer_names",
@@ -53,14 +61,31 @@ __all__ = [
 
 @dataclasses.dataclass(frozen=True)
 class CNNSpec:
+    """A network's shape.  A chain gives ``convs`` and ``fcs``; a bottleneck
+    residual network gives ``stem``, ``stem_pool`` and ``stages`` (and
+    ``fcs`` = () for a bare classifier), and then runs: the stem conv with
+    ReLU, the max pool, every stage's blocks, a global average pool, the
+    classifier.  A block is 1×1 (``mid``) → 3×3 (``mid``, the stage's
+    stride on the first block, as in ResNet v1.5) → 1×1 (``out``, no ReLU);
+    its shortcut is the block's input, or a 1×1 projection (stride, no
+    ReLU) where the width or the stride changes; the add is followed by
+    ReLU."""
+
     name: str
     input_hw: int
     input_ch: int
     n_classes: int
     # conv stages: (out_ch, k, stride, pad, pool) — pool is maxpool window (0 = none)
-    convs: tuple
+    convs: tuple = ()
     # fc widths (excluding the final classifier)
-    fcs: tuple
+    fcs: tuple = ()
+    stem: tuple = ()  # (out_ch, k, stride, pad) of a residual network's first conv
+    stem_pool: tuple = ()  # (window, stride, pad) of the max pool after it
+    stages: tuple = ()  # (blocks, mid, out, stride) per bottleneck stage
+
+    @property
+    def residual(self) -> bool:
+        return bool(self.stages)
 
 
 ALEXNET = CNNSpec(
@@ -93,28 +118,110 @@ LENET = CNNSpec(
     fcs=(120, 84),
 )
 
-CNN_ZOO = {c.name: c for c in (ALEXNET, VGG16, LENET)}
+#: He et al., arXiv:1512.03385, Table 1, 50-layer column, with the v1.5
+#: strides (each stage's stride on its first 3×3, as torchvision's resnet50)
+RESNET50 = CNNSpec(
+    "resnet50", 224, 3, 1000,
+    stem=(64, 7, 2, 3),
+    stem_pool=(3, 2, 1),
+    stages=((3, 64, 256, 1), (4, 128, 512, 2), (6, 256, 1024, 2), (3, 512, 2048, 2)),
+)
+
+CNN_ZOO = {c.name: c for c in (ALEXNET, VGG16, LENET, RESNET50)}
 
 
-def _maxpool(x, w: int):
-    """NHWC max pool, window w, stride w (PS-plane op).
+def _bottlenecks(spec: CNNSpec):
+    """Per block of a residual spec: (index of its first conv, index of its
+    projection or None, stride, mid, out).  Convs are numbered in forward
+    order: the stem 0, then each block's 1×1, 3×3, 1×1 and projection."""
+    i, cin = 1, spec.stem[0]
+    for blocks, mid, out, stride in spec.stages:
+        for b in range(blocks):
+            s = stride if b == 0 else 1
+            proj = i + 3 if (s != 1 or cin != out) else None
+            yield i, proj, s, mid, out
+            i += 3 + (proj is not None)
+            cin = out
+
+
+def conv_shapes(spec: CNNSpec, input_shape: Optional[Sequence[int]] = None) -> tuple:
+    """((input h, input w, cin, cout, k, stride, pad) per conv in forward
+    order, the width of the features entering the first FC layer), for an
+    (H, W, C) input (default the spec's)."""
+    h, w, ch = input_shape or (spec.input_hw, spec.input_hw, spec.input_ch)
+    out = []
+
+    def conv(cout, k, stride, pad):
+        nonlocal h, w, ch
+        out.append((h, w, ch, cout, k, stride, pad))
+        h, w, ch = (h + 2 * pad - k) // stride + 1, (w + 2 * pad - k) // stride + 1, cout
+
+    if not spec.residual:
+        for cout, k, stride, pad, pool in spec.convs:
+            conv(cout, k, stride, pad)
+            if pool:
+                h, w = h // pool, w // pool
+        return tuple(out), h * w * ch
+    conv(*spec.stem)
+    window, stride, pad = spec.stem_pool
+    h, w = (h + 2 * pad - window) // stride + 1, (w + 2 * pad - window) // stride + 1
+    for _, proj, s, mid, wide in _bottlenecks(spec):
+        hin, win, cin = h, w, ch
+        conv(mid, 1, 1, 0)
+        conv(mid, 3, s, 1)
+        conv(wide, 1, 1, 0)
+        if proj is not None:
+            out.append((hin, win, cin, wide, 1, s, 0))
+    return tuple(out), ch
+
+
+def _maxpool(x, w: int, stride: Optional[int] = None, pad: int = 0):
+    """NHWC max pool, window w, stride ``stride`` (default w), ``pad``
+    rows and columns of the dtype's least value on each side (PS-plane op).
 
     QTensor inputs pool on the integer raws directly (int16 or int8 per the
     grid's rung): dequantization is monotone, so max-of-raw == raw-of-max
     and the activation never leaves the fixed-point grid for pooling
-    (DESIGN.md §8).
+    (DESIGN.md §8).  Their padding is the raw minimum, which no window
+    takes where it holds a real value.
     """
+    s = stride or w
+    padding = ((0, 0), (pad, pad), (pad, pad), (0, 0)) if pad else "VALID"
     if isinstance(x, QTensor):
         init = jnp.array(jnp.iinfo(x.raw.dtype).min, x.raw.dtype)
         return QTensor(
             jax.lax.reduce_window(
-                x.raw, init, jax.lax.max, (1, w, w, 1), (1, w, w, 1), "VALID"
+                x.raw, init, jax.lax.max, (1, w, w, 1), (1, s, s, 1), padding
             ),
             x.fmt,
         )
     return jax.lax.reduce_window(
-        x, -jnp.inf, jax.lax.max, (1, w, w, 1), (1, w, w, 1), "VALID"
+        x, -jnp.inf, jax.lax.max, (1, w, w, 1), (1, s, s, 1), padding
     )
+
+
+def _residual_add(a, b):
+    """A bottleneck's residual add with its ReLU.  On a grid: the int32 sum
+    of the two raws, saturated to the grid's raw range, then ReLU; both
+    operands must lie on the one grid (:func:`_residual_grids`)."""
+    if isinstance(a, QTensor):
+        fmt = a.fmt
+        acc = a.raw.astype(jnp.int32) + b.raw.astype(jnp.int32)
+        acc = jnp.maximum(jnp.clip(acc, fmt.raw_min, fmt.raw_max), 0)
+        return QTensor(acc.astype(fmt.storage_dtype), fmt)
+    return jnp.maximum(a + b, 0.0)
+
+
+def _avgpool(x):
+    """Global average pool, NHWC -> (N, C).  On a grid, exact integers: the
+    int32 sum ``s`` of the H·W = n raws, then ``(s + n // 2) // n``, which
+    rounds half up the non-negative (post-ReLU) sums it is given; the mean
+    stays on the input's grid.  Float: the mean."""
+    if isinstance(x, QTensor):
+        n = x.shape[1] * x.shape[2]
+        s = jnp.sum(x.raw.astype(jnp.int32), axis=(1, 2))
+        return QTensor(((s + n // 2) // n).astype(x.raw.dtype), x.fmt)
+    return jnp.mean(x, axis=(1, 2))
 
 
 # -- spatial (H-slab) sharding helpers (DESIGN.md §10) -----------------------
@@ -178,21 +285,15 @@ def _maxpool_spatial(x, w: int, ph):
 def init_cnn(key, spec: CNNSpec, dtype=jnp.float32, scale: float = 0.5):
     """He-style init, scaled into the Q2.14 representable range [-2, 2)."""
     params = {"convs": [], "fcs": []}
-    ch = spec.input_ch
-    hw = spec.input_hw
-    keys = jax.random.split(key, len(spec.convs) + len(spec.fcs) + 1)
+    convs, feat = conv_shapes(spec)
+    keys = jax.random.split(key, len(convs) + len(spec.fcs) + 1)
     ki = 0
-    for (cout, k, stride, pad, pool) in spec.convs:
+    for (_, _, ch, cout, k, _, _) in convs:
         fan_in = k * k * ch
         w = jax.random.normal(keys[ki], (k, k, ch, cout)) * (scale * fan_in ** -0.5)
         b = jnp.zeros((cout,))
         params["convs"].append({"w": w.astype(dtype), "b": b.astype(dtype)})
         ki += 1
-        hw = (hw + 2 * pad - k) // stride + 1
-        if pool:
-            hw //= pool
-        ch = cout
-    feat = hw * hw * ch
     widths = (*spec.fcs, spec.n_classes)
     fan = feat
     for wd in widths:
@@ -245,7 +346,8 @@ class NetworkPlan:
             lines.append(
                 f"conv{i}: route={cp.route} tau={cp.tau} halo_mode={mode}"
                 f"{tiling} vmem={cp.vmem_bytes}B "
-                f"({cp.vmem_bytes / 2**20:.1f}MiB) gemm={cp.gemm}{halo}"
+                f"({cp.vmem_bytes / 2**20:.1f}MiB) gemm={cp.gemm}"
+                f"{' space_to_depth' if cp.space_to_depth else ''}{halo}"
             )
         for i, gp in enumerate(self.fcs):
             blk = steps = None
@@ -293,7 +395,9 @@ def plan_cnn(
     Recorded as the set-up span ``plan``, whose ``dse_searches`` is the
     PlanRegistry's miss delta (0 when every layer was a hit) and whose
     ``skinny_gemms`` counts the searches among them that took the skinny-M
-    GEMM branch (an FC head at batch 1-8: 3 for VGG16).
+    GEMM branch (an FC head at batch 1-8: 3 for VGG16).  It also counts the
+    network's ``residual_adds``, ``pointwise_convs`` (1×1) and
+    ``strided_convs``: 16, 36 and 7 for ResNet-50, none for VGG16.
     """
     with layer("plan") as attrs:
         with tpl.engine.plan_cache.scope() as delta:
@@ -301,11 +405,17 @@ def plan_cnn(
                              partition, spatial)
         attrs["dse_searches"] = delta["misses"]
         attrs["skinny_gemms"] = delta["skinny"]
+        convs = conv_shapes(spec)[0]
+        attrs["residual_adds"] = sum(1 for _ in _bottlenecks(spec)) if spec.residual else 0
+        attrs["pointwise_convs"] = sum(c[4] == 1 for c in convs)
+        attrs["strided_convs"] = sum(c[5] > 1 for c in convs)
     return plan
 
 
 def _plan_cnn(tpl, spec, input_shape, force_route, mesh, partition, spatial):
     spatial_n, spatial_ax = 1, None
+    if spatial is not None and spec.residual:
+        raise ValueError(f"{spec.name}: a residual network has no spatial (H-slab) plan")
     if spatial is not None:
         from repro.parallel.sharding import spatial_shards
 
@@ -364,20 +474,13 @@ def _plan_cnn(tpl, spec, input_shape, force_route, mesh, partition, spatial):
         )
         _NETWORK_PLANS[key] = plan
         return plan
+    shapes, fan = conv_shapes(spec, (hh, ww, ch))
     convs = []
-    for cout, k, stride, pad, pool in spec.convs:
-        cp = eng.plan_conv(
-            (n, hh, ww, ch), (k, k, ch, cout), stride=stride, padding=pad,
+    for h, w, cin, cout, k, stride, pad in shapes:
+        convs.append(eng.plan_conv(
+            (n, h, w, cin), (k, k, cin, cout), stride=stride, padding=pad,
             route=force_route, mesh=mesh, partition=partition,
-        )
-        convs.append(cp)
-        hh = (hh + 2 * cp.pad - k) // stride + 1
-        ww = (ww + 2 * cp.pad - k) // stride + 1
-        if pool:
-            hh //= pool
-            ww //= pool
-        ch = cout
-    fan = hh * ww * ch
+        ))
     fcs = []
     for wd in (*spec.fcs, spec.n_classes):
         fcs.append(eng.plan_gemm(n, wd, fan, mesh=mesh, partition=partition))
@@ -390,8 +493,10 @@ def _plan_cnn(tpl, spec, input_shape, force_route, mesh, partition, spatial):
 def cnn_layer_names(spec: CNNSpec) -> tuple:
     """The per-layer precision-DSE names, forward order: conv0.. then fc0..
     (the final entry is the classifier).  A layer's name keys its *input*
-    activation grid in ``NumericsPolicy.layer_fmts`` and the plan store."""
-    return tuple(f"conv{i}" for i in range(len(spec.convs))) + tuple(
+    activation grid in ``NumericsPolicy.layer_fmts`` and the plan store.  A
+    residual network numbers its convs in forward order, each block's
+    projection after its third conv."""
+    return tuple(f"conv{i}" for i in range(len(conv_shapes(spec)[0]))) + tuple(
         f"fc{i}" for i in range(len(spec.fcs) + 1)
     )
 
@@ -579,17 +684,60 @@ def cnn_forward(
     dequantize for the entire forward (DESIGN.md §8).
     """
     with layer("forward", traced=isinstance(x, jax.core.Tracer)):
-        if policy is not None and policy.quantized and isinstance(
+        on_grid = policy is not None and policy.quantized and isinstance(
             params["convs"][0]["w"], QTensor
-        ):
+        )
+        if spec.residual:
+            if quantized:
+                raise ValueError(f"{spec.name}: a residual network runs in float or "
+                                 f"on a quantized NumericsPolicy, not fake-quantized")
+            return _forward_residual(tpl, spec, params, x, plan,
+                                     policy if on_grid else None)
+        if on_grid:
             return _forward_q(tpl, spec, params, x, plan, policy)
         return _forward_float(tpl, spec, params, x, quantized, fmt, plan)
 
 
-# Every layer of the forward runs under a span named as
-# bench/roofline.py:layer_counts names it (conv{i}, fc{i}), plus quantize,
-# pool{i} (numbered by the conv it follows) and gather: its device
+# Every layer of the forward runs under a span named as the form's
+# layer_counts names it (conv{i}, fc{i}), plus quantize, the pools (a
+# chain's pool{i} numbered by the conv it follows; a residual network's
+# pool0 and avgpool), a residual network's add{j} and gather: its device
 # operations carry the name in their op_name, its trace time a host span.
+
+
+def _q_head(tpl, plan, policy, names, nc):
+    """The FC layers on the grid: each hidden one writes its successor's
+    input grid, the classifier reads its int32 accumulator out exactly."""
+    def head(h, fcs):
+        last = len(fcs) - 1
+        for i, (p, gp) in enumerate(zip(fcs, plan.fcs)):
+            with layer(f"fc{i}"):
+                if i < last:
+                    h = tpl.linear(h, p["w"], p["b"], relu=True,
+                                   qout=policy.fmt_for(names[nc + i + 1]),
+                                   plan=gp)
+                else:
+                    # final classifier: exact accumulator read-out (the
+                    # single counted dequantize of the whole network)
+                    h = tpl.linear(h, p["w"], p["b"], wide=True, plan=gp)
+        return h
+
+    return head
+
+
+def _float_head(tpl, plan, fq, qo):
+    """The FC layers in float, ReLU on all but the classifier."""
+    def head(h, fcs):
+        last = len(fcs) - 1
+        for i, (p, gp) in enumerate(zip(fcs, plan.fcs)):
+            with layer(f"fc{i}"):
+                h = tpl.linear(
+                    h, fq(p["w"]), fq(p["b"]),
+                    relu=i < last, qout=qo if i < last else None, plan=gp,
+                )
+        return h
+
+    return head
 
 
 def _forward_q(tpl, spec, params, x, plan, policy):
@@ -617,22 +765,7 @@ def _forward_q(tpl, spec, params, x, plan, policy):
             with layer(f"pool{i}"):
                 h = _maxpool_spatial(h, pool, ph) if ph is not None else _maxpool(h, pool)
     h = _flatten(h, plan)
-
-    def head(h, fcs):
-        last = len(fcs) - 1
-        for i, (p, gp) in enumerate(zip(fcs, plan.fcs)):
-            with layer(f"fc{i}"):
-                if i < last:
-                    h = tpl.linear(h, p["w"], p["b"], relu=True,
-                                   qout=policy.fmt_for(names[nc + i + 1]),
-                                   plan=gp)
-                else:
-                    # final classifier: exact accumulator read-out (the
-                    # single counted dequantize of the whole network)
-                    h = tpl.linear(h, p["w"], p["b"], wide=True, plan=gp)
-        return h
-
-    return _fc_head(plan, head, h, params["fcs"])
+    return _fc_head(plan, _q_head(tpl, plan, policy, names, nc), h, params["fcs"])
 
 
 def _forward_float(tpl, spec, params, x, quantized, fmt, plan):
@@ -655,17 +788,71 @@ def _forward_float(tpl, spec, params, x, quantized, fmt, plan):
             with layer(f"pool{i}"):
                 h = _maxpool_spatial(h, pool, ph) if ph is not None else _maxpool(h, pool)
     h = _flatten(h, plan)
+    return _fc_head(plan, _float_head(tpl, plan, fq, qo), h, params["fcs"])
 
-    def head(h, fcs):
-        last = len(fcs) - 1
-        for i, (p, gp) in enumerate(zip(fcs, plan.fcs)):
-            with layer(f"fc{i}"):
-                h = tpl.linear(
-                    h, fq(p["w"]), fq(p["b"]),
-                    relu=i < last, qout=qo if i < last else None, plan=gp,
-                )
-        return h
 
+def _residual_grids(spec: CNNSpec, policy: NumericsPolicy) -> list:
+    """The grid each conv of a residual network writes.  A conv writes its
+    consumer's input grid (``policy.fmt_for`` of its name); a block's third
+    conv and its projection write the grid of the block's output, the next
+    block's (or the classifier's) input grid; the stem writes the first
+    block's, through the max pool.  A value that two layers read, and the
+    two operands of an add, have to lie on one grid: a policy that splits
+    them is an error that names the layer."""
+    names = cnn_layer_names(spec)
+    fmt = policy.fmt_for
+    blocks = list(_bottlenecks(spec))
+    nconv = len(names) - len(spec.fcs) - 1
+    ins = [fmt(names[i]) for i, *_ in blocks] + [fmt(names[nconv])]
+    out = [ins[0]] + [None] * (nconv - 1)
+    for j, (i, proj, *_) in enumerate(blocks):
+        out[i], out[i + 1], out[i + 2] = fmt(names[i + 1]), fmt(names[i + 2]), ins[j + 1]
+        if proj is None:
+            if ins[j] != ins[j + 1]:
+                raise ValueError(
+                    f"add{j}: its operands lie on grids {ins[j].name} (the shortcut) and "
+                    f"{ins[j + 1].name}; a residual add needs both on one grid")
+            continue
+        if fmt(names[proj]) != ins[j]:
+            raise ValueError(
+                f"conv{proj}: the projection reads its block's input, on grid "
+                f"{ins[j].name}, but the policy puts its input on {fmt(names[proj]).name}")
+        out[proj] = ins[j + 1]
+    return out
+
+
+def _forward_residual(tpl, spec, params, x, plan, policy):
+    """Stem, max pool, bottleneck blocks, average pool, classifier, grid-
+    resident on a quantized ``policy`` (the residual add and both pools on
+    the raws, :func:`_residual_add`, :func:`_avgpool`) or in float (None)."""
+    plan = plan or plan_cnn(tpl, spec, x.shape)
+    names = cnn_layer_names(spec)
+    nc = len(plan.convs)
+    grids = _residual_grids(spec, policy) if policy is not None else [None] * nc
+
+    def conv(i, h, relu):
+        p, cp = params["convs"][i], plan.convs[i]
+        with layer(f"conv{i}"):
+            return tpl.conv2d(h, p["w"], stride=cp.stride, padding=cp.pad,
+                              bias=p["b"], relu=relu, qout=grids[i], plan=cp)
+
+    h = x
+    if policy is not None:
+        with layer("quantize"):
+            h = tpl.engine.quant(x, policy.fmt_for(names[0]))
+    h = conv(0, h, relu=True)
+    with layer("pool0"):
+        h = _maxpool(h, *spec.stem_pool)
+    for j, (i, proj, *_) in enumerate(_bottlenecks(spec)):
+        t = conv(i + 2, conv(i + 1, conv(i, h, True), True), False)
+        shortcut = h if proj is None else conv(proj, h, False)
+        with layer(f"add{j}"):
+            h = _residual_add(t, shortcut)
+    with layer("avgpool"):
+        h = _avgpool(h)
+    h = _flatten(h, plan)
+    head = (_q_head(tpl, plan, policy, names, nc) if policy is not None
+            else _float_head(tpl, plan, lambda a: a, None))
     return _fc_head(plan, head, h, params["fcs"])
 
 
@@ -695,7 +882,8 @@ def cnn_forward_ref(spec: CNNSpec, params, x: jax.Array, *,
     no plan, no Pallas — for checking the engine's forward on a chip.
 
     Float (``policy`` None or float): f32 convs and dense layers at full f32
-    precision (``Precision.HIGHEST``), bias, ReLU, max pool.  Quantized
+    precision (``Precision.HIGHEST``), bias, ReLU, the pools, a residual
+    network's adds.  Quantized
     (a quantized ``policy`` with a :func:`quantize_cnn_params` tree): the
     grid-resident semantics of :func:`cnn_forward` op for op on the int
     raws — the input quantized once, every conv/FC the mixed-format oracle
@@ -708,45 +896,57 @@ def cnn_forward_ref(spec: CNNSpec, params, x: jax.Array, *,
     from repro.kernels.ref import conv2d_qtensor_ref
 
     hi = jax.lax.Precision.HIGHEST
-    if policy is None or not policy.quantized:
-        h = x.astype(jnp.float32)
-        for p, (cout, k, stride, pad, pool) in zip(params["convs"], spec.convs):
-            h = jax.lax.conv_general_dilated(
-                h, p["w"].astype(jnp.float32), (stride, stride),
-                [(pad, pad), (pad, pad)],
-                dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=hi,
-            )
-            h = jnp.maximum(h + p["b"], 0.0)
-            if pool:
-                h = _maxpool(h, pool)
-        h = h.reshape(h.shape[0], -1)
-        last = len(params["fcs"]) - 1
-        for i, p in enumerate(params["fcs"]):
-            h = jnp.dot(h, p["w"].astype(jnp.float32), precision=hi) + p["b"]
-            if i < last:
-                h = jnp.maximum(h, 0.0)
-        return h
     names = cnn_layer_names(spec)
-    fmt0 = policy.fmt_for(names[0])
-    h = QTensor(quantize(x, fmt0), fmt0)
-    nc = len(spec.convs)
-    for i, (p, (cout, k, stride, pad, pool)) in enumerate(
-        zip(params["convs"], spec.convs)
-    ):
-        h = conv2d_qtensor_ref(h, p["w"], policy.fmt_for(names[i + 1]),
-                               p["b"], stride=stride, padding=pad, relu=True)
-        if pool:
-            h = _maxpool(h, pool)
-    h = h.reshape(h.shape[0], -1)
-    last = len(params["fcs"]) - 1
-    for i, p in enumerate(params["fcs"]):
+    nc, last = len(params["convs"]), len(params["fcs"]) - 1
+    shapes = conv_shapes(spec, x.shape[1:])[0]
+    on_grid = policy is not None and policy.quantized
+    if on_grid:
+        grids = (_residual_grids(spec, policy) if spec.residual
+                 else [policy.fmt_for(n) for n in names[1:nc + 1]])
+
+    def conv(i, h, relu):
+        p, (*_, stride, pad) = params["convs"][i], shapes[i]
+        if on_grid:
+            return conv2d_qtensor_ref(h, p["w"], grids[i], p["b"],
+                                      stride=stride, padding=pad, relu=relu)
+        h = jax.lax.conv_general_dilated(
+            h, p["w"].astype(jnp.float32), (stride, stride),
+            [(pad, pad), (pad, pad)],
+            dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=hi,
+        ) + p["b"]
+        return jnp.maximum(h, 0.0) if relu else h
+
+    def dense(i, h):
+        p = params["fcs"][i]
+        if not on_grid:
+            h = jnp.dot(h, p["w"].astype(jnp.float32), precision=hi) + p["b"]
+            return jnp.maximum(h, 0.0) if i < last else h
         if i < last:
-            h = qtensor_matmul_ref(h, p["w"], policy.fmt_for(names[nc + i + 1]),
-                                   bias=p["b"], relu=True)
-            continue
+            return qtensor_matmul_ref(h, p["w"], policy.fmt_for(names[nc + i + 1]),
+                                      bias=p["b"], relu=True)
         acc_frac = h.fmt.frac_bits + p["w"].fmt.frac_bits
         acc = jnp.dot(h.raw.astype(jnp.int32), p["w"].raw.astype(jnp.int32),
                       preferred_element_type=jnp.int32)
         acc = acc + (p["b"].raw.astype(jnp.int32)
                      << (acc_frac - p["b"].fmt.frac_bits))
         return acc.astype(jnp.float32) * 2.0 ** -acc_frac
+
+    if on_grid:
+        h = QTensor(quantize(x, policy.fmt_for(names[0])), policy.fmt_for(names[0]))
+    else:
+        h = x.astype(jnp.float32)
+    if spec.residual:
+        h = _maxpool(conv(0, h, True), *spec.stem_pool)
+        for i, proj, *_ in _bottlenecks(spec):
+            t = conv(i + 2, conv(i + 1, conv(i, h, True), True), False)
+            h = _residual_add(t, h if proj is None else conv(proj, h, False))
+        h = _avgpool(h)
+    else:
+        for i, (*_, pool) in enumerate(spec.convs):
+            h = conv(i, h, True)
+            if pool:
+                h = _maxpool(h, pool)
+    h = h.reshape(h.shape[0], -1)
+    for i in range(last + 1):
+        h = dense(i, h)
+    return h

@@ -20,7 +20,7 @@ from repro.core import dse
 from repro.core.engine import Engine, reset_plan_caches
 from repro.core.quantization import Q2_14, dequantize, quantize
 from repro.core.template import TemplateConfig
-from repro.core.tiling import TPU_V5E, ceil_div
+from repro.core.tiling import TPU_V5E, ceil_div, space_to_depth_geometry
 from repro.kernels import ops, ref
 
 KEY = jax.random.PRNGKey(42)
@@ -168,8 +168,10 @@ def test_oversized_layer_tiles_and_matches_im2col(stride, pad):
         plan = eng.plan_conv(x.shape, w.shape, stride=stride, padding=pad)
         hp, wp = 32 + 2 * plan.pad, 32 + 2 * plan.pad
         ho = (hp - 3) // stride + 1
+        # the stride-1 conv the kernel runs (over the space-to-depth input)
         untiled = dse.direct_conv_vmem(
-            hp, wp, 32, 3, 3, ho, ho, plan.tau, in_bytes, stride=stride
+            *space_to_depth_geometry(hp, wp, 32, 3, 3, stride), ho, ho,
+            plan.tau, in_bytes,
         )
         assert untiled > budget, backend  # it really was oversized
         assert plan.route == "direct", backend
@@ -222,7 +224,7 @@ def test_forced_fallback_boundary():
     vmin = min(
         c.vmem_bytes
         for c in dse.explore_conv_spatial(
-            hp, wp, 16, 3, 3, ho, wo, 8, 1,
+            hp, wp, 16, 3, 3, ho, wo, 8,
             dataclasses.replace(TPU_V5E, vmem_bytes=2**62), 4, top=1000,
         )
     )
@@ -346,7 +348,7 @@ def test_divisor_tile_ladder_offers_exact_tilings():
     assert dse._tile_ladder(8, 1) == [8, 4, 2, 1]
     # and the explored configs include an exact non-power-of-two tiling
     ranked = dse.explore_conv_spatial(
-        29, 29, 8, 3, 3, 27, 27, 8, 1,
+        29, 29, 8, 3, 3, 27, 27, 8,
         # Cin=8 occupies 128 lanes (16x) in the chip-layout VMEM model
         dataclasses.replace(TPU_V5E, vmem_bytes=1024 * 1024), 4, top=1000,
     )

@@ -354,7 +354,6 @@ def test_adhoc_conv2d_plans_local_shape_under_mesh():
     w = jax.random.normal(jax.random.fold_in(KEY, 2), (3, 3, 4, 8)) * 0.3
     with use_mesh(_StubMesh(), TRAIN_RULES):
         eng.conv2d(x, w, padding=1)
-    # conv DSE keys: (hp, wp, cin, kh, kw, ho, wo, cout, stride, in_bytes,
-    # spec) — the planned Cout is the model-sharded local 4, never 8
+    # conv DSE keys: (hp, wp, cin, kh, kw, ho, wo, cout, in_bytes, spec) — the planned Cout is the model-sharded local 4, never 8
     couts = {k[7] for k in eng.plan_cache._conv_tiles}
     assert couts == {4}, couts

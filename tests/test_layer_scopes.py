@@ -21,15 +21,16 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core.quantization import NumericsPolicy
+from repro.core.quantization import NumericsPolicy, QFormat
 from repro.core.template import default_template
 from repro.models import cnn as C
 
 REPO = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(REPO / "bench" / "tests"))
 from bench_tiny import TINY  # noqa: E402
+from tiny_resnet import TINY_RESNET  # noqa: E402
 
-LAYER = re.compile(r"^(quantize|conv\d+|pool\d+|gather|fc\d+)$")
+LAYER = re.compile(r"^(quantize|conv\d+|pool\d+|add\d+|avgpool|gather|fc\d+)$")
 KERNEL = re.compile(r"^(conv|matmul)_[a-z0-9_]+$")
 COLLECTIVE = re.compile(r"\s(collective-permute|all-gather|all-reduce)[a-z-]*\(")
 
@@ -83,6 +84,42 @@ def test_every_kernel_instruction_lies_in_a_layer_scope(backend):
     assert {"pool0", "pool1"} <= found
     if backend == "q16":
         assert "quantize" in found
+
+
+@pytest.mark.parametrize("backend", ["pallas", "q16"])
+def test_every_layer_of_a_residual_network_is_named(backend):
+    """The tiny ResNet: its 17 convs (projections included) and classifier
+    are kernels in their own scopes; the max pool, the four adds and the
+    average pool are scoped XLA work, as the ``resnet`` form's patterns
+    read them."""
+    from bench import forms
+
+    r = TINY_RESNET
+    spec = C.CNNSpec(r["network"], r["input_hw"], r["input_ch"], r["n_classes"],
+                     stem=tuple(r["stem"]), stem_pool=tuple(r["stem_pool"]),
+                     stages=tuple(tuple(st) for st in r["stages"]))
+    # 64x64 frames leave 2x2 maps for the average pool to reduce
+    x = np.random.default_rng(0).standard_normal((2, 64, 64, 3), dtype=np.float32)
+    tpl = default_template(backend)
+    params = C.init_cnn(jax.random.PRNGKey(0), spec)
+    policy = None
+    if backend == "q16":
+        policy = NumericsPolicy("q16", fmt=QFormat(4, 12, 16))
+        params = C.quantize_cnn_params(tpl, spec, params, policy)
+    hlo = jax.jit(lambda p, x: C.cnn_forward(tpl, spec, p, x, policy=policy)).lower(
+        params, x).compile().as_text()
+    kernels, bare, _, _ = scoped_instructions(hlo)
+    assert bare == 0
+    assert kernels == {f"conv{i}" for i in range(17)} | {"fc0"}
+    found = {c for op in re.findall(r'op_name="([^"]*)"', hlo)
+             for c in op.split("/") if LAYER.match(c)}
+    assert {"pool0", "add0", "add1", "add2", "add3", "avgpool"} <= found
+    form = forms.find_form({"form": "resnet"})
+    own = re.compile(f"^({form.xla_layer_pattern})$")
+    assert {c for c in found if re.match(f"^({form.layer_pattern})$", c)} == found - {
+        "quantize", "gather"}
+    assert {c for c in found if own.match(c)} == {"pool0", "avgpool"} | {
+        f"add{j}" for j in range(4)}
 
 
 _SLABS = textwrap.dedent(

@@ -385,7 +385,7 @@ def test_serve_q8_warm_restart_zero_searches(tmp_path, monkeypatch):
     serve.main(args)  # cold: calibrates, sweeps, pins, saves
     with open(store) as f:
         doc = json.load(f)
-    assert doc["version"] == 3 and doc["precision"], \
+    assert doc["version"] == 4 and doc["precision"], \
         "cold q8 serve must persist measured precision pins"
     assert all(e["source"] == "measured" for e in doc["precision"])
 

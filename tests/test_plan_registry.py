@@ -116,7 +116,7 @@ def test_store_is_versioned_json(tmp_path):
     with open(path) as f:
         doc = json.load(f)
     assert doc["format"] == "repro-plan-store"
-    assert doc["version"] == 3
+    assert doc["version"] == 4
     assert doc["specs"] and doc["gemm"] and doc["conv"]
     assert "precision" in doc
     # every entry carries provenance
@@ -170,6 +170,8 @@ def test_v2_store_migrates_gemm_and_conv_without_precision(tmp_path):
     reg.pin_precision("lenet", "conv0", Q2_6, drift=1.0)
     doc = reg.to_doc()
     doc["version"] = 2  # keep the (stray) precision section on purpose
+    for e in doc["conv"]:
+        e["key"].insert(8, 1)  # v2 keys carry the layer's stride
     path = tmp_path / "v2.json"
     path.write_text(json.dumps(doc))
 
@@ -185,6 +187,32 @@ def test_v2_store_migrates_gemm_and_conv_without_precision(tmp_path):
     assert eng.plan_gemm(256, 512, 256) == g
     assert eng.plan_conv((1, 32, 32, 8), (3, 3, 8, 16), stride=1, padding=1) == c
     assert loaded.misses == 0
+
+
+def test_v3_store_drops_its_strided_conv_entries(tmp_path):
+    """A v3 store keys a conv by the layer's stride: its stride-1 entries
+    load without the stride, and a strided entry (planned over strided
+    windows) is dropped, so that layer re-plans over its space-to-depth
+    input."""
+    reg, (_, c, _) = _populated_registry()
+    doc = reg.to_doc()
+    doc["version"] = 3
+    for e in doc["conv"]:
+        e["key"].insert(8, 1)
+    strided = json.loads(json.dumps(doc["conv"][0]))
+    strided["key"][8] = 2
+    doc["conv"].append(strided)
+    path = tmp_path / "v3.json"
+    path.write_text(json.dumps(doc))
+
+    loaded = PlanRegistry()
+    assert loaded.load(str(path)) == len(reg._blocks) + len(reg._conv_tiles)
+    assert loaded._conv_tiles == reg._conv_tiles
+    eng = Engine(TemplateConfig(backend="pallas"), plan_cache=loaded)
+    assert eng.plan_conv((1, 32, 32, 8), (3, 3, 8, 16), stride=1, padding=1) == c
+    assert loaded.misses == 0
+    eng.plan_conv((1, 32, 32, 8), (3, 3, 8, 16), stride=2, padding=1)
+    assert loaded.misses == 1
 
 
 def test_v1_store_migrates_gemm_only(tmp_path):

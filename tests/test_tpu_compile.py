@@ -1,5 +1,6 @@
-"""Compile rehearsal for the TPU v5e: the main path's kernels at real widths,
-compiled for a described (not attached) ``v5e:2x2`` chip.
+"""Compile rehearsal for the TPU v5e: the main path's kernels at real widths
+(VGG16's and ResNet-50's), compiled for a described (not attached)
+``v5e:2x2`` chip.
 
 Interpret-mode tests cannot see what the chip's compiler refuses — a block
 not aligned to the (8, 128) tiling, a view of a ref Mosaic cannot slice, a
@@ -63,6 +64,14 @@ def _vgg16_layer(backend, hw, i):
     return tpl, plan.convs[i], (1, h, h, ch), (k, k, ch, cout)
 
 
+def _resnet50_layer(backend, i):
+    """The same for ResNet-50 conv ``i`` at batch 32."""
+    tpl = default_template(backend)
+    plan = C.plan_cnn(tpl, C.RESNET50, (32, 224, 224, 3))
+    h, w, cin, cout, k = C.conv_shapes(C.RESNET50)[0][i][:5]
+    return tpl, plan.convs[i], (32, h, w, cin), (k, k, cin, cout)
+
+
 def _conv_fn(tpl, cp):
     fn = ops.conv2d if tpl.config.backend == "pallas" else ops.conv2d_q16
     return lambda x, w, b: fn(
@@ -80,6 +89,20 @@ def test_vgg16_224_conv_compiles_as_planned(one_chip, backend, layer):
     """VGG16@224 conv0 (Cin=3: one channel in 128 lanes), conv1 (the
     largest activation) and a Cin=512 fixed-point layer, as planned."""
     tpl, cp, xs, ws = _vgg16_layer(backend, 224, layer)
+    assert cp.route == "direct" and cp.vmem_bytes <= tpl.config.hw.vmem_bytes
+    dt = jnp.float32 if backend == "pallas" else jnp.int16
+    _compile(_conv_fn(tpl, cp), one_chip, (xs, dt), (ws, dt), ((ws[-1],), dt))
+
+
+@pytest.mark.parametrize("backend", ["pallas", "q16"])
+@pytest.mark.parametrize("layer,strided", [(0, True), (12, True), (14, True), (3, False)])
+def test_resnet50_conv_compiles_as_planned(one_chip, backend, layer, strided):
+    """ResNet-50 at batch 32: the 7x7/s2 stem (Cin=3), stage 2's 3x3/s2 and
+    its 1x1/s2 projection, and a 1x1 at 56x56 to Cout=256.  Mosaic loads no
+    strided window of 16-bit data (nor of 32-bit data wider than 128
+    lanes), so the strided ones run over their space-to-depth input."""
+    tpl, cp, xs, ws = _resnet50_layer(backend, layer)
+    assert (cp.stride > 1) == strided == cp.space_to_depth
     assert cp.route == "direct" and cp.vmem_bytes <= tpl.config.hw.vmem_bytes
     dt = jnp.float32 if backend == "pallas" else jnp.int16
     _compile(_conv_fn(tpl, cp), one_chip, (xs, dt), (ws, dt), ((ws[-1],), dt))
